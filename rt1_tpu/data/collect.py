@@ -73,7 +73,7 @@ def collect_episode(
     mitigation for the round-3 closed-loop drift failure (a policy trained
     on noise-free demos collapses to the marginal action the moment its
     own imperfect actions leave the demo state distribution; diagnosis in
-    RESULTS.md, `artifacts/cpu_t1_diag_ck7500.json`). The reference never
+    `artifacts/cpu_t1_diag_ck7500.json`). The reference never
     needed this because its corpus is human teleop, which carries this
     state coverage naturally.
     """

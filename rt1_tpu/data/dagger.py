@@ -2,7 +2,7 @@
 
 Round-3 measured mechanism of the closed-loop 0/20s: a BC policy trained on
 oracle demos leaves the demo state distribution after one imperfect action
-and collapses to the marginal action (RESULTS.md, `artifacts/
+and collapses to the marginal action (`artifacts/
 cpu_t1_diag_ck7500.json` — action std 0.0009, oracle cosine −0.73, zero
 block progress). DART (execution noise at collection) covers *near-demo*
 states; DAgger (Ross et al. 2011) covers the states the TRAINED policy

@@ -10,6 +10,9 @@ so callers never hold dangling views.
 from __future__ import annotations
 
 import ctypes
+import fcntl
+import hashlib
+import logging
 import os
 import subprocess
 import threading
@@ -31,29 +34,46 @@ _ws_lock = threading.Lock()
 _ws_build_failed = False
 
 
+def _source_digest(src_path: str) -> str:
+    with open(src_path, "rb") as f:
+        return hashlib.sha256(f.read()).hexdigest()
+
+
+def _is_current(lib_path: str, digest: str) -> bool:
+    """True when `lib_path` exists and was built from source `digest`.
+
+    The digest of the source each library was built from is recorded beside
+    it (`<lib>.srchash`); mtimes are not consulted because a copied tree
+    does not preserve them.
+    """
+    try:
+        with open(lib_path + ".srchash") as f:
+            return os.path.exists(lib_path) and f.read().strip() == digest
+    except OSError:
+        return False
+
+
 def _build_lib(source: str, lib_path: str, link_flags=()) -> bool:
-    """Ensure `lib_path` exists and is newer than `source`; compile if not.
+    """Ensure `lib_path` is built from the current `source`; compile if not.
 
     The freshness check runs BEFORE any write (a read-only install with a
     prebuilt current .so must work). Compilation happens under an flock so
     racing worker processes serialize, to a temp name atomically renamed so
     no process ever dlopens (or has mapped) a half-written .so. The commands
-    mirror native/Makefile (kept for manual/dev builds).
+    mirror native/Makefile (kept for manual/dev builds). A failed build is
+    logged with the compiler's output; callers then take the numpy path.
     """
     src_path = os.path.join(_NATIVE_DIR, source)
-    if os.path.exists(lib_path) and not _source_newer(src_path, lib_path):
-        return True
     try:
-        import fcntl
-
+        digest = _source_digest(src_path)
+        if _is_current(lib_path, digest):
+            return True
         lock_path = os.path.join(_NATIVE_DIR, ".build.lock")
         with open(lock_path, "w") as lock_f:
             fcntl.flock(lock_f, fcntl.LOCK_EX)
             try:
                 # Re-check under the lock: another process may have built.
-                if not os.path.exists(lib_path) or _source_newer(
-                    src_path, lib_path
-                ):
+                if not _is_current(lib_path, digest):
                     tmp = lib_path + f".tmp.{os.getpid()}"
                     subprocess.run(
                         [
@@ -64,23 +84,24 @@ def _build_lib(source: str, lib_path: str, link_flags=()) -> bool:
                         capture_output=True,
                     )
                     os.replace(tmp, lib_path)
+                    with open(lib_path + ".srchash", "w") as f:
+                        f.write(digest)
             finally:
                 fcntl.flock(lock_f, fcntl.LOCK_UN)
         return True
-    except (subprocess.CalledProcessError, FileNotFoundError, OSError):
+    except subprocess.CalledProcessError as e:
+        logging.warning(
+            "native build of %s failed (rc=%d):\n%s",
+            source, e.returncode, e.stderr.decode(errors="replace"),
+        )
+        return False
+    except OSError as e:  # no g++, read-only tree, missing source
+        logging.warning("native build of %s failed: %r", source, e)
         return False
 
 
 def _build() -> bool:
     return _build_lib("episode_reader.cc", _LIB_PATH, ("-lz",))
-
-
-def _source_newer(src: str, lib_path: str) -> bool:
-    """Rebuild when the source is newer than the built library."""
-    try:
-        return os.path.getmtime(src) > os.path.getmtime(lib_path)
-    except OSError:
-        return True
 
 
 def get_library() -> Optional[ctypes.CDLL]:

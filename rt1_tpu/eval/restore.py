@@ -109,17 +109,14 @@ def serving_plan(config):
     shards), so `dp` collapses to 1 and the mesh covers exactly the
     fsdp × tp × pp × sp devices model parallelism needs — for the default
     all-ones config that is a 1-device mesh, byte-identical placement to
-    the pre-plan engine. Returns None when jax has no initialized backend
-    yet (callers treat that as plain placement).
+    the pre-plan engine. A backend that fails to initialize (no chip, or
+    a chip another process holds) raises here, by name, at startup.
     """
     import jax
 
     from rt1_tpu.parallel import ShardingPlan
 
-    try:
-        devices = jax.local_devices()
-    except RuntimeError:  # no initialized backend — plain placement
-        return None
+    devices = jax.local_devices()
     # One resolver with train (`auto` resolves against THIS host's devices,
     # the data axis collapses — sessions are slots, not shards); see
     # ShardingPlan.from_config(collapse_data=True).

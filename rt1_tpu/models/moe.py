@@ -77,7 +77,8 @@ class MoEFeedForward(nn.Module):
         capacity = int(self.capacity_factor * t / e) or 1
         position_in_expert = (jnp.cumsum(one_hot, axis=0) - 1.0) * one_hot
         pos_one_hot = jax.nn.one_hot(   # (t, c); out-of-range (≥ capacity)
-            position_in_expert.sum(axis=-1), capacity, dtype=jnp.float32
+            position_in_expert.sum(axis=-1).astype(jnp.int32),  # exact: counts
+            capacity, dtype=jnp.float32,
         )                               # rows are all-zero → token dropped
         dispatch = one_hot[:, :, None] * pos_one_hot[:, None, :]  # (t, e, c)
 

@@ -102,7 +102,7 @@ class RT1Policy(nn.Module):
     # (1 - p_label)^gamma. 0 disables (reference parity). BC on smooth
     # scripted demos concentrates labels on a few near-center buckets, so a
     # near-constant policy already scores low CE (the "copycat" collapse
-    # diagnosed in RESULTS.md round 2); gamma > 0 down-weights those easy
+    # diagnosed in round 2); gamma > 0 down-weights those easy
     # marginal tokens and shifts gradient onto the rare directional ones.
     focal_gamma: float = 0.0
     # Soft-argmax auxiliary regression: loss += w * MSE(E[a], a_true) where
@@ -128,7 +128,8 @@ class RT1Policy(nn.Module):
     # sequence over the mesh's ``seq`` axis (sequence/context parallelism
     # for long-horizon variants; requires `mesh` with a >1 seq axis).
     # "pallas" fuses inference attention into one VMEM kernel on TPU
-    # (training and non-TPU backends fall back to dense).
+    # (training takes the dense math: the kernel is forward-only; off-TPU
+    # it raises unless `pallas_interpret`).
     attention_impl: str = "dense"
     mesh: Optional[Any] = None
     pallas_interpret: bool = False  # test-only: run the kernel off-TPU

@@ -59,12 +59,13 @@ class TFMultiHeadAttention(nn.Module):
     dropout_rate: float = 0.1
     dtype: jnp.dtype = jnp.float32
     # "dense" | "ring" | "pallas". "ring" needs `mesh` with a >1 seq axis;
-    # "pallas" is the fused inference kernel — used only when train=False on
-    # a TPU backend (gradients and non-TPU backends fall back to dense).
+    # "pallas" is the fused inference kernel: it runs whenever train=False
+    # (it has no autodiff rule, so train=True takes the dense math) and
+    # raises off-TPU unless `pallas_interpret` is set.
     attention_impl: str = "dense"
     mesh: Optional[Any] = None
-    # Test escape hatch: run the pallas kernel in interpreter mode off-TPU
-    # (orders of magnitude slower than dense; never set in production).
+    # Run the pallas kernel in interpreter mode (tests off-TPU; orders of
+    # magnitude slower than dense; never set in production).
     pallas_interpret: bool = False
 
     @nn.compact
@@ -117,17 +118,18 @@ class TFMultiHeadAttention(nn.Module):
             new_cache = jnp.stack([k_cache, v_cache], axis=1)
             return QuantDense(self.d_model, dtype=self.dtype, name="out")(out), new_cache
 
-        use_pallas = (
-            self.attention_impl == "pallas"
-            and not train  # forward-only kernel: no autodiff rule
-            and (
-                _jax.default_backend() == "tpu" or self.pallas_interpret
-            )
-        )
-        if use_pallas:
+        # Forward-only kernel (no autodiff rule): train=True takes the
+        # dense math below.
+        if self.attention_impl == "pallas" and not train:
             # Fused VMEM kernel (rt1_tpu/parallel/flash_attention.py).
             from rt1_tpu.parallel.flash_attention import fused_attention
 
+            if not self.pallas_interpret and _jax.default_backend() != "tpu":
+                raise RuntimeError(
+                    'attention_impl="pallas" needs a TPU backend (found '
+                    f"{_jax.default_backend()!r}); set pallas_interpret=True "
+                    "to run the kernel in interpreter mode"
+                )
             if mask is not None and mask.ndim != 2:
                 raise ValueError("pallas attention supports (s, s) masks only")
             out = fused_attention(
@@ -136,7 +138,7 @@ class TFMultiHeadAttention(nn.Module):
                 v,
                 mask=mask,
                 scale=1.0 / float(k) ** 0.5,
-                interpret=_jax.default_backend() != "tpu",
+                interpret=self.pallas_interpret,
             )
             out = out.reshape(b, s, h * k)
             return QuantDense(self.d_model, dtype=self.dtype, name="out")(out), None

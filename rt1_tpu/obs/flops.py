@@ -17,9 +17,9 @@ Two analysis paths, deliberately distinct:
   numbers ``bench.py --mode mfu`` has always published. Bench keeps this
   path so its baselines stay comparable.
 
-Peak FLOP/s defaults to a v5e chip's bf16 197 TFLOP/s; override with the
-``RT1_TPU_PEAK_FLOPS`` env var for other generations (same knob bench has
-always honored).
+Peak FLOP/s comes from :data:`PEAK_FLOPS_BY_DEVICE_KIND`, keyed by jax's
+``device_kind``. A device that is not in the table has no peak and so no
+MFU: :func:`peak_flops` warns, naming the device, and returns None.
 
 Import-light by contract: stdlib at module scope, jax only inside the
 functions that analyze a program (pinned by tests/test_obs_imports.py).
@@ -27,31 +27,37 @@ functions that analyze a program (pinned by tests/test_obs_imports.py).
 
 from __future__ import annotations
 
-import os
+import logging
 from typing import Any, Dict, Optional
 
-#: Default peak FLOP/s assumed for MFU: one v5e chip's bf16 peak.
-DEFAULT_PEAK_FLOPS = 197e12
-
-PEAK_FLOPS_ENV = "RT1_TPU_PEAK_FLOPS"
-
-
-def default_peak_flops() -> float:
-    """Peak FLOP/s per chip: ``RT1_TPU_PEAK_FLOPS`` env or the v5e default."""
-    return float(os.environ.get(PEAK_FLOPS_ENV, DEFAULT_PEAK_FLOPS))
+#: Peak dense bf16 FLOP/s of ONE chip, keyed by ``jax.Device.device_kind``.
+#: Source: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16 per
+#: chip); jax reports that chip as "TPU v5 lite".
+PEAK_FLOPS_BY_DEVICE_KIND = {
+    "TPU v5 lite": 197e12,
+}
 
 
-def cost_analysis_flops(cost: Any) -> float:
-    """Pull the 'flops' entry out of a jax cost-analysis result.
+def peak_flops(device_kind: str) -> Optional[float]:
+    """Peak FLOP/s of one `device_kind` chip; None (and a warning) if unknown."""
+    peak = PEAK_FLOPS_BY_DEVICE_KIND.get(device_kind)
+    if peak is None:
+        logging.warning(
+            "no peak FLOP/s known for device_kind %r: MFU is not reported "
+            "(add it to rt1_tpu.obs.flops.PEAK_FLOPS_BY_DEVICE_KIND with "
+            "its source)",
+            device_kind,
+        )
+    return peak
 
-    Handles both shapes jax has returned over versions: a plain dict, or a
-    one-element list/tuple of dicts (one per XLA computation).
+
+def cost_analysis_flops(cost: Optional[Dict[str, float]]) -> float:
+    """The 'flops' entry of a jax ``cost_analysis()`` result.
+
+    jax 0.9 returns one dict, or None where the backend has no analysis
+    (it catches the backend's NotImplementedError itself).
     """
-    if cost is None:
-        return 0.0
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return float(cost.get("flops", 0.0))
+    return float(cost.get("flops", 0.0)) if cost else 0.0
 
 
 def train_step_flops(
@@ -61,45 +67,41 @@ def train_step_flops(
 
     `args` may be concrete arrays or ``jax.ShapeDtypeStruct`` avals (the
     train loop passes avals so no device transfer happens). Returns None
-    when the analysis is unavailable or reports zero — callers treat that
-    as "no MFU gauge", never as a real measurement.
+    when the analysis reports no FLOPs — callers treat that as "no MFU
+    gauge", never as a real measurement. Lowering and compile errors
+    propagate: the same program is about to run, so they are real.
     """
-    try:
-        lowered = jitted_fn.lower(*args)
-        target = lowered.compile() if compile else lowered
-        flops = cost_analysis_flops(target.cost_analysis())
-    except Exception:  # noqa: BLE001 - an estimator must never kill a run
-        return None
+    lowered = jitted_fn.lower(*args)
+    target = lowered.compile() if compile else lowered
+    flops = cost_analysis_flops(target.cost_analysis())
     return flops if flops > 0 else None
 
 
 def mfu_pct(
     flops_per_step: float,
     sec_per_step: float,
-    n_chips: int = 1,
-    peak_flops: Optional[float] = None,
+    n_chips: int,
+    peak_flops: float,
 ) -> float:
     """Model-FLOPs-utilization in percent: achieved / peak FLOP/s."""
     if sec_per_step <= 0 or flops_per_step <= 0:
         return 0.0
-    peak = default_peak_flops() if peak_flops is None else float(peak_flops)
     n = max(int(n_chips), 1)
-    return flops_per_step / sec_per_step / (peak * n) * 100.0
+    return flops_per_step / sec_per_step / (float(peak_flops) * n) * 100.0
 
 
 def mfu_detail(
     flops_per_step: float,
     sec_per_step: float,
-    n_chips: int = 1,
-    peak_flops: Optional[float] = None,
+    n_chips: int,
+    peak_flops: float,
 ) -> Dict[str, float]:
-    """The stderr detail dict bench has always printed next to the metric."""
-    peak = default_peak_flops() if peak_flops is None else float(peak_flops)
+    """The stderr detail dict bench prints next to the metric."""
     return {
         "flops_per_step": float(flops_per_step),
         "sec_per_step": round(float(sec_per_step), 6),
-        "peak_flops_assumed": peak,
+        "peak_flops": float(peak_flops),
         "mfu_pct": round(
-            mfu_pct(flops_per_step, sec_per_step, n_chips, peak), 3
+            mfu_pct(flops_per_step, sec_per_step, n_chips, peak_flops), 3
         ),
     }

@@ -184,7 +184,8 @@ class GoodputLedger:
         peak_flops: Optional[float] = None,
         n_chips: int = 1,
     ) -> None:
-        """Arm the MFU gauge (flops=None leaves it disarmed)."""
+        """Arm the MFU gauge; it stays disarmed unless both the step's
+        `flops` and the chip's `peak_flops` (obs/flops.py table) are known."""
         with self._lock:
             self._flops_per_step = float(flops) if flops else None
             self._peak_flops = peak_flops
@@ -210,7 +211,7 @@ class GoodputLedger:
             flops, steps = self._flops_per_step, self._steps_productive
             step_s = self._buckets["step"]
             peak, n_chips = self._peak_flops, self._n_chips
-        if not flops or steps <= 0 or step_s <= 0:
+        if not flops or not peak or steps <= 0 or step_s <= 0:
             return None
         from rt1_tpu.obs import flops as flops_lib
 

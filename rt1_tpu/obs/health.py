@@ -37,7 +37,7 @@ unpack the fetched vector by position. Entries:
 * ``health/update_norm_global``    — global L2 of the applied update.
 * ``health/logit_entropy``         — mean action-token softmax entropy in
   nats (0 = deterministic collapse, log(vocab) = uniform; the copycat
-  collapse diagnosed in RESULTS.md shows up here first).
+  collapse diagnosed in round 2 shows up here first).
 * ``health/token_acc/dim<k>``      — per-action-dimension token accuracy
   of the argmax prediction against the label, one entry per action token.
 * ``health/task_loss/<task>`` / ``health/task_acc/<task>`` /

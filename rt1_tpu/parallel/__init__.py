@@ -15,6 +15,7 @@ eval, and serve — dense/fsdp/tp/pp are config switches, not code paths.
 
 from rt1_tpu.parallel.distributed import (
     DistributedOptions,
+    describe_devices,
     initialize_from_config,
     is_primary,
 )
@@ -49,6 +50,7 @@ __all__ = [
     "PlanCoverageError",
     "ShardingPlan",
     "auto_mesh_shape",
+    "describe_devices",
     "initialize_from_config",
     "is_primary",
     "make_mesh",

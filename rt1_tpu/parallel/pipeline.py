@@ -35,10 +35,6 @@ from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
-try:  # jax >= 0.6 promotes shard_map to the top-level namespace
-    from jax import shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -177,12 +173,12 @@ def pipeline_apply(
     from rt1_tpu.parallel import plan as planlib
 
     stacked_params = planlib.pipeline_stack_placement(stacked_params, mesh)
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(stage_axis), batch_spec),
         out_specs=batch_spec,
-        check_rep=False,
+        check_vma=False,
     )(stacked_params, x)
 
 

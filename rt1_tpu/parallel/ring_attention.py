@@ -27,11 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.6 promotes shard_map to the top-level namespace
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 NEG_INF = -1e9
 
 
@@ -136,17 +131,13 @@ def ring_attention(
     body = functools.partial(
         _ring_attention_local, axis_name=seq_axis, scale=scale
     )
-    kwargs = dict(
+    return jax.shard_map(
+        body,
         mesh=mesh,
         in_specs=(qkv_spec, qkv_spec, qkv_spec, mask_spec if mask is not None else None),
         out_specs=qkv_spec,
-    )
-    try:
-        # jax >= 0.6 renamed the replication check flag check_rep -> check_vma.
-        mapped = _shard_map(body, check_vma=False, **kwargs)
-    except TypeError:
-        mapped = _shard_map(body, check_rep=False, **kwargs)
-    return mapped(q, k, v, mask)
+        check_vma=False,
+    )(q, k, v, mask)
 
 
 def dense_attention_reference(q, k, v, mask=None, scale=None):

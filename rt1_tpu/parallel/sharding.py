@@ -38,6 +38,20 @@ def batch_sharding(mesh: Mesh, axis: str = "data") -> NamedSharding:
     return NamedSharding(mesh, P(axis))
 
 
+def per_device_bytes(tree: Any) -> dict:
+    """Bytes each addressable device actually holds of `tree`'s arrays.
+
+    Read off `addressable_shards` — what the runtime placed, not what a
+    plan asked for. Compare with `sum(leaf.nbytes)`: a sharded tree holds
+    less than that on every device, a replicated one holds all of it.
+    """
+    out: dict = {}
+    for leaf in jax.tree.leaves(tree):
+        for shard in getattr(leaf, "addressable_shards", ()):
+            out[shard.device.id] = out.get(shard.device.id, 0) + shard.data.nbytes
+    return out
+
+
 def rt1_parameter_rules() -> List[Rule]:
     """Path-regex → PartitionSpec for RT1Policy parameters: the full
     declarative plan (plan.py), one rule list for every param group.

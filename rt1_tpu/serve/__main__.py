@@ -79,6 +79,25 @@ def main(argv):
 
     compilation_cache.enable_persistent_cache()
 
+    FLAGS = flags.FLAGS
+    # First touch of the accelerator, before the heavy imports: a replica
+    # that cannot have it says so at once — on stdout too, where a fleet
+    # supervisor reads its replicas' status lines.
+    from rt1_tpu.parallel.distributed import describe_devices
+
+    try:
+        device = describe_devices()
+    except RuntimeError as exc:
+        print(
+            json.dumps({
+                "status": "failed",
+                "replica_id": FLAGS.replica_id,
+                "error": str(exc),
+            }),
+            flush=True,
+        )
+        raise
+
     from rt1_tpu.eval.embedding import get_embedder
     from rt1_tpu.eval.restore import build_serve_engine
     from rt1_tpu.serve.server import (
@@ -87,7 +106,6 @@ def main(argv):
         make_server,
     )
 
-    FLAGS = flags.FLAGS
     config = FLAGS.config
     if not FLAGS.random_init and not FLAGS.allow_embedder_mismatch:
         # Same guard as eval/main.py: serving a checkpoint with a different
@@ -189,6 +207,7 @@ def main(argv):
                 "status": "serving",
                 "host": httpd.server_address[0],
                 "port": httpd.server_address[1],
+                **device,
                 "replica_id": FLAGS.replica_id,
                 "checkpoint_step": step,
                 "max_sessions": engine.max_sessions,

@@ -237,6 +237,7 @@ class FleetSupervisor:
         if self.extra_env:
             env.update(self.extra_env)
         replica.url = None
+        replica.boot_error = None
         replica.state = STARTING
         replica.consecutive_probe_failures = 0
         try:
@@ -253,16 +254,18 @@ class FleetSupervisor:
                 # copy open would leak one fd per (re)spawn.
                 stderr.close()
         replica.spawned_at = time.monotonic()
-        threading.Thread(
+        replica.stdout_thread = threading.Thread(
             target=self._read_ready_line,
             args=(replica, replica.proc),
             name=f"rt1-fleet-stdout-{replica.id}",
             daemon=True,
-        ).start()
+        )
+        replica.stdout_thread.start()
 
     def _read_ready_line(self, replica: Replica, proc) -> None:
-        """Parse `{"status": "serving", "port": ...}` off the replica's
-        stdout, then keep draining so the pipe never fills."""
+        """Parse `{"status": "serving", "port": ...}` — or the replica's
+        `{"status": "failed", "error": ...}` — off its stdout, then keep
+        draining so the pipe never fills."""
         try:
             for line in proc.stdout:
                 if replica.url is None:
@@ -273,6 +276,8 @@ class FleetSupervisor:
                     if ready.get("status") == "serving":
                         host = ready.get("host", "127.0.0.1")
                         replica.url = f"http://{host}:{ready['port']}"
+                    elif ready.get("status") == "failed":
+                        replica.boot_error = ready.get("error")
         except (ValueError, OSError):
             pass  # closed pipe on kill/shutdown
 
@@ -322,12 +327,20 @@ class FleetSupervisor:
                 if replica.id not in pending:
                     continue
                 if replica.proc.poll() is not None:
+                    # Let the reader reach EOF: the replica's last line
+                    # may say why it gave up.
+                    replica.stdout_thread.join(timeout=2.0)
                     raise RuntimeError(
                         f"replica {replica.id} exited rc="
                         f"{replica.proc.returncode} during warm-up"
                         + (
                             f" (see {self.log_dir})"
                             if self.log_dir
+                            else ""
+                        )
+                        + (
+                            f": {replica.boot_error}"
+                            if replica.boot_error
                             else ""
                         )
                     )

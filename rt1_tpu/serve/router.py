@@ -188,6 +188,10 @@ class Replica:
         self.tier = TIER_BASE
         self.dtype: Optional[str] = None
         self.spawned_at: Optional[float] = None
+        # Why the process gave up before serving, from its own
+        # `{"status": "failed"}` stdout line (e.g. the chip is held).
+        self.boot_error: Optional[str] = None
+        self.stdout_thread = None  # the supervisor's ready-line reader
 
     def summary(self) -> Dict[str, Any]:
         return {

@@ -41,6 +41,9 @@ def get_config():
     config.model.remat = False
     # Attention implementation: "dense" (reference parity), "ring" (sequence-
     # parallel over the mesh's 'seq' axis), "pallas" (fused inference kernel).
+    # The pallas kernel is forward-only (no autodiff rule): under "pallas"
+    # the train step still runs the dense math and only inference/serving
+    # runs the kernel, which needs a TPU (it raises elsewhere).
     config.model.attention_impl = "dense"
     # GPipe microbatches per step when mesh.stage > 1 (parallel/pipeline.py).
     config.model.pipeline_microbatches = 4

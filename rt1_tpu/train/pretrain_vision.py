@@ -5,8 +5,8 @@ EfficientNet-B3 weights
 (`/root/reference/pytorch_robotics_transformer/film_efficientnet/
 film_efficientnet_encoder.py:376-425`); this image carries no pretrained
 blobs and no network, so every arm so far trained vision from scratch —
-and round 4 concluded the learning failure is perception-limited
-(RESULTS.md). This module is the in-image substitute (VERDICT r4 next #3):
+and round 4 concluded the learning failure is perception-limited.
+This module is the in-image substitute (VERDICT r4 next #3):
 the simulator generates unlimited (frame, block/effector position) pairs
 for free, so the encoder can be pretrained on *state regression* — exactly
 the visual competence the policy needs — and then grafted into the RT-1

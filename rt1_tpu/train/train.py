@@ -16,6 +16,7 @@ Run:
 from __future__ import annotations
 
 import functools
+import json
 import os
 from typing import Iterator, Optional
 
@@ -491,9 +492,17 @@ def train_and_evaluate(config, workdir: str):
     # resolves against the global device set, and a post-backend-init
     # rendezvous is too late). No-op unless `config.parallel.distributed`
     # is enabled; idempotent across runs in one process.
-    from rt1_tpu.parallel import initialize_from_config
+    from rt1_tpu.parallel import describe_devices, initialize_from_config
 
     initialize_from_config(config)
+
+    from absl import logging
+
+    device = describe_devices()
+    logging.info(
+        "devices: platform=%s device_kind=%s count=%d",
+        device["platform"], device["device_kind"], device["device_count"],
+    )
 
     # Observability first: the tracer must be live before dataset_batches
     # spawns feeder workers, or their assembly spans are lost.
@@ -609,8 +618,6 @@ def train_and_evaluate(config, workdir: str):
 
         manifest = read_manifest(config.data.data_dir)
         if manifest is not None and jax.process_index() == 0:
-            import json
-
             os.makedirs(workdir, exist_ok=True)
             with open(os.path.join(workdir, "data_manifest.json"), "w") as f:
                 json.dump(manifest, f, indent=2, sort_keys=True)
@@ -711,12 +718,22 @@ def train_and_evaluate(config, workdir: str):
         check_coverage=config.model.get("family", "rt1") == "rt1",
     )
     state = fns.shard_state(state)
+    # What the runtime placed, read off the shards (not the plan): under
+    # fsdp/tp every device holds less than the replicated total.
+    from rt1_tpu.parallel.sharding import per_device_bytes
+
+    placed = (state.params, state.opt_state)
+    logging.info(
+        "state placement: params+opt_state total_bytes=%d per_device_bytes=%s",
+        sum(leaf.nbytes for leaf in jax.tree.leaves(placed)),
+        json.dumps(per_device_bytes(placed), sort_keys=True),
+    )
 
     if ledger is not None and obs_opts.goodput_mfu:
         # Arm the live MFU gauge: FLOPs per step from XLA cost analysis of
         # the LOWERED step program — avals only, so no second compile and
-        # no extra device transfer; a failed estimate just disarms the
-        # gauge (obs/flops.py returns None).
+        # no extra device transfer. The gauge stays disarmed when the
+        # analysis reports no FLOPs or the device has no known peak.
         with obs.trace.span("goodput_flops_estimate"):
             batch_tpl = jax.tree.map(
                 lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
@@ -732,7 +749,11 @@ def train_and_evaluate(config, workdir: str):
                 flops = obs.flops.train_step_flops(
                     fns.train_step, state, batch_tpl, rng_tpl
                 )
-            ledger.set_flops_per_step(flops, n_chips=jax.device_count())
+            ledger.set_flops_per_step(
+                flops,
+                peak_flops=obs.flops.peak_flops(device["device_kind"]),
+                n_chips=jax.device_count(),
+            )
 
     eval_iter = None
     if config.eval_every_steps:
@@ -1146,6 +1167,12 @@ def train_and_evaluate(config, workdir: str):
 
     ckpt.wait_until_finished()
     writer.flush()
+    memory = jax.local_devices()[0].memory_stats()
+    if memory and "peak_bytes_in_use" in memory:  # None on the CPU backend
+        logging.info(
+            "device memory: peak_bytes_in_use=%d stats=%s",
+            memory["peak_bytes_in_use"], json.dumps(memory, sort_keys=True),
+        )
     # Refresh the summary the cleanup stack already wrote: the async final
     # checkpoint's wait and the teardown itself belong in the totals.
     _write_goodput()
@@ -1171,6 +1198,12 @@ def main(argv):
 
     from absl import flags, logging
     from ml_collections import config_flags
+
+    from rt1_tpu import compilation_cache
+
+    # A cold flagship train-step compile costs minutes on the chip; cached,
+    # a relaunch (resume, preemption restart) starts in seconds.
+    compilation_cache.enable_persistent_cache()
 
     FLAGS = flags.FLAGS
     config = FLAGS.config

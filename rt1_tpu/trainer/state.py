@@ -56,18 +56,37 @@ def create_train_state(
     tx: optax.GradientTransformation,
     init_fn: Optional[Callable] = None,
 ) -> TrainState:
-    """Initialize params (+ batch_stats) from an example (observations, actions)."""
+    """Initialize params (+ batch_stats) from an example (observations, actions).
+
+    On an accelerator init runs as ONE jitted program: eagerly, the
+    flagship's ~1,600 initializer and `zeros_like` calls each compile a tiny
+    program of their own — minutes of a cold start on the chip, and none of
+    them reaches the persistent cache's compile-time floor, so every
+    relaunch paid them again (PERF.md, PR 21). The CPU backend dispatches
+    those ops in microseconds and reuses them across calls, while one big
+    program would be compiled anew on every call (the test suite makes
+    hundreds): there init stays op by op. Same values either way
+    (tests/test_trainer.py).
+    """
     obs, actions = example_batch
-    if init_fn is None:
-        variables = model.init({"params": rng, "crop": rng}, obs, actions, train=False)
-    else:
-        variables = init_fn(model, rng, obs, actions)
-    params = variables["params"]
-    batch_stats = variables.get("batch_stats", {})
+
+    def init(rng, obs, actions):
+        if init_fn is None:
+            variables = model.init(
+                {"params": rng, "crop": rng}, obs, actions, train=False
+            )
+        else:
+            variables = init_fn(model, rng, obs, actions)
+        params = variables["params"]
+        return params, variables.get("batch_stats", {}), tx.init(params)
+
+    if jax.default_backend() != "cpu":
+        init = jax.jit(init)
+    params, batch_stats, opt_state = init(rng, obs, actions)
     return TrainState(
         step=jnp.zeros((), jnp.int32),
         params=params,
         batch_stats=batch_stats,
-        opt_state=tx.init(params),
+        opt_state=opt_state,
         tx=tx,
     )

@@ -115,3 +115,27 @@ def test_rt1_policy_pallas_infer_matches_dense():
         np.asarray(out_p["action_logits"]),
         atol=1e-4,
     )
+
+
+def test_pallas_off_tpu_without_interpret_raises():
+    """attention_impl="pallas" must run the kernel or fail: off-TPU without
+    pallas_interpret it raises instead of quietly running dense, while the
+    train-time forward (the kernel has no autodiff rule) stays dense."""
+    import pytest
+
+    from rt1_tpu.models.transformer import TFMultiHeadAttention
+
+    attn = TFMultiHeadAttention(
+        key_dim=8, num_heads=2, d_model=16, attention_impl="pallas"
+    )
+    x = jnp.ones((1, 16, 16), jnp.float32)
+    variables = attn.clone(attention_impl="dense").init(
+        jax.random.PRNGKey(0), x
+    )
+    assert jax.default_backend() != "tpu"
+    with pytest.raises(RuntimeError, match='attention_impl="pallas" needs a TPU'):
+        attn.apply(variables, x, train=False)
+    out, _ = attn.apply(
+        variables, x, train=True, rngs={"dropout": jax.random.PRNGKey(1)}
+    )
+    assert np.isfinite(np.asarray(out)).all()

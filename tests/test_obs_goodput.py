@@ -161,6 +161,29 @@ def test_mfu_gauge_arithmetic(clock):
     assert led.mfu_pct() is None  # disarm again
 
 
+def test_unknown_device_kind_has_no_peak_and_no_mfu(clock, caplog):
+    """An unknown device gets no MFU gauge and a warning naming it — never
+    a default peak (obs/flops.py); the one known chip has its table row."""
+    from rt1_tpu.obs import flops
+
+    assert flops.peak_flops("TPU v5 lite") == 197e12
+    with caplog.at_level("WARNING"):
+        assert flops.peak_flops("Imaginary TPU v99") is None
+    assert "Imaginary TPU v99" in caplog.text
+    assert not hasattr(flops, "DEFAULT_PEAK_FLOPS")
+
+    led = GoodputLedger(clock=clock)
+    led.set_flops_per_step(
+        1e12, peak_flops=flops.peak_flops("Imaginary TPU v99"), n_chips=1
+    )
+    led.note_step(_step_record(100.0))  # compile
+    clock.advance(0.1)
+    led.note_step(_step_record(100.0))
+    assert led.mfu_pct() is None
+    assert "mfu_pct" not in led.summary()
+    assert "goodput/mfu_pct" not in led.scalars()
+
+
 def test_summary_json_roundtrip(tmp_path, clock):
     led = GoodputLedger(clock=clock)
     with led.phase("init"):

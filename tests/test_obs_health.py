@@ -354,11 +354,23 @@ def test_train_loop_emits_per_task_health_live(tmp_path):
 
 
 @pytest.mark.slow
-def test_train_loop_emits_health_goodput_and_report(tmp_path):
+def test_train_loop_emits_health_goodput_and_report(tmp_path, monkeypatch):
     """Integration over the tiny synthetic config: health/* scalars land in
     the TB events, goodput_summary.json's buckets sum to 100%±1 with a live
     MFU gauge, and run_report merges both into one report."""
     import sys
+
+    import jax
+
+    from rt1_tpu.obs import flops as flops_lib
+
+    # The MFU gauge arms only for a device with a known peak; give the
+    # test's CPU one so the gauge's plumbing is exercised.
+    monkeypatch.setitem(
+        flops_lib.PEAK_FLOPS_BY_DEVICE_KIND,
+        jax.devices()[0].device_kind,
+        1e12,
+    )
 
     from rt1_tpu.train.configs import tiny
     from rt1_tpu.train.train import train_and_evaluate

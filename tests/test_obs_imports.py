@@ -55,7 +55,8 @@ assert names[-1] == "health/token_acc/dim1"
 assert obs.health.unpack(("x",), [1.5]) == {"x": 1.5}
 
 assert obs.flops.mfu_pct(100.0, 1.0, n_chips=1, peak_flops=1000.0) == 10.0
-assert obs.flops.cost_analysis_flops([{"flops": 3.0}]) == 3.0
+assert obs.flops.cost_analysis_flops({"flops": 3.0}) == 3.0
+assert obs.flops.cost_analysis_flops(None) == 0.0
 
 from rt1_tpu.serve.metrics import ServeMetrics
 

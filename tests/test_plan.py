@@ -229,11 +229,12 @@ def test_plan_from_config_auto_composes_with_pp():
     }
 
 
-def test_serving_plan_honors_auto_and_backend_fallback(monkeypatch):
+def test_serving_plan_honors_auto_and_backend_failure_raises(monkeypatch):
     """serving_plan resolves `auto` against the serve host's own device
     count (data axis collapsed — sessions are slots, not shards) instead of
-    silently serving dense, and returns None (plain placement) when jax has
-    no initialized backend — the documented fallback."""
+    silently serving dense, and a backend that fails to initialize (no
+    chip, or one another process holds) raises by name instead of quietly
+    serving with plain placement."""
     from rt1_tpu.eval import restore as R
 
     plan = R.serving_plan({"parallel": {"auto": True}})
@@ -246,7 +247,8 @@ def test_serving_plan_honors_auto_and_backend_fallback(monkeypatch):
         raise RuntimeError("Backend 'cpu' failed to initialize")
 
     monkeypatch.setattr(jax, "local_devices", _no_backend)
-    assert R.serving_plan({"parallel": {"auto": True}}) is None
+    with pytest.raises(RuntimeError, match="failed to initialize"):
+        R.serving_plan({"parallel": {"auto": True}})
 
 
 def test_indivisible_dims_fall_back_to_replication():

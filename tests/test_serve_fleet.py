@@ -469,3 +469,25 @@ def test_fleet_chaos_loadgen_real_replicas(tmp_path):
     summary_path = output.parent / "slo_summary.json"
     assert summary_path.exists()
     assert json.loads(summary_path.read_text()) == slo
+
+
+def test_replica_that_cannot_boot_is_named_by_the_fleet():
+    """A replica that gives up before serving (on a TPU host: the chip is
+    held by its sibling — one process per chip) prints a
+    `{"status": "failed"}` line; the supervisor's warm-up error carries
+    that reason instead of a bare exit code."""
+
+    def _failing_argv(replica_id):
+        return [
+            sys.executable, "-c",
+            "import json; print(json.dumps({'status': 'failed', 'error': "
+            "'cannot initialize the accelerator backend: chip held'}), "
+            "flush=True); raise SystemExit(1)",
+        ]
+
+    supervisor = FleetSupervisor(
+        Router(replica_timeout_s=10.0), _failing_argv, 1,
+        poll_interval_s=0.1, chaos_interval_s=3600.0, warmup_timeout_s=30.0,
+    )
+    with pytest.raises(RuntimeError, match="exited rc=1 .*chip held"):
+        supervisor.start(wait_ready=True)

@@ -1,0 +1,108 @@
+"""Ahead-of-time compiles for the described (not attached) TPU v5e.
+
+The chip's own compiler is installed in the sandbox and compiles for a
+topology description, so the kernels of the main path are refused HERE, at
+no chip time, when a change breaks their tiling, VMEM budget or
+partitioning — none of which interpret mode can see. Nothing runs: a
+passing compile is not a chip run. Skipped where the topology cannot be
+described (no libtpu).
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from rt1_tpu.models.action_tokenizer import tokens_per_action
+from rt1_tpu.parallel.flash_attention import fused_attention
+from rt1_tpu.parallel.ring_attention import ring_attention
+from rt1_tpu.serve.engine import pow2_buckets
+from rt1_tpu.specs import language_table_action_space
+from rt1_tpu.train.configs import language_table
+
+# Flagship decoder attention, read off the config the trainer and server
+# run: window 6 x (8 image + 3 action tokens) = 66 positions, 8 heads of
+# key_dim (= layer_size) 128.
+_MODEL = language_table.get_config().model
+SEQ = _MODEL.time_sequence_length * (
+    _MODEL.num_image_tokens + tokens_per_action(language_table_action_space())
+)
+HEADS, HEAD_DIM = _MODEL.num_heads, _MODEL.layer_size
+# Default `python -m rt1_tpu.serve` ladder: --max_sessions 8, --buckets auto.
+BUCKETS = pow2_buckets(8)
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as exc:  # noqa: BLE001 - any failure to describe = skip
+        pytest.skip(f"TPU topology cannot be described here: {exc!r}")
+    return topo
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    """A compile for a described device is written to the persistent cache
+    but cannot be read back without the chip; keep these out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("masked", [True, False], ids=["mask", "nomask"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("batch", BUCKETS)
+def test_fused_attention_compiles_for_v5e(v5e, batch, dtype, masked):
+    """The Pallas kernel lowers to Mosaic at the flagship shape for every
+    serve bucket size — and the program really holds the kernel."""
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    qkv = jax.ShapeDtypeStruct(
+        (batch, SEQ, HEADS, HEAD_DIM), dtype, sharding=one_chip
+    )
+    args = [qkv, qkv, qkv]
+    if masked:
+        args.append(jax.ShapeDtypeStruct((SEQ, SEQ), jnp.int32, sharding=one_chip))
+
+    def attend(q, k, v, mask=None):
+        return fused_attention(q, k, v, mask=mask)
+
+    compiled = jax.jit(attend).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_ring_attention_compiles_on_four_chip_mesh(v5e):
+    """One small program across the four described chips: ring attention
+    over a dp 2 x seq 2 mesh must hold the K/V rotation collective."""
+    mesh = Mesh(
+        np.array(v5e.devices).reshape(2, 2),
+        axis_names=("data", "seq"),
+    )
+    qkv = jax.ShapeDtypeStruct(
+        (4, 64, HEADS, HEAD_DIM),
+        jnp.bfloat16,
+        sharding=NamedSharding(mesh, P("data", "seq", None, None)),
+    )
+    mask = jax.ShapeDtypeStruct(
+        (64, 64), jnp.int32, sharding=NamedSharding(mesh, P())
+    )
+
+    def attend(q, k, v, mask):
+        return ring_attention(q, k, v, mesh, mask=mask)
+
+    compiled = jax.jit(attend).lower(qkv, qkv, qkv, mask).compile()
+    assert "collective-permute" in compiled.as_text()
