@@ -381,13 +381,14 @@ class SampleAheadFeeder:
         exactly once per epoch, under the lock — so the whole epoch is
         drawn from one corpus snapshot."""
         e = len(self._epochs)
-        if e > 0 and self.refresh_at_epoch:
-            try:
-                self.cache.refresh()
-            except Exception:  # noqa: BLE001 - keep feeding the old view
-                pass
-        n_windows = len(self.cache.index)
-        order = self._compute_order(e, n_windows)
+        with obs_trace.span("feeder/epoch", epoch=e):
+            if e > 0 and self.refresh_at_epoch:
+                try:
+                    self.cache.refresh()
+                except Exception:  # noqa: BLE001 - keep feeding the old view
+                    pass
+            n_windows = len(self.cache.index)
+            order = self._compute_order(e, n_windows)
         first = (
             0
             if e == 0
@@ -542,21 +543,27 @@ class SampleAheadFeeder:
                             f"injected fault [feeder_kill]: worker {k} "
                             f"at ticket {ticket}"
                         )
-                # obs: the span makes this worker's assembly visible on the
-                # shared host timeline; no-op (one global read) untraced.
+                # obs: the span puts this worker's assembly on its own line
+                # of a profile (`rt1/feeder/assemble`) and in the host ring;
+                # the ticket is the batch index the consumer asks for.
                 t0 = time.perf_counter()
-                with obs_trace.span("feeder_assemble", ticket=ticket):
+                with obs_trace.span("feeder/assemble", ticket=ticket):
                     batch = self._assemble(ticket)
                 self._assembly_s[k] += time.perf_counter() - t0
                 self._assembled[k] += 1
-                # Bounded put that stays responsive to close(): a plain
-                # q.put would deadlock a full queue against a consumer gone.
-                while not self._stop.is_set():
-                    try:
-                        q.put(batch, timeout=0.1)
-                        break
-                    except queue.Full:
-                        continue
+                try:
+                    q.put_nowait(batch)
+                except queue.Full:
+                    # The feeder is ahead. Bounded put that stays responsive
+                    # to close(): a plain q.put would deadlock a full queue
+                    # against a consumer gone.
+                    with obs_trace.span("feeder/put_wait", ticket=ticket):
+                        while not self._stop.is_set():
+                            try:
+                                q.put(batch, timeout=0.1)
+                                break
+                            except queue.Full:
+                                continue
                 if obs_trace.enabled():
                     obs_trace.counter(
                         "feeder_queue_depth",
@@ -666,12 +673,22 @@ class SampleAheadFeeder:
         t = self._next_ticket
         if self._past_end(t):
             raise StopIteration
+        # obs: where the loop waits for its input. `ready` is how far ahead
+        # the workers were when the loop asked: batches already assembled,
+        # over all queues (capacity num_threads x depth).
+        ready = sum(qq.qsize() for qq in self._queues)
+        with obs_trace.span("feeder/next", ticket=t, ready=ready):
+            batch = self._take(t)
+        self._next_ticket = t + 1
+        return batch
+
+    def _take(self, t: int) -> Dict:
+        """Block until ticket `t`'s batch is in its queue."""
         q = self._queues[t % self.num_threads]
         waited = 0.0
         while True:
             try:
-                batch = q.get(timeout=0.1)
-                break
+                return q.get(timeout=0.1)
             except queue.Empty:
                 if self._stop.is_set():
                     self._raise_or_stop()
@@ -687,8 +704,6 @@ class SampleAheadFeeder:
                     # arrive. Diagnose immediately instead of waiting out
                     # the timeout — or forever, when none is configured.
                     raise self._stalled_error(t, waited)
-        self._next_ticket = t + 1
-        return batch
 
     def _stalled_error(self, ticket: int, waited: float) -> "FeederStalledError":
         alive = [th.name for th in self._threads if th.is_alive()]
@@ -701,7 +716,7 @@ class SampleAheadFeeder:
             f"{depths} (capacity {self.depth} each). A dead worker with no "
             f"stashed error means it deadlocked or was killed without "
             f"unwinding — check the flight-recorder dump and the host "
-            f"trace for its last feeder_assemble span."
+            f"trace for its last feeder/assemble span."
         )
 
     def _raise_or_stop(self) -> None:

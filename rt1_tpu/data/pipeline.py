@@ -26,6 +26,7 @@ from typing import Callable, Dict, Iterator, List, Optional, Sequence, Tuple
 import numpy as np
 
 from rt1_tpu.data import episodes as ep_lib
+from rt1_tpu.obs import trace as obs_trace
 
 
 class WindowedEpisodeDataset:
@@ -388,9 +389,15 @@ def prefetch_to_device(iterator, sharding, depth: int = 2) -> Iterator:
     multi-process runs each host feeds its shard of the global batch
     (`put_global`).
     """
+    import jax
+
     queue = collections.deque()
-    for batch in iterator:
-        queue.append(put_global(batch, sharding))
+    for ticket, batch in enumerate(iterator):
+        # obs: the copy's host side (enqueue; the transfer itself is
+        # asynchronous). The n-th put carries the feeder's n-th batch.
+        nbytes = sum(getattr(x, "nbytes", 0) for x in jax.tree.leaves(batch))
+        with obs_trace.span("h2d/put", ticket=ticket, bytes=nbytes):
+            queue.append(put_global(batch, sharding))
         if len(queue) >= max(depth, 1):
             yield queue.popleft()
     while queue:

@@ -273,7 +273,10 @@ class RT1Policy(nn.Module):
         """image (b, t, H, W, 3), context (b, t, D) or (b, D) → tokens (b, t, I, E)."""
         if context is not None and context.ndim == 2:
             context = jnp.tile(context[:, None, :], (1, image.shape[1], 1))
-        image = self._preprocess_images(image, train)
+        # Device scopes (metadata of the ops, read from a profile): Flax
+        # names every module's ops; what runs outside a module is named here.
+        with jax.named_scope("preprocess"):
+            image = self._preprocess_images(image, train)
         return self.image_tokenizer(image, context=context, train=train)
 
     def _assemble(self, context_image_tokens: jnp.ndarray) -> jnp.ndarray:
@@ -345,68 +348,69 @@ class RT1Policy(nn.Module):
         context_image_tokens = self._tokenize_images(image, context, train)
         logits, scores = self._transformer_logits(context_image_tokens, train)
 
-        labels = action_tokenizer.tokenize(self.action_space, actions, self.vocab_size)
+        with jax.named_scope("loss"):
+            labels = action_tokenizer.tokenize(self.action_space, actions, self.vocab_size)
 
-        # Transformer predicts next token: read logits one position early (:237,304).
-        pred_positions = jnp.asarray(self._action_positions - 1)
-        action_logits = jnp.take(logits, pred_positions, axis=1)
-        action_logits = action_logits.reshape(b, t, self.tokens_per_action, self.vocab_size)
+            # Transformer predicts next token: read logits one position early (:237,304).
+            pred_positions = jnp.asarray(self._action_positions - 1)
+            action_logits = jnp.take(logits, pred_positions, axis=1)
+            action_logits = action_logits.reshape(b, t, self.tokens_per_action, self.vocab_size)
 
-        ce = _softmax_ce_int(action_logits.astype(jnp.float32), labels)  # (b, t, A)
-        loss_terms = ce
-        if self.focal_gamma > 0:
-            # ce = -log p_label, so 1 - p_label = -expm1(-ce); gradients flow
-            # through the modulating factor too (the standard focal-loss
-            # form). The floor keeps the power branch differentiable at
-            # ce == 0 for fractional gamma (x**g has an infinite slope at 0
-            # when g < 1, and saturated easy tokens do reach ce == 0 in fp32).
-            # Only the optimized loss is modulated; the "cross_entropy" aux
-            # output stays raw CE so it remains comparable across gammas.
-            base = jnp.maximum(-jnp.expm1(-ce), 1e-12)
-            loss_terms = base ** self.focal_gamma * ce
-        if self.loss_scale == "reference":
-            num_items = float(b * t) * self.single_step_tokens
-            action_loss = jnp.mean(loss_terms, axis=-1) / num_items  # (b, t), reference :314-320
-        else:
-            action_loss = jnp.mean(loss_terms, axis=-1)
-        loss = jnp.mean(action_loss)  # harness loss_fn (distribute_train.py:112-118)
-
-        out = {
-            "loss": loss,
-            "action_loss": action_loss,
-            "cross_entropy": ce,
-            "action_labels": labels,
-            "action_logits": action_logits,
-            "action_predictions": jnp.argmax(action_logits, axis=-1),
-        }
-        if self.aux_mse_weight > 0:
-            bins, box_mask = action_tokenizer.box_bin_values(
-                self.action_space, self.vocab_size
-            )
-            probs = jax.nn.softmax(
-                action_logits.astype(jnp.float32), axis=-1
-            )  # (b, t, A, V)
-            expected = jnp.einsum("btav,av->bta", probs, jnp.asarray(bins))
-            target = action_tokenizer.continuous_targets(
-                self.action_space, actions
-            )  # (b, t, A)
-            mask = jnp.asarray(box_mask)  # (A,)
-            mse = jnp.sum(
-                jnp.square(expected - target) * mask
-            ) / (jnp.sum(mask) * b * t)
-            # Under 'reference' scaling the CE part is ∝ 1/(b·t·(I+A));
-            # giving the aux term the same normalizer keeps (a) gradient
-            # accumulation exact (the trainer's extra /accum correction
-            # assumes the WHOLE loss is inversely proportional to runtime
-            # batch) and (b) the CE/aux balance independent of batch size
-            # and sequence length. The reported "aux_mse" metric stays the
-            # raw, unit-interpretable mean-squared error.
+            ce = _softmax_ce_int(action_logits.astype(jnp.float32), labels)  # (b, t, A)
+            loss_terms = ce
+            if self.focal_gamma > 0:
+                # ce = -log p_label, so 1 - p_label = -expm1(-ce); gradients flow
+                # through the modulating factor too (the standard focal-loss
+                # form). The floor keeps the power branch differentiable at
+                # ce == 0 for fractional gamma (x**g has an infinite slope at 0
+                # when g < 1, and saturated easy tokens do reach ce == 0 in fp32).
+                # Only the optimized loss is modulated; the "cross_entropy" aux
+                # output stays raw CE so it remains comparable across gammas.
+                base = jnp.maximum(-jnp.expm1(-ce), 1e-12)
+                loss_terms = base ** self.focal_gamma * ce
             if self.loss_scale == "reference":
-                loss = loss + self.aux_mse_weight * mse / num_items
+                num_items = float(b * t) * self.single_step_tokens
+                action_loss = jnp.mean(loss_terms, axis=-1) / num_items  # (b, t), reference :314-320
             else:
-                loss = loss + self.aux_mse_weight * mse
-            out["loss"] = loss
-            out["aux_mse"] = mse
+                action_loss = jnp.mean(loss_terms, axis=-1)
+            loss = jnp.mean(action_loss)  # harness loss_fn (distribute_train.py:112-118)
+
+            out = {
+                "loss": loss,
+                "action_loss": action_loss,
+                "cross_entropy": ce,
+                "action_labels": labels,
+                "action_logits": action_logits,
+                "action_predictions": jnp.argmax(action_logits, axis=-1),
+            }
+            if self.aux_mse_weight > 0:
+                bins, box_mask = action_tokenizer.box_bin_values(
+                    self.action_space, self.vocab_size
+                )
+                probs = jax.nn.softmax(
+                    action_logits.astype(jnp.float32), axis=-1
+                )  # (b, t, A, V)
+                expected = jnp.einsum("btav,av->bta", probs, jnp.asarray(bins))
+                target = action_tokenizer.continuous_targets(
+                    self.action_space, actions
+                )  # (b, t, A)
+                mask = jnp.asarray(box_mask)  # (A,)
+                mse = jnp.sum(
+                    jnp.square(expected - target) * mask
+                ) / (jnp.sum(mask) * b * t)
+                # Under 'reference' scaling the CE part is ∝ 1/(b·t·(I+A));
+                # giving the aux term the same normalizer keeps (a) gradient
+                # accumulation exact (the trainer's extra /accum correction
+                # assumes the WHOLE loss is inversely proportional to runtime
+                # batch) and (b) the CE/aux balance independent of batch size
+                # and sequence length. The reported "aux_mse" metric stays the
+                # raw, unit-interpretable mean-squared error.
+                if self.loss_scale == "reference":
+                    loss = loss + self.aux_mse_weight * mse / num_items
+                else:
+                    loss = loss + self.aux_mse_weight * mse
+                out["loss"] = loss
+                out["aux_mse"] = mse
         if scores is not None:
             out["attention_scores"] = scores
         return out

@@ -11,15 +11,21 @@ production loop itself by bucketing each step's host wall time:
 * ``h2d``         — laying the batch out on device (`jax.device_put`
                     enqueue inside `device_feeder`), i.e. time in
                     ``next(dev_iter)`` *minus* the inner ``wait_data``.
-* ``device_step`` — the jitted step call. Dispatch is asynchronous, so by
-                    default this is host dispatch time and the device's
-                    actual execution hides inside the *next* step's
-                    ``wait_data``/``h2d`` (the queues only back up when the
-                    device is the bottleneck). With ``sync=True`` the
-                    timeline blocks on a step output and the bucket is the
-                    true device latency — exact attribution for ~one extra
-                    sync per step (use for diagnosis, not for the headline
-                    run).
+* ``device_step`` — the jitted step call. **Unless ``sync=True`` this is
+                    host dispatch time, not device time**: dispatch is
+                    asynchronous, and the device's actual execution hides
+                    inside the *next* step's ``wait_data``/``h2d`` (the
+                    queues only back up when the device is the bottleneck).
+                    The span says so: it is ``step/dispatch`` without
+                    ``sync`` and ``step/device_step`` with it; the bucket,
+                    the ``timing/device_step_ms`` scalar and the goodput
+                    ledger keep the one name (dashboards read it). With
+                    ``sync=True`` the timeline blocks on a step output and
+                    the bucket is the true device latency — exact
+                    attribution for ~one extra sync per step (use for
+                    diagnosis, not for the headline run). The device's own
+                    time a step is read from a profile
+                    (docs/observability.md, "One profile").
 * ``host``        — the residual: logging, checkpoint scheduling, Python.
 
 The rolling window turns these into the production `stall_pct` gauge —
@@ -32,6 +38,9 @@ Single-consumer by design: all methods are called from the train loop's
 thread (the timed iterator is pulled from inside ``next(dev_iter)`` on
 that same thread). Feeder workers report through `obs.trace` spans and the
 feeder's own stats, not through this object.
+
+Spans (`obs.trace.span`, so `rt1/<name>` in a profile): ``step`` around the
+whole step and ``step/<bucket>`` around each phase, each with the ``step``.
 """
 
 from __future__ import annotations
@@ -97,7 +106,7 @@ class StepTimeline:
         self._orphan = {}
         self._cur_step = step
         self._t0 = time.perf_counter()
-        self._step_span = trace.span("train_step", step=step)
+        self._step_span = trace.span("step", step=step)
         self._step_span.__enter__()
 
     @contextlib.contextmanager
@@ -111,7 +120,8 @@ class StepTimeline:
         cur = self._cur if self._cur is not None else self._orphan
         inner0 = cur.get(exclusive_of, 0.0) if exclusive_of else 0.0
         t0 = time.perf_counter()
-        with trace.span(bucket):
+        name = "dispatch" if bucket == "device_step" and not self.sync else bucket
+        with trace.span("step/" + name, step=self._cur_step):
             yield
         dt = time.perf_counter() - t0
         if exclusive_of:
@@ -130,7 +140,7 @@ class StepTimeline:
             import jax
 
             t0 = time.perf_counter()
-            with trace.span("device_sync"):
+            with trace.span("step/device_sync", step=self._cur_step):
                 jax.block_until_ready(sync_on)
             self._add("device_step", time.perf_counter() - t0)
         total = time.perf_counter() - self._t0

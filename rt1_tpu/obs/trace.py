@@ -1,20 +1,32 @@
-"""Chrome-trace-event span recorder: one host-side timeline across threads.
+"""The program's host spans: on the profiler's clock, and in a Chrome-trace ring.
 
 The XPlane traces from `jax.profiler` show device ops but are blind to the
 host threads that feed them — the train loop, the `SampleAheadFeeder`
 workers, the serve micro-batcher all spend wall time the device profiler
-cannot attribute. This module records *host* spans from any thread into one
-in-memory ring and serializes them as Chrome trace events (the
-`{"traceEvents": [...]}` JSON that `chrome://tracing` and Perfetto load
-directly), so a single file shows the feeder assembling batch N+2 while
-the train loop blocks on batch N's H2D.
+cannot attribute. `span(name, ...)` therefore does two things:
+
+* it opens a `jax.profiler.TraceAnnotation("rt1/" + name, ...)` (a TraceMe),
+  so whoever runs a profile — the benchmark's tracer,
+  `scripts/profile_train.py`, an operator's `jax.profiler.start_trace` —
+  finds the span in the same `.xplane.pb` as the device's ops, on the same
+  clock, on the line of the thread that opened it. While no profile runs a
+  TraceMe is one atomic read; a process that never imported jax (the serve
+  stub, the fleet supervisor) cannot be profiled and opens none.
+* while a recorder is installed (`config.obs.trace`, the serve request
+  traces) it also records the span into one in-memory ring, serialized as
+  Chrome trace events (the `{"traceEvents": [...]}` JSON that
+  `chrome://tracing` and Perfetto load directly). The ring keeps its own
+  clock (`time.perf_counter` from import) and cannot be laid against a
+  device gap; `complete(...)`, which stamps a span after the fact, cannot be
+  a TraceMe and goes to the ring only.
 
 Design constraints, in order:
 
 1. ~zero cost when disabled. Instrumented hot paths (`feeder._worker`
    assembles a batch in under a millisecond) call `span(...)` per
-   iteration; when no recorder is installed that must be one global read
-   and one shared no-op context manager — no allocation, no lock.
+   iteration; when no recorder is installed the ring's part is one global
+   read and one shared no-op context manager — no allocation, no lock —
+   and the profiler's part an inactive TraceMe (under a microsecond).
 2. Thread-safe when enabled. Events land on a `collections.deque`, whose
    `append` is atomic under the GIL; the only lock guards the
    first-event-per-thread name registration.
@@ -41,6 +53,7 @@ import collections
 import contextlib
 import json
 import os
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional
@@ -75,6 +88,30 @@ class _NullSpan:
 
 
 _NULL_SPAN = _NullSpan()
+
+#: Every span of the program carries this prefix in a profile, so a reader
+#: of the xplane tells the program's spans from jax's own and a harness's.
+PROFILE_PREFIX = "rt1/"
+
+
+class _Both:
+    """A span that is live in the profile and in the ring."""
+
+    __slots__ = ("_note", "_ring")
+
+    def __init__(self, note, ring):
+        self._note = note
+        self._ring = ring
+
+    def __enter__(self):
+        self._note.__enter__()
+        self._ring.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._ring.__exit__(*exc)
+        self._note.__exit__(*exc)
+        return False
 
 
 class _Span:
@@ -274,15 +311,20 @@ def enabled() -> bool:
 
 
 def span(name: str, **args):
-    """Context manager timing one span on the current thread.
+    """Context manager timing one span on the current thread: a TraceMe
+    `rt1/<name>` for a running profile, and an event of the ring while a
+    recorder is installed (module docstring).
 
-    The disabled path is one global load + returning a shared no-op object;
-    keyword construction is the only per-call cost left to the caller.
+    jax is looked up, never imported: a process that has not imported it
+    runs no profiler. With the ring off and no jax this is one global load
+    and a shared no-op object.
     """
     t = _tracer
-    if t is None:
-        return _NULL_SPAN
-    return t.span(name, **args)
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    if profiler is None:
+        return _NULL_SPAN if t is None else t.span(name, **args)
+    note = profiler.TraceAnnotation(PROFILE_PREFIX + name, **args)
+    return note if t is None else _Both(note, t.span(name, **args))
 
 
 def instant(name: str, **args) -> None:

@@ -383,15 +383,18 @@ def make_train_step_fns(
             if getattr(model, "aux_mse_weight", 0.0) > 0:
                 out["aux_mse"] = mse / accum_steps  # mean over micros
 
-        if model_health:
-            new_state, updates = state.apply_gradients(
-                grads, new_batch_stats=new_bs, return_updates=True
-            )
-        else:
-            new_state = state.apply_gradients(grads, new_batch_stats=new_bs)
+        # Device scopes for what no module names (rt1.py names the model's).
+        with jax.named_scope("optimizer"):
+            if model_health:
+                new_state, updates = state.apply_gradients(
+                    grads, new_batch_stats=new_bs, return_updates=True
+                )
+            else:
+                new_state = state.apply_gradients(grads, new_batch_stats=new_bs)
+            grad_norm = optax_global_norm(grads)
         metrics = {
             "loss": loss,
-            "grad_norm": optax_global_norm(grads),
+            "grad_norm": grad_norm,
         }
         if "action_loss" in out:
             metrics["action_loss_mean"] = jnp.mean(out["action_loss"])
@@ -404,15 +407,16 @@ def make_train_step_fns(
             # dispatched with the step and fetched only at log steps. Fed
             # from the optimizer's update tree, NOT (old, new) params —
             # reading pre-update params would pin the donated buffers.
-            metrics[health_lib.PACK_KEY] = health_lib.compute_pack(
-                updates=updates,
-                new_params=new_state.params,
-                grads=grads,
-                out=out,
-                depth=health_group_depth,
-                action_dims=health_action_dims,
-                task_names=health_tasks,
-            )
+            with jax.named_scope("health"):
+                metrics[health_lib.PACK_KEY] = health_lib.compute_pack(
+                    updates=updates,
+                    new_params=new_state.params,
+                    grads=grads,
+                    out=out,
+                    depth=health_group_depth,
+                    action_dims=health_action_dims,
+                    task_names=health_tasks,
+                )
         return new_state, metrics
 
     def eval_step(state: TrainState, batch: Batch):
@@ -437,9 +441,10 @@ def make_train_step_fns(
             ok &= metrics["grad_norm"] <= guard_grad_norm_max
         # Dropped update = pass the INPUT state through unchanged (including
         # `step`: an update that never happened should not count as one).
-        new_state = jax.tree.map(
-            lambda n, o: jnp.where(ok, n, o), new_state, state
-        )
+        with jax.named_scope("optimizer"):
+            new_state = jax.tree.map(
+                lambda n, o: jnp.where(ok, n, o), new_state, state
+            )
         skips = skips + jnp.where(ok, 0, 1).astype(jnp.int32)
         metrics = dict(metrics, guard_skips_cum=skips)
         return new_state, skips, metrics
@@ -480,12 +485,13 @@ def make_train_step_fns(
 def _bf16_compute_copy(tree: Any) -> Any:
     """bf16 copy of the f32 leaves (masters untouched; non-float leaves
     pass through). The single cast site of the mixed-precision step."""
-    return jax.tree.map(
-        lambda x: x.astype(jnp.bfloat16)
-        if jnp.asarray(x).dtype == jnp.float32
-        else x,
-        tree,
-    )
+    with jax.named_scope("cast_bf16"):
+        return jax.tree.map(
+            lambda x: x.astype(jnp.bfloat16)
+            if jnp.asarray(x).dtype == jnp.float32
+            else x,
+            tree,
+        )
 
 
 def optax_global_norm(tree: Any) -> jnp.ndarray:

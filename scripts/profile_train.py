@@ -1,15 +1,19 @@
-"""Capture an XPlane/TensorBoard profiler trace of the train step — plus
-the host-side Chrome trace (`rt1_tpu/obs/trace.py`) next to it.
+"""Capture one profiler trace of the train step: the device's ops under
+their scopes and the program's host spans, in one file on one clock.
 
 The reference has no profiling story beyond Lightning's progress bar
 (SURVEY.md §5 "Tracing/profiling"); Stack B wraps steps in
 `jax.profiler.StepTraceAnnotation` (`language_table/train/train.py:182`).
 This script is the deep-dive companion: it traces N real train steps with
-`jax.profiler.start_trace` (XPlane protos viewable in TensorBoard's
-profile plugin or Perfetto) and, in the same run, records the host
-timeline (`<logdir>/host_trace.json`) — so the device-op view and the
-host-thread view (train loop phases; with `--packed`, the sample-ahead
-feeder workers) come from the same steps.
+`jax.profiler.start_trace` (an `.xplane.pb` viewable in TensorBoard's
+profile plugin or Perfetto). The device's ops carry the scope that made
+them (Flax names every module; `preprocess`, `loss`, `optimizer`, `health`,
+`cast_bf16` name the rest), and the program's own spans (`rt1/feeder/*`,
+`rt1/h2d/put`: `rt1_tpu/obs/trace.py`) lie in the same file on the lines of
+the threads that opened them — with `--packed`, the sample-ahead feeder's
+workers. `benchmarks/trace/program.py` reduces such a file to device time a
+step per scope group and a table of the spans; docs/observability.md,
+"One profile", says how to read it.
 
 Model/state construction reuses `train.build_model` + the trainer helpers
 — the profiled step is the REAL config's step (`--model tiny` profiles
@@ -73,13 +77,6 @@ def main():
 
     enable_persistent_cache()
 
-    # Host tracer first: with --packed the feeder threads start below, and
-    # their assembly spans belong in this trace.
-    from rt1_tpu.obs import trace as obs_trace
-
-    host_trace_path = os.path.join(args.logdir, "host_trace.json")
-    obs_trace.enable(host_trace_path)
-
     from rt1_tpu.parallel import MeshConfig, make_mesh
     from rt1_tpu.specs import language_table_action_space, sample_space
     from rt1_tpu.trainer import (
@@ -137,8 +134,7 @@ def main():
         feed = bench_module._e2e_feed(feed_args, fns)
 
         def next_batch():
-            with obs_trace.span("wait_batch"):
-                return next(feed)
+            return next(feed)
 
     else:
         resident = fns.shard_batch((obs, actions))
@@ -158,24 +154,26 @@ def main():
         with step_trace("train", i):
             t0 = time.perf_counter()
             dev_batch = next_batch()
-            with obs_trace.span("device_step", step=i):
-                state, metrics = fns.train_step(
-                    state, dev_batch, jax.random.fold_in(rng, 100 + i)
-                )
-                jax.block_until_ready(metrics["loss"])
+            state, metrics = fns.train_step(
+                state, dev_batch, jax.random.fold_in(rng, 100 + i)
+            )
+            jax.block_until_ready(metrics["loss"])
             times.append(time.perf_counter() - t0)
     jax.profiler.stop_trace()
-    obs_trace.disable()  # dumps host_trace.json
 
     for i, dt in enumerate(times):
         print(f"step {i}: {dt * 1e3:.2f} ms")
+    # The one file, reduced: device time a step by scope group (on a chip;
+    # the CPU's profile has no device plane) and the program's spans.
+    from benchmarks.trace import program, xplane
+
+    for line in program.describe(program.reduce_xplane(xplane.find_xplane(args.logdir))):
+        print(line)
     print(
-        f"device trace written to {args.logdir} — view with TensorBoard's "
-        "profile plugin (xplane.pb) or convert to Perfetto."
-    )
-    print(
-        f"host trace written to {host_trace_path} — load directly in "
-        "Perfetto / chrome://tracing (docs/observability.md)."
+        f"trace written to {args.logdir} — device ops under their scopes and "
+        "the program's rt1/* host spans in one xplane.pb (reduced above by "
+        "benchmarks/trace/program.py): view it with TensorBoard's profile "
+        "plugin (docs/observability.md, 'One profile')."
     )
 
 

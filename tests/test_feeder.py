@@ -586,3 +586,43 @@ def test_train_dataset_batches_packed_switch(tmp_path, corpus):
     assert tid.shape == (2,) and tid.dtype == np.int32
     assert set(tid) == {0}
     it.close()
+
+
+def test_spans_at_the_layer_boundaries_line_up_by_ticket(cache):
+    """assemble (worker threads) -> next (the loop's) -> h2d/put (the loop's):
+    one batch, one ticket; `ready` says how far ahead the workers were; an
+    epoch end is a span of its own. Read from the ring here; in a profile
+    the same spans are `rt1/<name>` (tests/test_obs_trace.py)."""
+    import jax
+    from jax.sharding import SingleDeviceSharding
+
+    from rt1_tpu.data.pipeline import device_feeder
+    from rt1_tpu.obs import trace
+
+    trace._tracer = None
+    rec = trace.enable()
+    try:
+        with SampleAheadFeeder(
+            cache, 4, seed=1, num_epochs=2, num_threads=2, depth=2
+        ) as feeder:
+            n = len(list(device_feeder(
+                feeder, SingleDeviceSharding(jax.devices()[0]), depth=2
+            )))
+    finally:
+        trace._tracer = None
+    spans = [e for e in rec.to_dict()["traceEvents"] if e["ph"] == "X"]
+    by = {}
+    for e in spans:
+        by.setdefault(e["name"], []).append(e)
+    tickets = lambda name: [e["args"]["ticket"] for e in by[name]]  # noqa: E731
+    assert n > 2 and tickets("feeder/next") == list(range(n))
+    assert tickets("h2d/put") == list(range(n))
+    assert set(tickets("feeder/assemble")) >= set(range(n))
+    assert all(0 <= e["args"]["ready"] <= 4 for e in by["feeder/next"])
+    assert all(e["args"]["bytes"] > 0 for e in by["h2d/put"])
+    assert {e["args"]["epoch"] for e in by["feeder/epoch"]} >= {0, 1}
+    # the loop's spans on one thread, the workers' on two others
+    loop = {e["tid"] for e in by["feeder/next"] + by["h2d/put"]}
+    workers = {e["tid"] for e in by["feeder/assemble"]}
+    assert len(loop) == 1 and len(workers) == 2 and not loop & workers
+    assert all(e["name"] != "feeder_assemble" for e in spans)

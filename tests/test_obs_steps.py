@@ -133,7 +133,7 @@ def test_end_step_without_start_raises():
 
 def test_train_loop_emits_trace_and_stall_scalars(tmp_path):
     """Integration: tiny synthetic train run with config.obs.trace=True
-    writes a loadable Chrome trace with train_step spans and keeps the
+    writes a loadable Chrome trace with step spans and keeps the
     flight recorder armed without dumping (clean exit)."""
     from rt1_tpu.train.configs import tiny
     from rt1_tpu.train.train import train_and_evaluate
@@ -152,9 +152,11 @@ def test_train_loop_emits_trace_and_stall_scalars(tmp_path):
         doc = json.load(f)
     spans = [e for e in doc["traceEvents"] if e["ph"] == "X"]
     names = {e["name"] for e in spans}
-    assert "train_step" in names
-    assert {"h2d", "device_step"} <= names
-    step_spans = [e for e in spans if e["name"] == "train_step"]
+    assert "step" in names
+    # dispatch, not device_step: without obs.sync_timing the phase is the
+    # host's dispatch of the step and is named for what it is
+    assert {"step/h2d", "step/dispatch"} <= names and "step/device_step" not in names
+    step_spans = [e for e in spans if e["name"] == "step"]
     assert {e["args"]["step"] for e in step_spans} == {0, 1, 2}
     # Clean exit: no flight-recorder dump.
     assert not os.path.exists(os.path.join(workdir, "flight_record.jsonl"))

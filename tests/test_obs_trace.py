@@ -1,6 +1,9 @@
 """obs/trace.py: thread-safe Chrome-trace recording + disabled fast path."""
 
+import glob
 import json
+import subprocess
+import sys
 import threading
 
 import pytest
@@ -16,12 +19,23 @@ def _clean_global_tracer():
     trace._tracer = None
 
 
-def test_disabled_tracer_is_a_shared_noop():
+def test_disabled_tracer_is_a_shared_noop(monkeypatch):
+    """The ring's part of a span is the shared no-op while no recorder is
+    installed: alone in a process without jax (the serve stub), beside an
+    inactive TraceMe where jax is loaded."""
     assert not trace.enabled()
+    import jax
+
+    s = trace.span("anything", step=1)
+    assert isinstance(s, jax.profiler.TraceAnnotation)
+    with s:
+        pass
+    monkeypatch.delitem(sys.modules, "jax")
     s = trace.span("anything", step=1)
     assert s is trace._NULL_SPAN
     with s:
         pass
+    monkeypatch.undo()
     # Instant/counter/dump are no-ops, not errors.
     trace.instant("marker")
     trace.counter("depth", 3)
@@ -128,3 +142,70 @@ def test_disable_dumps_when_path_configured(tmp_path):
     with open(path) as f:
         doc = json.load(f)
     assert any(e["ph"] == "X" for e in doc["traceEvents"])
+
+
+def _profiled_events(tmp_path, body):
+    """Every (line index, event name, stats) of a CPU profile taken around ``body``."""
+    import jax
+    from jax.profiler import ProfileData
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    return [
+        (i, e.name, {k: str(v) for k, v in e.stats})
+        for plane in ProfileData.from_file(path).planes
+        for i, line in enumerate(plane.lines)
+        for e in line.events
+        if e.name.startswith(trace.PROFILE_PREFIX)
+    ]
+
+
+@pytest.mark.parametrize("ring", [False, True], ids=["ring_off", "ring_on"])
+def test_span_is_a_traceme_on_the_profilers_clock(tmp_path, ring):
+    """Whoever runs a profile finds the program's spans in it as
+    ``rt1/<name>`` with their args, each on its thread's line, whether or
+    not the ring records them too."""
+    rec = trace.enable() if ring else None
+
+    def body():
+        def worker():
+            with trace.span("feeder/assemble", ticket=7):
+                pass
+
+        t = threading.Thread(target=worker, name="rt1-test-worker")
+        with trace.span("feeder/next", ticket=7, ready=3):
+            t.start()
+            t.join(timeout=30)
+        assert not t.is_alive()
+        trace.complete("after_the_fact", trace.now_us(), 5.0)
+
+    events = _profiled_events(tmp_path, body)
+    by_name = {name: (line, stats) for line, name, stats in events}
+    assert set(by_name) == {"rt1/feeder/assemble", "rt1/feeder/next"}
+    assert by_name["rt1/feeder/next"][1] == {"ticket": "7", "ready": "3"}
+    assert by_name["rt1/feeder/assemble"][1] == {"ticket": "7"}
+    assert by_name["rt1/feeder/next"][0] != by_name["rt1/feeder/assemble"][0]
+    if ring:
+        names = [e["name"] for e in rec.to_dict()["traceEvents"] if e["ph"] == "X"]
+        assert sorted(names) == ["after_the_fact", "feeder/assemble", "feeder/next"]
+
+
+def test_a_process_without_jax_opens_no_traceme_and_imports_none():
+    probe = (
+        "import sys\n"
+        "from rt1_tpu.obs import trace\n"
+        "assert trace.span('x', a=1) is trace._NULL_SPAN\n"
+        "rec = trace.enable()\n"
+        "with trace.span('x', a=1):\n"
+        "    pass\n"
+        "assert [e['name'] for e in rec.to_dict()['traceEvents'] if e['ph'] == 'X'] == ['x']\n"
+        "assert 'jax' not in sys.modules\n"
+    )
+    done = subprocess.run([sys.executable, "-c", probe], capture_output=True, text=True, timeout=120)
+    assert done.returncode == 0, done.stderr
