@@ -130,42 +130,28 @@ def pack_names(
 def _grouped_sumsq(tree: Any, depth: int) -> Dict[str, Any]:
     """{group: sum of squares} over the tree's leaves (traced).
 
-    Per-leaf reductions, deliberately in the same form as
-    `trainer.train.optax_global_norm` — when both run over the SAME tree
-    (the gradients) XLA's CSE merges the subcomputations and this pass is
-    free next to the ``grad_norm`` metric the step already emits.
+    One ``sum(square(leaf))`` per leaf, in the same form as
+    `trainer.train.optax_global_norm`. Over the gradients XLA's CSE merges
+    it with the ``grad_norm`` metric the step already emits; over the trees
+    the optimizer has just written (the updates, the new parameters) it
+    becomes one more scalar output of the fusion that makes the leaf. No
+    pass re-reads the state and no flat copy of it is built: a concatenate
+    + vdot per group cost 1.5 % of the flagship's step on a TPU v5e
+    (PERF.md section 6, PR 26).
+
+    Spelt in `lax`: the same `square`, `reduce_sum` and `add` equations as
+    the `jnp` calls give, without tracing a jitted wrapper for each (three
+    a leaf over three trees of 570 leaves was ~2 s of every start-up).
     """
     import jax
-    import jax.numpy as jnp
+    from jax import lax
 
     out: Dict[str, Any] = {}
     for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
         group = _path_str(path[:depth])
-        sq = jnp.sum(jnp.square(jnp.asarray(leaf, jnp.float32)))
-        out[group] = out.get(group, 0.0) + sq
-    return out
-
-
-def _grouped_sumsq_concat(tree: Any, depth: int) -> Dict[str, Any]:
-    """Like :func:`_grouped_sumsq`, via one concat + one vdot per group.
-
-    ~8 ops per tree instead of ~|leaves|: on XLA:CPU each un-fused
-    reduction pays a dispatch, and the pack's budget is 2% of a *tiny*
-    step (bench.py --health). The transient per-group flat copies are
-    noise next to activations at RT-1 scale.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    grouped: Dict[str, list] = {}
-    for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
-        grouped.setdefault(_path_str(path[:depth]), []).append(
-            jnp.ravel(jnp.asarray(leaf, jnp.float32))
-        )
-    out: Dict[str, Any] = {}
-    for group, flats in grouped.items():
-        v = flats[0] if len(flats) == 1 else jnp.concatenate(flats)
-        out[group] = jnp.vdot(v, v)
+        x = lax.convert_element_type(leaf, "float32")
+        sq = lax.reduce_sum(lax.square(x), axes=tuple(range(x.ndim)))
+        out[group] = lax.add(out[group], sq) if group in out else sq
     return out
 
 
@@ -197,11 +183,9 @@ def compute_pack(
     import jax.numpy as jnp
 
     groups = param_groups(new_params, depth)
-    # Grads per-leaf (CSE-merges with the step's grad_norm metric, ~free);
-    # updates/new-params via concat+vdot (few ops — no metric to CSE with).
     grad_sq = _grouped_sumsq(grads, depth)
-    upd_sq = _grouped_sumsq_concat(updates, depth)
-    new_sq = _grouped_sumsq_concat(new_params, depth)
+    upd_sq = _grouped_sumsq(updates, depth)
+    new_sq = _grouped_sumsq(new_params, depth)
 
     parts = [
         jnp.stack([jnp.sqrt(grad_sq[g]) for g in groups]),

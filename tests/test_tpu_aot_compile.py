@@ -9,6 +9,7 @@ described (no libtpu).
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
 
@@ -106,3 +107,56 @@ def test_ring_attention_compiles_on_four_chip_mesh(v5e):
 
     compiled = jax.jit(attend).lower(qkv, qkv, qkv, mask).compile()
     assert "collective-permute" in compiled.as_text()
+
+
+def test_health_pack_copies_no_state_in_the_compiled_step(v5e):
+    """The guarded step with the health pack on, at the rehearsal size of
+    tests/benchmark/data, compiled for one described chip: the pack's sums
+    leave the optimizer's own fusions as scalars, so the program holds no
+    `concatenate` or `dynamic-update-slice` the size of a layer group (the
+    flat float32 copies concat + vdot made of the updates and the new
+    parameters were 1.5 % of the flagship's step on the chip), and none
+    over 1 M elements at all."""
+    from benchmarks import program
+    from rt1_tpu.obs import health
+    from rt1_tpu.trainer import make_train_step_fns
+
+    config = program.program_config(program.load_config_file(os.path.join(
+        os.path.dirname(__file__), "benchmark", "data", "rt1-small-test.json")))
+    plan, model, init_fn, loss_fn, tx = program.build_model(
+        config, devices=v5e.devices[:1])
+    shapes = program.abstract_state(config, model, init_fn, tx)
+    fns = make_train_step_fns(
+        model, plan.mesh, shapes, loss_fn=loss_fn, guard_nonfinite=True,
+        model_health=True, health_task_names=("a", "b"), plan=plan,
+    )
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+            tree)
+
+    observations, actions = program.batch_spec(config)
+    observations[health.TASK_ID_KEY] = jax.ShapeDtypeStruct(
+        (config.per_host_batch_size,), jnp.int32)
+    text = fns.train_step.lower(
+        on_chip(shapes), jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+        on_chip((observations, actions)),
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip),
+    ).compile().as_text()
+
+    def elements(dims):
+        return int(np.prod([int(d) for d in dims.split(",") if d]))
+
+    copies = [
+        (op, elements(dims), "/health/" in line)
+        for line in text.splitlines()
+        for dims, op in re.findall(
+            r" = \w+\[([\d,]*)\]\S* (concatenate|dynamic-update-slice)\(", line)
+    ]
+    pack = len(fns.health_names)
+    assert ("concatenate", pack, True) in copies  # the pack itself: read right
+    group = max(pack, len(jax.tree.leaves(shapes.params)))
+    assert not [c for c in copies if c[2] and c[1] > group], copies
+    assert not [c for c in copies if c[1] > 1_000_000], copies
