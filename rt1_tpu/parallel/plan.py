@@ -152,6 +152,21 @@ def rt1_sharding_plan() -> List[Rule]:
         (r"image_tokenizer_def/ctx_proj/bias$", P()),
         (r"image_tokenizer_def/tok/kernel$", P(None, "fsdp")),
         (r"image_tokenizer_def/tok/bias$", P()),
+        # --- block-spec decoder LM (models/lm) ------------------------------
+        # Expert stacks (held, in, out): experts over `model`. On one chip
+        # (every axis 1) the layer is told its share instead
+        # (`model.lm.experts_held`) and runs without the exchange.
+        (r"ffn/experts/w[123]/kernel$", P("model", None, None)),
+        # fp32 router and its selection bias: every shard routes alike.
+        (r"ffn/router/kernel$", P()),
+        (r"ffn/expert_bias/kernel$", P()),
+        # The (sliced) embedding, tied to the output head: rows over `model`.
+        (r"embed/embedding$", P("model", None)),
+        # Mixers, the dense FFN and the norms: replicated.
+        (r"mixer/(in_proj|out_proj|[qkvo]_proj)/kernel$", P()),
+        (r"mixer/kernel$", P()),
+        (r"ffn/w[123]/kernel$", P()),
+        (r"(mixer_norm|ffn_norm|final_norm|q_norm|k_norm)/scale$", P()),
     ]
 
 

@@ -113,14 +113,22 @@ def build_model(model_config, mesh=None):
     )
 
 
+# Families whose parameter paths parallel/plan.py's rules describe.
+PLANNED_FAMILIES = ("rt1", "lfm2_moe")
+
+
 def build_family(model_config, mesh=None):
-    """(model, init_fn, loss_fn) for config.model.family = "rt1" | "lava".
+    """(model, init_fn, loss_fn) for config.model.family = "rt1" | "lava" |
+    "lfm2_moe".
 
     The reference trains its two model families from separate stacks
     (Stack A `distribute_train.py` for RT-1, Stack B
     `language_table/train/train.py:105-116` for LAVA/BC); here one train
-    loop serves both — the family only selects the model constructor, the
-    init signature, and the loss closure plugged into the jitted SPMD step.
+    loop serves every family — the family only selects the model
+    constructor, the init signature, and the loss closure plugged into the
+    jitted SPMD step. "lfm2_moe" is a decoder language model built from a
+    block description (rt1_tpu/models/lm, docs/lm_family.md); its batches
+    are token ids (rt1_tpu/data/tokens.py).
     """
     family = model_config.get("family", "rt1")
     if family == "rt1":
@@ -172,6 +180,17 @@ def build_family(model_config, mesh=None):
             )
 
         return model, init_fn, make_bc_step_loss_fn(model)
+    if family == "lfm2_moe":
+        from rt1_tpu.models.lm import DecoderLM, LMSpec, make_lm_step_loss_fn
+
+        model = DecoderLM(LMSpec.from_config(
+            model_config.lm, jnp.dtype(model_config.get("dtype", "float32"))
+        ))
+
+        def init_fn(model, rng, obs, actions):
+            return model.init({"params": rng}, obs, actions, train=False)
+
+        return model, init_fn, make_lm_step_loss_fn(model)
     raise ValueError(f"Unknown model family: {family!r}")
 
 
@@ -621,6 +640,10 @@ def train_and_evaluate(config, workdir: str):
             os.makedirs(workdir, exist_ok=True)
             with open(os.path.join(workdir, "data_manifest.json"), "w") as f:
                 json.dump(manifest, f, indent=2, sort_keys=True)
+    elif config.model.get("family", "rt1") == "lfm2_moe":
+        from rt1_tpu.data.tokens import feed_from_config
+
+        train_iter = feed_from_config(config, config.seed)
     else:
         train_iter = synthetic_batches(config, config.seed)
 
@@ -715,7 +738,7 @@ def train_and_evaluate(config, workdir: str):
         ),
         plan=sharding_plan,
         mixed_precision=mixed_precision,
-        check_coverage=config.model.get("family", "rt1") == "rt1",
+        check_coverage=config.model.get("family", "rt1") in PLANNED_FAMILIES,
     )
     state = fns.shard_state(state)
     # What the runtime placed, read off the shards (not the plan): under
@@ -762,6 +785,10 @@ def train_and_evaluate(config, workdir: str):
                 eval_iter = dataset_batches(config, "val")
             except FileNotFoundError:
                 eval_iter = None
+        elif config.model.get("family", "rt1") == "lfm2_moe":
+            from rt1_tpu.data.tokens import feed_from_config
+
+            eval_iter = feed_from_config(config, config.seed + 1)
         else:
             eval_iter = synthetic_batches(config, config.seed + 1)
 
@@ -954,6 +981,8 @@ def train_and_evaluate(config, workdir: str):
     cleanup = contextlib.ExitStack()
     cleanup.callback(_obs_teardown)
     cleanup.callback(_close_host_iter)
+    if callable(getattr(eval_iter, "close", None)):
+        cleanup.callback(eval_iter.close)
     cleanup.callback(_write_goodput)
     if ledger is not None:
         ledger.close_phase()  # init ends where the step loop begins

@@ -402,6 +402,8 @@ def make_train_step_fns(
             metrics["moe_aux_loss"] = out["moe_aux_loss"]
         if "aux_mse" in out:  # soft-argmax regression monitor
             metrics["aux_mse"] = out["aux_mse"]
+        # A family's own per-step counters (the routed layers' rows).
+        metrics.update(out.get("counters", {}))
         if model_health:
             # One small replicated vector; like every other metric it is
             # dispatched with the step and fetched only at log steps. Fed

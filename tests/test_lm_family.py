@@ -1,0 +1,273 @@
+"""The block-spec decoder family (``lfm2_moe``) at a small size on the CPU,
+float32, against the plain reference (benchmarks/references/lfm2_moe.py):
+5 layers in the published pattern, d 64, 4 heads / 2 KV heads, 16 sigmoid-routed
+experts top-4 of which 4 are held, vocabulary slice 128.
+
+Tolerance 1e-5 (of a leaf's largest element): both sides are float32 and do
+the same arithmetic; they differ in the order of sums only (a sort and a
+grouped product against a masked loop over experts, query blocks against the
+keys before them against the whole masked square), which reads 1e-6 to 2e-6.
+"""
+
+import flax
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks import weights
+from benchmarks.references import lfm2_moe as ref
+from rt1_tpu.data.tokens import IGNORE, PackedTokenFeed, feed_from_config
+from rt1_tpu.models.lm import layers
+from rt1_tpu.models.lm.moe import RoutedFFN
+from rt1_tpu.models.lm.spec import LMSpec
+from rt1_tpu.train.configs import lfm2_moe
+from rt1_tpu.train.train import build_family
+
+TOL = 1e-5
+SMALL = dict(hidden_size=64, num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+             intermediate_size=96, moe_intermediate_size=32, num_experts=16, experts_held=(4, 4),
+             vocab_held=128, seq_len=64)
+
+
+def small_config(**changes):
+    config = lfm2_moe.get_config()
+    for k, v in dict(SMALL, **changes).items():
+        config.model.lm[k] = v
+    config.model.dtype = "float32"
+    return config
+
+
+def overrides_of(config):
+    return {"model.lm." + k: (list(v) if isinstance(v, tuple) else v)
+            for k, v in config.model.lm.to_dict().items()}
+
+
+def reference_sizes(config):
+    return dict(ref.sizes(overrides_of(config)), query_block=16, token_block=32)
+
+
+def close(a, b, what=""):
+    a, b = np.asarray(a), np.asarray(b)
+    gap = float(np.max(np.abs(a - b))) / (float(np.max(np.abs(b))) + 1e-30)
+    assert gap <= TOL, (what, gap)
+
+
+@pytest.fixture(scope="module")
+def world():
+    config = small_config()
+    model, init_fn, loss_fn = build_family(config.model)
+    feed = feed_from_config(config, 3)
+    host = next(feed)
+    feed.close()
+    batch = (host["observations"], host["actions"])
+    abstract = jax.eval_shape(
+        lambda r: init_fn(model, r, *batch), jax.random.PRNGKey(0))["params"]
+    params, _ = weights.make_weights(abstract, {}, 11, {"experts": 2.0, "expert_bias": 0.02})
+    return config, model, loss_fn, batch, params
+
+
+def test_program_against_the_reference(world):
+    config, model, loss_fn, batch, params = world
+    sz = reference_sizes(config)
+    with jax.default_matmul_precision("highest"):
+        out = model.apply({"params": params}, *batch, return_logits=True)
+        (loss, _), grads = jax.value_and_grad(
+            lambda p: loss_fn(p, {}, batch, None, True), has_aux=True)(params)
+        ref_logits = ref.logits_fn(params, batch[0]["tokens"], sz)
+        (ref_loss, _), ref_grads = jax.value_and_grad(
+            lambda p: ref.loss_fn(p, {}, batch, None, sz), has_aux=True)(params)
+    # the positions after a sequence's last counted target get no rows in the
+    # routed layers: nothing the loss reads depends on them, their logits do
+    counted = np.asarray(batch[1]["targets"]) != IGNORE
+    live = np.flip(np.cumsum(np.flip(counted, 1), 1), 1) > 0
+    assert 0 < (~live).sum() < live.size // 2
+    close(np.asarray(out["logits"])[live], np.asarray(ref_logits)[live], "logits")
+    assert float(out["counters"]["moe/assignments_held"]) <= live.sum() * 4 * 4
+    assert abs(float(loss) - float(ref_loss)) <= TOL * abs(float(ref_loss))
+    got, want = (flax.traverse_util.flatten_dict(t, sep="/") for t in (grads, ref_grads))
+    assert set(got) == set(want) and len(want) == 53
+    for path in want:
+        close(got[path], want[path], path)
+    # the expert bias enters the selection only
+    assert all(float(jnp.max(jnp.abs(v))) == 0.0 for k, v in got.items() if "expert_bias" in k)
+    assert float(out["counters"]["moe/assignments_held"]) > 0
+
+
+def _routed_layer(config, held):
+    lm = config.model.lm.copy_and_resolve_references()
+    lm.experts_held = held
+    return RoutedFFN(LMSpec.from_config(lm, jnp.float32))
+
+
+def _full_layer_params(config, seed=5):
+    """A routed layer's leaves with ALL the router's experts' stacks."""
+    lm = config.model.lm
+    layer = _routed_layer(config, (0, lm.num_experts))
+    x = jnp.zeros((2, 8, lm.hidden_size))
+    abstract = jax.eval_shape(lambda r: layer.init(r, x), jax.random.PRNGKey(0))["params"]
+    params, _ = weights.make_weights(abstract, {}, seed, {"experts": 4.0, "expert_bias": 0.02})
+    return params
+
+
+def _share(params, first, count):
+    cut = flax.core.unfreeze(jax.tree.map(lambda a: a, params))
+    cut["experts"] = jax.tree.map(lambda a: a[first:first + count], params["experts"])
+    return cut
+
+
+def test_the_shares_add_up():
+    """What the shares [0,4) ... [12,16) give, summed, is the uncut layer."""
+    config = small_config()
+    params = _full_layer_params(config)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, config.model.lm.hidden_size))
+    total = 0.0
+    rows = 0.0
+    for first in range(0, 16, 4):
+        out, counters = _routed_layer(config, (first, 4)).apply(
+            {"params": _share(params, first, 4)}, x)
+        total = total + out
+        rows += float(counters["rows_held"])
+    whole = ref.routed_ffn(x, params, reference_sizes(config), "highest", held=(0, 16))
+    close(total, whole, "sum of the shares")
+    assert rows == 2 * 32 * 4       # every assignment computed once, none dropped
+
+
+def test_dropless_under_imbalance(world):
+    """Every token selects held expert 5: its group is the whole batch."""
+    config = small_config()
+    params = _full_layer_params(config)
+    lm = config.model.lm
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, 32, lm.hidden_size))
+    # a router whose expert 5 wins everywhere, through the selection bias
+    params = flax.core.unfreeze(params)
+    params["expert_bias"]["kernel"] = params["expert_bias"]["kernel"].at[5].set(10.0)
+    first, count = lm.experts_held
+    out, counters = _routed_layer(config, (first, count)).apply(
+        {"params": _share(params, first, count)}, x)
+    sz = reference_sizes(config)
+    want = ref.routed_ffn(x, _share(params, first, count), sz, "highest")
+    close(out, want, "skewed router")
+    idx, _ = ref.route(x.reshape(-1, lm.hidden_size), params, sz)
+    per_expert = np.array([(np.asarray(idx) == e).sum() for e in range(first, first + count)])
+    assert per_expert[5 - first] == 64
+    assert float(counters["rows_max"]) == 64.0
+    assert float(counters["rows_max"] / counters["rows_mean"]) == pytest.approx(
+        per_expert.max() / per_expert.mean())
+
+
+def test_tail_padding_takes_no_rows():
+    """Positions that are not live are routed but computed by no expert."""
+    config = small_config()
+    lm = config.model.lm
+    params = _full_layer_params(config)
+    x = jax.random.normal(jax.random.PRNGKey(8), (2, 32, lm.hidden_size))
+    live = jnp.arange(32)[None, :] < jnp.array([[20], [32]])
+    layer = _routed_layer(config, (0, lm.num_experts))
+    out, counters = layer.apply({"params": params}, x, live)
+    whole, _ = layer.apply({"params": params}, x)
+    assert float(counters["rows_held"]) == (20 + 32) * 4
+    assert float(jnp.max(jnp.abs(out[0, 20:]))) == 0.0
+    close(out[0, :20], whole[0, :20], "live positions")
+    close(out[1], whole[1], "a sequence with no padding")
+
+
+@pytest.mark.parametrize("mixer", ["conv", "full_attention"])
+def test_a_mixer_is_causal(mixer):
+    """Tokens after t move nothing at or before t, forward and gradient."""
+    spec = LMSpec.from_config(small_config().model.lm, jnp.float32)
+    module = (layers.ShortConv if mixer == "conv" else layers.GQAttention)(spec)
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, 32, spec.hidden_size))
+    params = module.init(jax.random.PRNGKey(4), x)
+    t = 13
+    moved = x.at[:, t + 1:].add(1.0)
+    a, b = module.apply(params, x), module.apply(params, moved)
+    np.testing.assert_array_equal(np.asarray(a[:, :t + 1]), np.asarray(b[:, :t + 1]))
+    assert float(jnp.max(jnp.abs(a[:, t + 1:] - b[:, t + 1:]))) > 1e-3
+    grad = jax.grad(lambda v: jnp.sum(module.apply(params, v)[:, :t + 1] ** 2))(x)
+    assert float(jnp.max(jnp.abs(grad[:, t + 1:]))) == 0.0
+    assert float(jnp.max(jnp.abs(grad[:, :t + 1]))) > 0.0
+
+
+def test_blockwise_attention_is_dense_attention_both_ways():
+    key = jax.random.PRNGKey(6)
+    q = jax.random.normal(key, (2, 256, 2, 2, 16))
+    k = jax.random.normal(jax.random.fold_in(key, 1), (2, 256, 2, 16))
+    v = jax.random.normal(jax.random.fold_in(key, 2), (2, 256, 2, 16))
+    probe = jax.random.normal(jax.random.fold_in(key, 3), q.shape)
+
+    def both_ways(fn):
+        return jax.value_and_grad(
+            lambda q, k, v: jnp.sum(fn(q, k, v) * probe), argnums=(0, 1, 2))(q, k, v)
+
+    with jax.default_matmul_precision("highest"):
+        dense, dense_grads = both_ways(lambda q, k, v: layers.dense_attention(q, k, v, 0.25))
+        blocks, block_grads = both_ways(
+            lambda q, k, v: layers.blockwise_attention(q, k, v, 0.25, 64))
+    assert abs(float(dense) - float(blocks)) <= TOL * abs(float(dense))
+    for a, b in zip(block_grads, dense_grads):
+        close(a, b, "attention gradient")
+
+
+def _batches(seed, n=3, **kw):
+    feed = PackedTokenFeed(batch_size=2, seq_len=256, vocab=128, seed=seed, documents=64,
+                           doc_len_median=48, doc_len_sigma=1.0, doc_len_min=4, **kw)
+    out = [next(feed) for _ in range(n)]
+    share = feed.padding_share
+    feed.close()
+    return out, share
+
+
+def test_the_token_feed():
+    from rt1_tpu.models.lm import spec
+
+    assert IGNORE == spec.IGNORE        # one contract, stated on both sides
+    a, share = _batches(7)
+    b, _ = _batches(7)
+    other, _ = _batches(8)
+    for x, y in zip(a, b):          # same seed, same batches
+        np.testing.assert_array_equal(x["observations"]["tokens"], y["observations"]["tokens"])
+        np.testing.assert_array_equal(x["actions"]["targets"], y["actions"]["targets"])
+    assert any((x["observations"]["tokens"] != y["observations"]["tokens"]).any()
+               for x, y in zip(a, other))
+    assert 0.0 < share < 0.5
+    for batch in a:
+        tokens, targets = batch["observations"]["tokens"], batch["actions"]["targets"]
+        assert tokens.dtype == np.int32 and tokens.shape == targets.shape == (2, 256)
+        assert tokens.min() >= 0 and tokens.max() <= 127        # inside the slice
+        for row, want in zip(tokens, targets):
+            counted = np.flatnonzero(want != IGNORE)
+            filled = counted.max() + 2          # the last token has no next
+            # padding only at the tail, and nothing after it counts
+            assert (want[filled - 1:] == IGNORE).all() and (want[:filled - 1] != IGNORE).all()
+            np.testing.assert_array_equal(want[:filled - 1], row[1:filled])
+            assert row[filled - 1] == 127 and (row[filled:] == 127).all()   # end-of-document
+
+
+def test_the_plan_has_a_rule_for_every_leaf(world):
+    from rt1_tpu.parallel import ShardingPlan
+    from rt1_tpu.parallel import sharding as shardlib
+
+    config, _, _, _, params = world
+    plan = ShardingPlan.from_config(config)
+    assert plan.coverage(params) == []
+    paths = [shardlib._path_str(p) for p, _ in jax.tree_util.tree_flatten_with_path(params)[0]]
+    assert len(paths) == 53
+    assert [p for p in paths if plan.spec_for(p) is None] == []
+    experts = [p for p in paths if "/experts/" in p]
+    assert experts and all(plan.spec_for(p)[0] == "model" for p in experts)
+
+
+def test_the_trainer_trains_the_family(tmp_path):
+    """``python -m rt1_tpu.train.train --config .../lfm2_moe.py`` at a small
+    size: train_and_evaluate -> make_train_step_fns, guard and health pack on."""
+    from rt1_tpu.train.train import train_and_evaluate
+
+    config = small_config(seq_len=32)
+    config.per_host_batch_size = 8      # the test's eight virtual devices
+    config.num_steps = 3
+    config.log_every_steps = 1
+    config.eval_every_steps = 0
+    config.checkpoint_every_steps = 100
+    state = train_and_evaluate(config, str(tmp_path))
+    assert int(state.step) == 3

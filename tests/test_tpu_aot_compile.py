@@ -160,3 +160,63 @@ def test_health_pack_copies_no_state_in_the_compiled_step(v5e):
     group = max(pack, len(jax.tree.leaves(shapes.params)))
     assert not [c for c in copies if c[2] and c[1] > group], copies
     assert not [c for c in copies if c[1] > 1_000_000], copies
+
+
+def _lm_spec():
+    from rt1_tpu.models.lm.spec import LMSpec
+    from rt1_tpu.train.configs import lfm2_moe
+
+    return LMSpec.from_config(lfm2_moe.get_config().model.lm, jnp.bfloat16)
+
+
+def test_lm_attention_compiles_both_ways_at_the_published_widths(v5e, monkeypatch):
+    """The decoder LM family's attention at 2 x 8,192 positions, 32 heads over
+    8 KV heads of 64: the kernel the code picks on a TPU, forward and backward,
+    inside the chip's VMEM (a block of 2,048 is refused there)."""
+    from rt1_tpu.models.lm import layers
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")     # as on the chip
+    sp = _lm_spec()
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    group = sp.num_heads // sp.num_kv_heads
+    q = jax.ShapeDtypeStruct((2, 8192, sp.num_kv_heads, group, sp.head_dim), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((2, 8192, sp.num_kv_heads, sp.head_dim), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def both_ways(q, k, v):
+        return jax.grad(lambda *a: jnp.sum(
+            layers.causal_attention(*a, sp.head_dim ** -0.5).astype(jnp.float32)),
+            argnums=(0, 1, 2))(q, k, v)
+
+    text = jax.jit(both_ways).lower(q, kv, kv).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3       # forward, dq, dk and dv
+
+
+def test_lm_routed_experts_compile_both_ways_at_the_published_widths(v5e, monkeypatch):
+    """One routed layer of the family at 16,384 tokens, 8 of 64 experts held:
+    sort, gathers and the megablox products, forward and backward."""
+    from rt1_tpu.models.lm import moe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    sp = _lm_spec()
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    tokens, d, f, held = 16384, sp.hidden_size, sp.moe_intermediate_size, sp.experts_held[1]
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def both_ways(x, weights, w1, w3, w2, idx, live):
+        return jax.grad(lambda x, weights, w1, w3, w2: jnp.sum(moe.held_experts_ffn(
+            x, idx, weights, live, w1, w3, w2, sp)[0].astype(jnp.float32)),
+            argnums=(0, 1, 2, 3, 4))(x, weights, w1, w3, w2)
+
+    compiled = jax.jit(both_ways).lower(
+        shape((tokens, d), jnp.bfloat16), shape((tokens, sp.experts_per_tok), jnp.float32),
+        shape((held, d, f), jnp.float32), shape((held, d, f), jnp.float32),
+        shape((held, f, d), jnp.float32), shape((tokens, sp.experts_per_tok), jnp.int32),
+        shape((tokens,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the gathers go back as gathers: no scatter over the row buffer's 2048-wide rows
+    assert not re.findall(r"bf16\[65536,2048\]\S* scatter\(", text)
