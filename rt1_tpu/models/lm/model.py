@@ -77,13 +77,14 @@ class DecoderLM(nn.Module):
         # give them no rows (loss and gradients are exactly what they were).
         counted = targets != IGNORE
         live = jnp.flip(jnp.cumsum(jnp.flip(counted, 1), 1), 1) > 0
-        held, largest, mean = 0.0, 0.0, 0.0
+        held, largest, mean, fallbacks = 0.0, 0.0, 0.0, 0.0
         for i, block in enumerate(sp.blocks):
             x, rows = Block(sp, block, name=f"layer_{i}")(x, live)
             if rows is not None:
                 held = held + rows["rows_held"]
                 largest = largest + rows["rows_max"]
                 mean = mean + rows["rows_mean"]
+                fallbacks = fallbacks + rows["fallback"]
         x = RMSNorm(sp.norm_eps, sp.dtype, name="final_norm")(x)
         head = embedding.astype(sp.dtype)
         with jax.named_scope("lm_loss"):
@@ -93,6 +94,7 @@ class DecoderLM(nn.Module):
             out["counters"] = {
                 "moe/assignments_held": held,
                 "moe/load_max_over_mean": largest / jnp.maximum(mean, 1e-9),
+                "moe/fallback_layers": fallbacks,
             }
         if return_logits:
             out["logits"] = jnp.einsum("bsd,vd->bsv", x, head,

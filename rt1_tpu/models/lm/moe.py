@@ -10,18 +10,34 @@ that would bring their part here.
 Dispatch is a sort, not a one-hot tensor: the tokens' assignments are ordered
 by held expert (assignments to absent experts last), the rows are gathered in
 that order, and one grouped matrix product runs over the rows of each held
-expert (the megablox Pallas kernel on a TPU, ``lax.ragged_dot`` elsewhere).  The order is a
-permutation, so both gathers (tokens to rows, rows back to the tokens' slots)
-go back as gathers through its inverse, not as scatter-adds.  Every assignment of a
-live token to a held expert is computed whatever the imbalance (a token is
-live unless nothing reads its output: the padding at a packed sequence's
-tail, which would otherwise all take the same experts): the row buffer has room for
-all tokens x top-k assignments, and the grouped product skips the rows after
-the last group, so the products' work follows the rows held.
+expert (the megablox Pallas kernel on a TPU, ``lax.ragged_dot`` elsewhere).
+Every assignment of a live token to a held expert is computed whatever the
+imbalance (a token is live unless nothing reads its output: the padding at a
+packed sequence's tail, which would otherwise all take the same experts).
+
+The buffers follow the rows held.  Of ``n`` = tokens x top-k assignment slots
+a layer that holds ``held`` of ``num_experts`` experts sees ``n x held /
+num_experts`` when the router is balanced; its row buffer has
+``row_capacity`` = twice that, in whole row tiles of the grouped product, at
+most ``n``.  A step whose held rows fit takes the row path: the first
+``capacity`` assignments in dispatch order are gathered, computed, weighed in
+float32 and added up per token, every array ``capacity`` rows long.  A step
+whose rows do not fit takes the slot path under ``lax.cond`` in the same
+compiled step: buffers of all ``n`` slots, the order a permutation, so both
+gathers (tokens to rows, rows back to the tokens' slots) go back as gathers
+through its inverse.  A row's arithmetic is the same on both.  A layer whose
+capacity is all its slots (it holds half the experts or more) runs the slot
+path with no branch.  ``held_experts_ffn`` says which path a step took; the
+model counts the layers that took the slots as ``moe/fallback_layers``.
+
+Nothing of a routed layer is kept for the way back but its arguments and the
+dispatch order: the way back makes its branch's forward again, inside the
+branch, so no buffer outlives a branch and none is sized for both.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Tuple
 
 import flax.linen as nn
@@ -76,6 +92,15 @@ def grouped_matmul(rows, stack, group_sizes):
     return lax.ragged_dot(rows, stack, group_sizes, preferred_element_type=rows.dtype)
 
 
+def row_capacity(n: int, held: int, num_experts: int) -> int:
+    """Rows of the row buffer for ``n`` assignment slots: twice the balanced
+    share of the held experts, in whole row tiles of the grouped product,
+    never more than ``n``."""
+    tile = MEGABLOX_TILING[0]
+    balanced = -(-n * held // num_experts)
+    return min(n, -(-2 * balanced // tile) * tile)
+
+
 @jax.custom_vjp
 def rows_of_tokens(x, order, position, valid, is_held):
     """``rows[r] = x[order[r] // k]`` for the valid rows, zero after them.
@@ -114,11 +139,171 @@ rows_of_tokens.defvjp(_rows_of_tokens_fwd, _rows_of_tokens_bwd)
 rows_to_slots.defvjp(_rows_to_slots_fwd, _rows_to_slots_bwd)
 
 
-def held_experts_ffn(x, idx, weights, live, w1, w3, w2, spec: LMSpec):
-    """(part of the layer's output from the held experts, rows per held expert).
+@jax.custom_vjp
+def held_rows(x, token, valid):
+    """``rows[r] = x[token[r]]`` for the valid rows of a compact buffer, zero
+    after them.  The way back adds the rows up per token in float32, as the
+    slot path sums a token's slots."""
+    return jnp.where(valid[:, None], x[token], 0)
+
+
+def _held_rows_fwd(x, token, valid):
+    return held_rows(x, token, valid), (token, valid, x.shape[0])
+
+
+def _held_rows_bwd(res, d_rows):
+    token, valid, tokens = res
+    kept = jnp.where(valid[:, None], d_rows, 0).astype(jnp.float32)
+    d_x = jnp.zeros((tokens, kept.shape[1]), jnp.float32).at[token].add(kept)
+    return d_x.astype(d_rows.dtype), None, None
+
+
+held_rows.defvjp(_held_rows_fwd, _held_rows_bwd)
+
+
+def _experts(rows, w1, w3, w2, group_sizes):
+    dtype = rows.dtype
+    both = grouped_matmul(rows, jnp.concatenate([w1, w3], axis=-1).astype(dtype), group_sizes)
+    gate, up = jnp.split(both, 2, axis=-1)
+    return grouped_matmul(jax.nn.silu(gate) * up, w2.astype(dtype), group_sizes)
+
+
+def _slot_index(order, group_sizes, is_held):
+    tokens, k = is_held.shape
+    n = tokens * k
+    # rows after the last group are zero and never computed
+    valid = jnp.arange(n) < jnp.sum(group_sizes)
+    position = jnp.zeros((n,), jnp.int32).at[order].set(
+        jnp.arange(n, dtype=jnp.int32), unique_indices=True)
+    return order, jnp.where(is_held, position.reshape(tokens, k), 0), valid, is_held
+
+
+def _slot_dispatch(x, index):
+    return rows_of_tokens(x, *index)
+
+
+def _slot_combine(out_rows, weights, index):
+    picked = rows_to_slots(out_rows, *index)
+    return jnp.sum(picked.astype(jnp.float32) * weights[..., None], axis=1).astype(
+        out_rows.dtype)
+
+
+def _row_index(capacity, order, group_sizes, is_held):
+    slot = order[:capacity]
+    return slot // is_held.shape[1], slot, jnp.arange(capacity) < jnp.sum(group_sizes)
+
+
+def _row_dispatch(x, index):
+    token, _, valid = index
+    return held_rows(x, token, valid)
+
+
+def _row_combine(out_rows, weights, index):
+    token, slot, valid = index
+    weight = weights.reshape(-1).at[slot].get(unique_indices=True)
+    weighed = jnp.where(valid[:, None], out_rows, 0).astype(jnp.float32) * weight[:, None]
+    out = jnp.zeros((weights.shape[0], out_rows.shape[1]), jnp.float32).at[token].add(weighed)
+    return out.astype(out_rows.dtype)
+
+
+def _path(capacity):
+    """A path between the tokens and the experts' rows: ``index(order,
+    group_sizes, is_held)``, ``dispatch(x, index) -> rows`` and
+    ``combine(out_rows, weights, index) -> out``.  By slots (``capacity``
+    None): buffers of all tokens x top-k rows, which fit whatever the
+    imbalance.  By rows: buffers of ``capacity`` rows, for a step whose held
+    rows fit, so that the first ``capacity`` assignments in dispatch order
+    are all of them."""
+    if capacity is None:
+        return _slot_index, _slot_dispatch, _slot_combine
+    return functools.partial(_row_index, capacity), _row_dispatch, _row_combine
+
+
+# Both are jitted so that a model's routed layers, alike in shape, trace each
+# path once and not once a layer.
+@functools.partial(jax.jit, static_argnums=(0,))
+def _forward(capacity, x, weights, w1, w3, w2, order, group_sizes, is_held):
+    make_index, dispatch, combine = _path(capacity)
+    with jax.named_scope("moe/dispatch"):
+        index = make_index(order, group_sizes, is_held)
+        rows = dispatch(x, index)
+    with jax.named_scope("moe/experts"):
+        out_rows = _experts(rows, w1, w3, w2, group_sizes)
+    with jax.named_scope("moe/combine"):
+        return combine(out_rows, weights, index)
+
+
+@functools.partial(jax.jit, static_argnums=(0,))
+def _backward(capacity, d_out, x, weights, w1, w3, w2, order, group_sizes, is_held):
+    """The forward again, stage by stage, and each stage's way back under the
+    stage's own scope."""
+    make_index, dispatch, combine = _path(capacity)
+    with jax.named_scope("moe/dispatch"):
+        index = make_index(order, group_sizes, is_held)
+        rows, back_dispatch = jax.vjp(lambda x: dispatch(x, index), x)
+    with jax.named_scope("moe/experts"):
+        out_rows, back_experts = jax.vjp(
+            lambda rows, w1, w3, w2: _experts(rows, w1, w3, w2, group_sizes), rows, w1, w3, w2)
+    with jax.named_scope("moe/combine"):
+        back_combine = jax.vjp(
+            lambda out_rows, weights: combine(out_rows, weights, index), out_rows, weights)[1]
+        d_out_rows, d_weights = back_combine(d_out)
+    with jax.named_scope("moe/experts"):
+        d_rows, d_w1, d_w3, d_w2 = back_experts(d_out_rows)
+    with jax.named_scope("moe/dispatch"):
+        (d_x,) = back_dispatch(d_rows)
+    return d_x, d_weights, d_w1, d_w3, d_w2
+
+
+def _fits(capacity, group_sizes):
+    return jnp.sum(group_sizes) <= capacity
+
+
+def _either(run, capacity, *operands):
+    """``run`` (``_forward`` or ``_backward``; the operands end with order,
+    group_sizes, is_held) by rows where the step's held rows fit
+    ``capacity``, by slots where they do not; by slots alone, with no branch,
+    where ``capacity`` is all the slots."""
+    by_slots = functools.partial(run, None)
+    group_sizes, is_held = operands[-2:]
+    if capacity >= is_held.size:
+        return by_slots(*operands)
+    return lax.cond(_fits(capacity, group_sizes),
+                    functools.partial(run, capacity), by_slots, *operands)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _routed(capacity, x, weights, w1, w3, w2, order, group_sizes, is_held):
+    """The held experts' part of the layer's output.  Nothing is kept for the
+    way back but the arguments: it makes its branch's forward again, as under
+    ``jax.checkpoint``, and both ways of a branch are inside the branch."""
+    return _either(_forward, capacity, x, weights, w1, w3, w2, order, group_sizes, is_held)
+
+
+def _routed_fwd(capacity, *args):
+    return _routed(capacity, *args), args
+
+
+def _routed_bwd(capacity, args, d_out):
+    # as jax.checkpoint ties what it makes again to the cotangent: without
+    # it the compiler is free to make every layer's forward again early
+    args, d_out = lax.optimization_barrier((args, d_out))
+    grads = _either(_backward, capacity, d_out, *args)
+    # what reads the stacks' gradients (the optimizer, the health pack's sums)
+    # stays outside the branches, one copy of it and not two
+    return lax.optimization_barrier(tuple(grads)) + (None,) * 3
+
+
+_routed.defvjp(_routed_fwd, _routed_bwd)
+
+
+def held_experts_ffn(x, idx, weights, live, w1, w3, w2, spec: LMSpec, capacity: int):
+    """(part of the layer's output from the held experts, rows per held
+    expert, whether the rows overflowed ``capacity`` and the slots were used).
 
     x: (tokens, d); idx, weights: (tokens, k); live: (tokens,) bool, the
-    tokens whose output anything reads; w1, w3: (held, d, f); w2: (held, f, d)."""
+    tokens whose output anything reads; w1, w3: (held, d, f); w2: (held, f, d);
+    capacity: rows of the row buffer, ``row_capacity`` of the layer's shapes."""
     tokens, k = idx.shape
     first, held = spec.experts_held
     n = tokens * k
@@ -128,22 +313,8 @@ def held_experts_ffn(x, idx, weights, live, w1, w3, w2, spec: LMSpec):
         key = jnp.where(is_held, local, held).reshape(n)
         order = jnp.argsort(key, stable=True).astype(jnp.int32)
         group_sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
-        # rows after the last group are zero and never computed
-        valid = jnp.arange(n) < jnp.sum(group_sizes)
-        position = jnp.zeros((n,), jnp.int32).at[order].set(
-            jnp.arange(n, dtype=jnp.int32), unique_indices=True)
-        index = (order, jnp.where(is_held, position.reshape(tokens, k), 0), valid, is_held)
-        rows = rows_of_tokens(x, *index)
-    with jax.named_scope("moe/experts"):
-        dtype = x.dtype
-        both = grouped_matmul(
-            rows, jnp.concatenate([w1, w3], axis=-1).astype(dtype), group_sizes)
-        gate, up = jnp.split(both, 2, axis=-1)
-        out_rows = grouped_matmul(jax.nn.silu(gate) * up, w2.astype(dtype), group_sizes)
-    with jax.named_scope("moe/combine"):
-        picked = rows_to_slots(out_rows, *index)
-        out = jnp.sum(picked.astype(jnp.float32) * weights[..., None], axis=1).astype(dtype)
-    return out, group_sizes
+    out = _routed(capacity, x, weights, w1, w3, w2, order, group_sizes, is_held)
+    return out, group_sizes, ~_fits(capacity, group_sizes)
 
 
 class RoutedFFN(nn.Module):
@@ -169,10 +340,10 @@ class RoutedFFN(nn.Module):
         with jax.named_scope("moe/router"):
             idx, weights = route(flat, router_kernel, bias, sp)
         self.sow("intermediates", "selected", idx)
-        # The row buffers have room for every assignment, eight times what the
-        # held experts see when balanced: made again on the way back, not kept.
-        out, group_sizes = jax.checkpoint(held_experts_ffn, static_argnums=(7,))(
-            flat, idx, weights, live, w1, w3, w2, sp)
+        out, group_sizes, fell_back = held_experts_ffn(
+            flat, idx, weights, live, w1, w3, w2, sp,
+            row_capacity(b * s * sp.experts_per_tok, sp.experts_held[1], sp.num_experts))
         rows = group_sizes.astype(jnp.float32)
         return out.reshape(b, s, d), {
-            "rows_held": jnp.sum(rows), "rows_max": jnp.max(rows), "rows_mean": jnp.mean(rows)}
+            "rows_held": jnp.sum(rows), "rows_max": jnp.max(rows), "rows_mean": jnp.mean(rows),
+            "fallback": fell_back.astype(jnp.float32)}

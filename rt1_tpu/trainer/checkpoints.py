@@ -39,8 +39,6 @@ import dataclasses
 import time
 from typing import Any, Callable, List, Optional
 
-import orbax.checkpoint as ocp
-
 from rt1_tpu.resilience import faults
 from rt1_tpu.resilience.retry import RetryOptions, retry_call
 
@@ -67,6 +65,13 @@ class CheckpointManager:
     """Thin wrapper over ocp.CheckpointManager for TrainState pytrees."""
 
     def __init__(self, config: CheckpointConfig):
+        # Imported where a manager is made, not with the module: orbax takes
+        # seconds to import (its cloud logging client), and whoever imports
+        # the trainer without saving (a benchmark run, a shape check) does
+        # not pay them.
+        import orbax.checkpoint as ocp
+
+        self._ocp = ocp
         self._config = config
         options = ocp.CheckpointManagerOptions(
             max_to_keep=config.max_to_keep,
@@ -111,7 +116,7 @@ class CheckpointManager:
             # save rather than silently consuming later saves' occurrences.
             faults.maybe_fail("ckpt_save", index=op, what=f"save at step {step}")
             return self._mgr.save(
-                step, args=ocp.args.StandardSave(state), force=force
+                step, args=self._ocp.args.StandardSave(state), force=force
             )
 
         saved = bool(self._io(_save, "ckpt_save"))
@@ -187,14 +192,14 @@ class CheckpointManager:
             )
             if plan is None:
                 return self._mgr.restore(
-                    step, args=ocp.args.StandardRestore(state_like)
+                    step, args=self._ocp.args.StandardRestore(state_like)
                 )
             from rt1_tpu.parallel import reshard
 
             template = reshard.abstract_target(state_like, plan)
             try:
                 return self._mgr.restore(
-                    step, args=ocp.args.StandardRestore(template)
+                    step, args=self._ocp.args.StandardRestore(template)
                 )
             except (TypeError, ValueError, NotImplementedError) as exc:
                 # Only template-shape rejections (an Orbax that cannot
@@ -213,7 +218,7 @@ class CheckpointManager:
                     step, type(exc).__name__, exc,
                 )
                 restored = self._mgr.restore(
-                    step, args=ocp.args.StandardRestore(state_like)
+                    step, args=self._ocp.args.StandardRestore(state_like)
                 )
                 return reshard.place_on_plan(restored, plan)
 

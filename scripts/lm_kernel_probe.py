@@ -3,10 +3,15 @@ the benchmark cell's shapes: causal attention forward + backward (the
 library's Pallas flash kernel at several block sizes against the blockwise
 lax form) and the grouped expert product forward + backward
 (``lax.ragged_dot`` against megablox ``gmm`` at several tilings) with an
-eighth of the row buffer in groups.  A tool for PERF.md section 6; no
-benchmark metric reads it.
+eighth of the row buffer in groups; and the routed layer's ways between
+tokens and expert rows (``--only routed``): the slot gather against a
+scatter-add of the rows (expert order, and token order with the sorted hint)
+at a row buffer of a quarter of the slots and of all of them, the index each
+needs (the sort of all slots against a token-major compaction that sorts the
+buffer's keys only), and ``models/lm/moe.py``'s whole layer both ways at both
+capacities.  A tool for PERF.md section 6; no benchmark metric reads it.
 
-    python scripts/lm_kernel_probe.py [--rows 65536 --held_rows 8192]
+    python scripts/lm_kernel_probe.py [--rows 65536 --held_rows 8192] [--only routed]
 """
 
 import argparse
@@ -34,12 +39,20 @@ def main() -> int:
     ap.add_argument("--rows", type=int, default=65536)
     ap.add_argument("--held_rows", type=int, default=8192)
     ap.add_argument("--seq", type=int, default=8192)
+    ap.add_argument("--only", choices=("attention", "experts", "routed"), default=None)
     args = ap.parse_args()
 
     sys.path.insert(0, ".")
+    print(json.dumps({"device": jax.devices()[0].device_kind}), flush=True)
+    for part in ("attention", "experts", "routed"):
+        if args.only in (None, part):
+            {"attention": attention, "experts": experts, "routed": routed}[part](args)
+    return 0
+
+
+def attention(args) -> None:
     from rt1_tpu.models.lm import layers
 
-    print(json.dumps({"device": jax.devices()[0].device_kind}), flush=True)
     key = jax.random.PRNGKey(0)
     b, s, kvh, g, d = 2, args.seq, 8, 4, 64
     q = jax.random.normal(key, (b, s, kvh, g, d), jnp.bfloat16)
@@ -64,8 +77,12 @@ def main() -> int:
             print(json.dumps({"attention": impl, "block": block, "error": repr(exc)[:300]}),
                   flush=True)
 
+
+
+def experts(args) -> None:
     from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
 
+    key = jax.random.PRNGKey(0)
     held, dm, f = 8, 2048, 1536
     rows = jax.random.normal(key, (args.rows, dm), jnp.bfloat16)
     w13 = jax.random.normal(key, (held, dm, 2 * f), jnp.bfloat16) * 0.02
@@ -98,7 +115,130 @@ def main() -> int:
             except Exception as exc:  # noqa: BLE001
                 print(json.dumps({"experts": impl, "tiling": tiling, "rows_in_groups": held_rows,
                                   "error": repr(exc)[:300]}), flush=True)
-    return 0
+
+
+def routed(args) -> None:
+    """2 x ``--seq`` tokens, top-4 of 64 experts, 8 held, 82 % of the tokens
+    live, width 2048: the token cell's routed layer."""
+    import functools
+
+    import numpy as np
+
+    from rt1_tpu.models.lm import moe
+    from rt1_tpu.models.lm.spec import LMSpec
+    from rt1_tpu.train.configs import lfm2_moe
+
+    spec = LMSpec.from_config(lfm2_moe.get_config().model.lm, jnp.bfloat16)
+    tokens, k = 2 * args.seq, spec.experts_per_tok
+    d, f = spec.hidden_size, spec.moe_intermediate_size
+    held, num_experts = spec.experts_held[1], spec.num_experts
+    n = tokens * k
+    key = jax.random.PRNGKey(0)
+    x = jax.random.normal(key, (tokens, d), jnp.bfloat16)
+    scores = jax.random.uniform(jax.random.fold_in(key, 1), (tokens, num_experts))
+    weights, idx = lax.top_k(scores, k)
+    live = jnp.arange(tokens) % args.seq < int(0.82 * args.seq)
+    is_held = (idx < held) & live[:, None]        # experts_held starts at 0 in the config
+    sort_key = jnp.where(is_held, idx, held).reshape(n)
+    order = jnp.argsort(sort_key, stable=True).astype(jnp.int32)
+    total = int(jnp.sum(is_held))
+
+    def report(name, rows, fn, *operands):
+        try:
+            print(json.dumps({"routed": name, "rows": rows, "held_rows": total,
+                              "ms": timed(jax.jit(fn), *operands)}), flush=True)
+        except Exception as exc:  # noqa: BLE001
+            print(json.dumps({"routed": name, "rows": rows, "error": repr(exc)[:300]}),
+                  flush=True)
+
+    # the index: what each way needs before a row moves
+    report("argsort_keys", n, lambda a: jnp.argsort(a, stable=True), sort_key)
+    report("bincount", n, lambda a: jnp.bincount(a, length=held + 1), sort_key)
+    report("count_by_compare", n,
+           lambda a: jnp.sum(a[:, None] == jnp.arange(held + 1)[None, :], axis=0), sort_key)
+    report("inverse_permutation", n, lambda o: jnp.zeros((n,), jnp.int32).at[o].set(
+        jnp.arange(n, dtype=jnp.int32), unique_indices=True), order)
+
+    for rows in (n // 4, n):
+        def compaction(is_held, idx, rows=rows):
+            flat = is_held.reshape(n)
+            slot = jnp.nonzero(flat, size=rows, fill_value=n - 1)[0].astype(jnp.int32)
+            keys = jnp.where(jnp.arange(rows) < jnp.sum(flat), idx.reshape(n)[slot], held)
+            by_expert = jnp.argsort(keys, stable=True).astype(jnp.int32)
+            back = jnp.zeros((rows,), jnp.int32).at[by_expert].set(
+                jnp.arange(rows, dtype=jnp.int32), unique_indices=True)
+            return slot[by_expert], back
+
+        report("token_major_compaction_and_sort", rows, compaction, is_held, idx)
+
+        valid = jnp.arange(rows) < total
+        by_expert = order[:rows] // k                       # tokens in expert order
+        by_token = jnp.sort(jnp.where(valid, by_expert, tokens - 1))     # and in token order
+        position = jnp.zeros((n,), jnp.int32).at[order].set(
+            jnp.arange(n, dtype=jnp.int32), unique_indices=True)
+        position = jnp.where(is_held, jnp.minimum(position, rows - 1).reshape(tokens, k), 0)
+        out_rows = jax.random.normal(jax.random.fold_in(key, 2), (rows, d), jnp.bfloat16)
+        row_weights = jax.random.uniform(jax.random.fold_in(key, 3), (rows,))
+        d_out = jax.random.normal(jax.random.fold_in(key, 4), (tokens, d), jnp.bfloat16)
+
+        report("gather_rows_of_tokens", rows,
+               lambda x, t, v: jnp.where(v[:, None], x[t], 0), x, by_expert, valid)
+
+        def slot_gather_combine(out_rows, weights, position, is_held):
+            picked = jnp.where(is_held[..., None], out_rows[position], 0)
+            return jnp.sum(picked.astype(jnp.float32) * weights[..., None], axis=1).astype(
+                out_rows.dtype)
+
+        def scatter_combine(out_rows, row_weights, token, valid, sorted_=False, drop=False):
+            weighed = jnp.where(valid[:, None], out_rows, 0).astype(jnp.float32) \
+                * row_weights[:, None]
+            if drop:        # the rows after the last group go nowhere
+                token = jnp.where(valid, token, tokens)
+            return jnp.zeros((tokens, d), jnp.float32).at[token].add(
+                weighed, indices_are_sorted=sorted_, mode="drop").astype(out_rows.dtype)
+
+        report("combine_slot_gather", rows, slot_gather_combine,
+               out_rows, weights, position, is_held)
+        report("combine_scatter_add_expert_order", rows, scatter_combine,
+               out_rows, row_weights, by_expert, valid)
+        report("combine_scatter_add_expert_order_invalid_dropped", rows,
+               functools.partial(scatter_combine, drop=True),
+               out_rows, row_weights, by_expert, valid)
+        report("combine_scatter_add_bfloat16_rows", rows,
+               lambda r, t: jnp.zeros((tokens, d), r.dtype).at[t].add(r), out_rows, by_expert)
+        report("combine_scatter_add_token_order_sorted", rows,
+               functools.partial(scatter_combine, sorted_=True),
+               out_rows, row_weights, by_token, valid)
+        # dispatch's way back: a token's rows added up in float32
+        report("dispatch_back_slot_gather", rows, lambda r, p, h: jnp.sum(
+            jnp.where(h[..., None], r[p], 0).astype(jnp.float32), axis=1).astype(r.dtype),
+            out_rows, position, is_held)
+        report("dispatch_back_scatter_add", rows, lambda r, t, v: jnp.zeros(
+            (tokens, d), jnp.float32).at[t].add(
+                jnp.where(v[:, None], r, 0).astype(jnp.float32)).astype(r.dtype),
+            out_rows, by_expert, valid)
+        report("permute_rows", rows, lambda r, p: r[p], out_rows,
+               jax.random.permutation(key, rows))
+
+    # the layer as the program runs it, forward and backward, at both capacities
+    w1 = jax.random.normal(jax.random.fold_in(key, 5), (held, d, f)) * 0.02
+    w3 = jax.random.normal(jax.random.fold_in(key, 6), (held, d, f)) * 0.02
+    w2 = jax.random.normal(jax.random.fold_in(key, 7), (held, f, d)) * 0.02
+    for capacity in (moe.row_capacity(n, held, num_experts), n):
+        def layer(x, weights, w1, w3, w2, capacity=capacity):
+            out, _, fell_back = moe.held_experts_ffn(
+                x, idx, weights, live, w1, w3, w2, spec, capacity)
+            return jnp.sum((out * d_out).astype(jnp.float32)), fell_back
+
+        def forward(*a):
+            return layer(*a)[0]
+
+        report("layer_forward", capacity, forward, x, weights, w1, w3, w2)
+        report("layer_both_ways", capacity,
+               jax.grad(layer, argnums=(0, 1, 2, 3, 4), has_aux=True), x, weights, w1, w3, w2)
+    print(json.dumps({"routed": "fallback_at_capacity", "value": np.asarray(
+        moe.held_experts_ffn(x, idx, weights, live, w1, w3, w2, spec,
+                             moe.row_capacity(n, held, num_experts))[2]).item()}), flush=True)
 
 
 if __name__ == "__main__":

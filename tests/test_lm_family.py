@@ -18,7 +18,7 @@ import pytest
 from benchmarks import weights
 from benchmarks.references import lfm2_moe as ref
 from rt1_tpu.data.tokens import IGNORE, PackedTokenFeed, feed_from_config
-from rt1_tpu.models.lm import layers
+from rt1_tpu.models.lm import layers, moe
 from rt1_tpu.models.lm.moe import RoutedFFN
 from rt1_tpu.models.lm.spec import LMSpec
 from rt1_tpu.train.configs import lfm2_moe
@@ -92,6 +92,7 @@ def test_program_against_the_reference(world):
     # the expert bias enters the selection only
     assert all(float(jnp.max(jnp.abs(v))) == 0.0 for k, v in got.items() if "expert_bias" in k)
     assert float(out["counters"]["moe/assignments_held"]) > 0
+    assert float(out["counters"]["moe/fallback_layers"]) == 0.0
 
 
 def _routed_layer(config, held):
@@ -116,11 +117,20 @@ def _share(params, first, count):
     return cut
 
 
-def test_the_shares_add_up():
+# A layer of 2 x 32 tokens has fewer slots than one row tile, so its row buffer
+# is all of them (the slot path, no branch); at 2 x 512 a quarter share's buffer
+# is half the slots (the row path, the slot path behind it).
+SEQ_BY_PATH = {"slots": 32, "rows": 512}
+
+
+@pytest.mark.parametrize("path", list(SEQ_BY_PATH))
+def test_the_shares_add_up(path):
     """What the shares [0,4) ... [12,16) give, summed, is the uncut layer."""
     config = small_config()
     params = _full_layer_params(config)
-    x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, config.model.lm.hidden_size))
+    seq = SEQ_BY_PATH[path]
+    assert (moe.row_capacity(2 * seq * 4, 4, 16) < 2 * seq * 4) == (path == "rows")
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, seq, config.model.lm.hidden_size))
     total = 0.0
     rows = 0.0
     for first in range(0, 16, 4):
@@ -128,13 +138,18 @@ def test_the_shares_add_up():
             {"params": _share(params, first, 4)}, x)
         total = total + out
         rows += float(counters["rows_held"])
+        assert float(counters["fallback"]) == 0.0
     whole = ref.routed_ffn(x, params, reference_sizes(config), "highest", held=(0, 16))
     close(total, whole, "sum of the shares")
-    assert rows == 2 * 32 * 4       # every assignment computed once, none dropped
+    assert rows == 2 * seq * 4       # every assignment computed once, none dropped
 
 
-def test_dropless_under_imbalance(world):
-    """Every token selects held expert 5: its group is the whole batch."""
+@pytest.mark.parametrize("capacity", [None, 64, 96])
+def test_dropless_under_imbalance(world, monkeypatch, capacity):
+    """Every token selects held expert 5: its group is the whole batch, and
+    with the others' 35 rows it overflows a row buffer of 64 or 96 rows."""
+    if capacity is not None:
+        monkeypatch.setattr(moe, "row_capacity", lambda *shape: capacity)
     config = small_config()
     params = _full_layer_params(config)
     lm = config.model.lm
@@ -154,21 +169,107 @@ def test_dropless_under_imbalance(world):
     assert float(counters["rows_max"]) == 64.0
     assert float(counters["rows_max"] / counters["rows_mean"]) == pytest.approx(
         per_expert.max() / per_expert.mean())
+    assert per_expert.sum() == 99
+    assert float(counters["fallback"]) == (0.0 if capacity is None else 1.0)
 
 
-def test_tail_padding_takes_no_rows():
+def _layer_both_ways(layer, params, x, probe):
+    def loss(params, x):
+        out, counters = layer.apply({"params": params}, x)
+        return jnp.sum(out * probe), (out, counters)
+
+    (_, (out, counters)), grads = jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(
+        params, x)
+    return out, counters, grads
+
+
+@pytest.mark.parametrize("room", ["spare", "none", "short"])
+def test_the_row_path_is_the_slot_path(monkeypatch, room):
+    """Outputs and gradients (x, the three stacks, the router) with a row
+    buffer that has rows to spare, exactly the rows held, and one too few
+    (the slot path under the branch) against the slot path with no branch."""
+    config = small_config()
+    lm = config.model.lm
+    first, count = lm.experts_held
+    layer = _routed_layer(config, (first, count))
+    params = _share(_full_layer_params(config), first, count)
+    x = jax.random.normal(jax.random.PRNGKey(9), (2, 32, lm.hidden_size))
+    probe = jax.random.normal(jax.random.PRNGKey(10), x.shape)
+    with jax.default_matmul_precision("highest"):
+        want, counters, want_grads = _layer_both_ways(layer, params, x, probe)
+        held = int(counters["rows_held"])
+        assert 32 < held < 128 and float(counters["fallback"]) == 0.0
+        capacity = {"spare": 128, "none": held, "short": held - 1}[room]
+        monkeypatch.setattr(moe, "row_capacity", lambda *shape: capacity)
+        got, counters, got_grads = _layer_both_ways(layer, params, x, probe)
+    assert float(counters["fallback"]) == (1.0 if room == "short" else 0.0)
+    assert int(counters["rows_held"]) == held
+    flat_got = flax.traverse_util.flatten_dict(got_grads[0], sep="/")
+    flat_want = flax.traverse_util.flatten_dict(want_grads[0], sep="/")
+    assert set(flat_got) == {"router/kernel", "expert_bias/kernel", "experts/w1/kernel",
+                             "experts/w3/kernel", "experts/w2/kernel"}
+    for what, a, b in [("out", got, want), ("x", got_grads[1], want_grads[1])] + [
+            (path, flat_got[path], flat_want[path]) for path in flat_want]:
+        a, b = np.asarray(a), np.asarray(b)
+        assert float(np.max(np.abs(a - b))) <= 1e-6 * float(np.max(np.abs(b))) + 1e-30, what
+    assert float(jnp.max(jnp.abs(flat_got["router/kernel"]))) > 0.0
+
+
+@pytest.mark.parametrize("n, held, experts, rows", [
+    (2 * 8192 * 4, 8, 64, 16384),       # the token cell: a quarter of the slots
+    (2 * 512 * 4, 4, 16, 2048),         # twice the balanced share
+    (2 * 8192 * 4, 3, 64, 6144),        # 2 x 3,072, a whole number of row tiles
+    (2 * 520 * 4, 4, 16, 2560),         # 2 x 1,040 = 2,080, rounded up to the tile
+    (2 * 32 * 4, 4, 16, 256),           # fewer slots than a tile: all of them
+    (2 * 512 * 4, 16, 16, 4096),        # every expert held: all the slots
+    (2 * 512 * 4, 9, 16, 4096),         # more than half held: all the slots
+])
+def test_the_capacity_rule(n, held, experts, rows):
+    assert moe.row_capacity(n, held, experts) == rows
+    assert rows == n or (rows % moe.MEGABLOX_TILING[0] == 0 and rows >= 2 * n * held / experts)
+
+
+@pytest.mark.parametrize("held, branches", [((4, 4), True), ((0, 16), False)])
+def test_a_branch_only_where_the_buffer_is_smaller(held, branches):
+    """A layer that holds every expert has all the slots for a buffer: the
+    slot path, forward and backward, with no ``cond`` traced."""
+    config = small_config()
+    layer = _routed_layer(config, held)
+    x = jnp.zeros((2, 512, config.model.lm.hidden_size))
+    params = jax.eval_shape(lambda r: layer.init(r, x), jax.random.PRNGKey(0))["params"]
+
+    def loss(params, x):
+        return jnp.sum(layer.apply({"params": params}, x)[0])
+
+    forward = str(jax.make_jaxpr(loss)(params, x))
+    backward = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(params, x))
+    assert (" cond[" in forward) == branches
+    assert (backward.count(" cond[") == 2) == branches      # forward, and the way back
+    assert branches or " cond[" not in backward
+
+
+@pytest.mark.parametrize("path", list(SEQ_BY_PATH))
+def test_tail_padding_takes_no_rows(path):
     """Positions that are not live are routed but computed by no expert."""
     config = small_config()
     lm = config.model.lm
-    params = _full_layer_params(config)
-    x = jax.random.normal(jax.random.PRNGKey(8), (2, 32, lm.hidden_size))
-    live = jnp.arange(32)[None, :] < jnp.array([[20], [32]])
-    layer = _routed_layer(config, (0, lm.num_experts))
+    seq = SEQ_BY_PATH[path]
+    cut = seq * 5 // 8
+    # all the experts held (every slot a row), or a quarter of them (the row path)
+    first, count = (0, lm.num_experts) if path == "slots" else (4, 4)
+    params = _share(_full_layer_params(config), first, count)
+    x = jax.random.normal(jax.random.PRNGKey(8), (2, seq, lm.hidden_size))
+    live = jnp.arange(seq)[None, :] < jnp.array([[cut], [seq]])
+    layer = _routed_layer(config, (first, count))
     out, counters = layer.apply({"params": params}, x, live)
     whole, _ = layer.apply({"params": params}, x)
-    assert float(counters["rows_held"]) == (20 + 32) * 4
-    assert float(jnp.max(jnp.abs(out[0, 20:]))) == 0.0
-    close(out[0, :20], whole[0, :20], "live positions")
+    idx, _ = ref.route(x.reshape(-1, lm.hidden_size), params, reference_sizes(config))
+    held = (np.asarray(idx) >= first) & (np.asarray(idx) < first + count)
+    assert float(counters["rows_held"]) == held[np.asarray(live).reshape(-1)].sum()
+    assert path == "rows" or float(counters["rows_held"]) == (cut + seq) * 4
+    assert float(counters["fallback"]) == 0.0
+    assert float(jnp.max(jnp.abs(out[0, cut:]))) == 0.0
+    close(out[0, :cut], whole[0, :cut], "live positions")
     close(out[1], whole[1], "a sequence with no padding")
 
 

@@ -503,3 +503,25 @@ def test_obs_imports_without_training_deps():
         f"stdout: {proc.stdout}\nstderr: {proc.stderr}"
     )
     assert "OK" in proc.stdout
+
+
+def test_the_trainer_imports_without_orbax():
+    """Orbax (seconds to import) comes in when a checkpoint manager is made,
+    not with ``rt1_tpu.train.train``: a benchmark run builds its step from
+    ``build_family`` and ``make_train_step_fns`` and saves nothing."""
+    probe = (
+        "import sys\n"
+        "from rt1_tpu.train.train import build_family\n"
+        "from rt1_tpu.trainer import make_train_step_fns\n"
+        "from rt1_tpu.trainer.checkpoints import CheckpointConfig, CheckpointManager\n"
+        "assert not [m for m in sys.modules if m.split('.')[0] == 'orbax'], 'orbax imported'\n"
+        "import tempfile\n"
+        "CheckpointManager(CheckpointConfig(directory=tempfile.mkdtemp())).close()\n"
+        "assert 'orbax.checkpoint' in sys.modules\n"
+        "print('OK')\n"
+    )
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run(
+        [sys.executable, "-c", probe], capture_output=True, text=True, timeout=180, cwd=repo,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert proc.returncode == 0 and "OK" in proc.stdout, proc.stderr[-2000:]

@@ -193,22 +193,28 @@ def test_lm_attention_compiles_both_ways_at_the_published_widths(v5e, monkeypatc
     assert text.count("tpu_custom_call") >= 3       # forward, dq, dk and dv
 
 
-def test_lm_routed_experts_compile_both_ways_at_the_published_widths(v5e, monkeypatch):
+@pytest.mark.parametrize("buffer", ["the rule's rows", "all the slots"])
+def test_lm_routed_experts_compile_both_ways_at_the_published_widths(v5e, monkeypatch, buffer):
     """One routed layer of the family at 16,384 tokens, 8 of 64 experts held:
-    sort, gathers and the megablox products, forward and backward."""
+    sort, gathers and the megablox products, forward and backward, with the
+    row buffer the layer's shapes give (a quarter of the slots, the slot path
+    behind a branch) and with all the slots (the slot path alone)."""
     from rt1_tpu.models.lm import moe
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     sp = _lm_spec()
     one_chip = SingleDeviceSharding(v5e.devices[0])
     tokens, d, f, held = 16384, sp.hidden_size, sp.moe_intermediate_size, sp.experts_held[1]
+    n = tokens * sp.experts_per_tok
+    capacity = moe.row_capacity(n, held, sp.num_experts) if buffer == "the rule's rows" else n
+    assert capacity == (16384 if buffer == "the rule's rows" else 65536)
 
     def shape(dims, dtype):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
     def both_ways(x, weights, w1, w3, w2, idx, live):
         return jax.grad(lambda x, weights, w1, w3, w2: jnp.sum(moe.held_experts_ffn(
-            x, idx, weights, live, w1, w3, w2, sp)[0].astype(jnp.float32)),
+            x, idx, weights, live, w1, w3, w2, sp, capacity)[0].astype(jnp.float32)),
             argnums=(0, 1, 2, 3, 4))(x, weights, w1, w3, w2)
 
     compiled = jax.jit(both_ways).lower(
@@ -218,5 +224,7 @@ def test_lm_routed_experts_compile_both_ways_at_the_published_widths(v5e, monkey
         shape((tokens,), jnp.bool_)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
-    # the gathers go back as gathers: no scatter over the row buffer's 2048-wide rows
-    assert not re.findall(r"bf16\[65536,2048\]\S* scatter\(", text)
+    assert (" conditional(" in text) == (capacity < n)
+    # the slot path's gathers go back as gathers, the row path adds up a
+    # buffer's rows: no scatter over 65,536 rows of 2048 on either
+    assert not re.findall(r"\[65536,2048\]\S* scatter\(", text)
