@@ -1,8 +1,8 @@
 """One train step under each parallelism mode on an 8-device virtual mesh.
 
 The reference's only parallelism is data-parallel DDP
-(`distribute_train.py:235`); this framework's mesh covers five modes, all
-reachable from the train config (`config.mesh.*` + `config.model.*`). This
+(`distribute_train.py:235`); this framework's mesh covers three modes, all
+reachable from the train config (`config.parallel.*`). This
 example runs ONE optimizer step of a tiny RT-1 under each, hermetically on
 CPU (`--xla_force_host_platform_device_count=8` — the same GSPMD
 partitioner and collectives XLA uses on a real TPU slice).
@@ -82,17 +82,12 @@ def main():
         # (label, mesh config, model kwargs)
         ("dp  (data parallel, DDP equivalent)", MeshConfig(), {}),
         ("tp  (tensor parallel heads/FFN)", MeshConfig(data=2, model=4), {}),
-        ("sp  (ring attention over seq)", MeshConfig(seq=2), {}),
         ("pp  (GPipe over decoder layers)", MeshConfig(data=2, stage=4),
          dict(pipeline_microbatches=2)),
-        ("ep  (Switch MoE expert FFN)", MeshConfig(data=2, model=4),
-         dict(ffn_impl="moe", num_experts=4)),
     ]
     for label, mesh_cfg, model_kw in modes:
         mesh = make_mesh(mesh_cfg)
         kw = dict(model_kw)
-        if mesh.shape["seq"] > 1:
-            kw.update(attention_impl="ring", mesh=mesh)
         if mesh.shape["stage"] > 1:
             kw.update(mesh=mesh)
         model = tiny(**kw)

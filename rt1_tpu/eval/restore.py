@@ -107,7 +107,7 @@ def serving_plan(config):
 
     Serving has no batch axis to shard (sessions are slots, not data
     shards), so `dp` collapses to 1 and the mesh covers exactly the
-    fsdp × tp × pp × sp devices model parallelism needs — for the default
+    fsdp × tp × pp devices model parallelism needs — for the default
     all-ones config that is a 1-device mesh, byte-identical placement to
     the pre-plan engine. A backend that fails to initialize (no chip, or
     a chip another process holds) raises here, by name, at startup.

@@ -22,8 +22,8 @@ mechanics of the `inference_dtype` engine mode (f32 | bf16 | int8):
   decided here: `rt1_tpu/parallel/plan.py` declares the quantization
   group per param path with the same path-regex machinery as the sharding
   rules, so "what gets int8" reads next to "how it shards" — norms,
-  embeddings, the action head, BatchNorm statistics, and the fp32 MoE
-  router stay at the master dtype by explicit rule.
+  embeddings, the action head and BatchNorm statistics stay at the
+  master dtype by explicit rule.
 * **bf16 mode.** `cast_tree` casts every float leaf once at restore;
   paired with a bf16-compute model this is bit-identical to flax's own
   compute-dtype cast at use sites (pinned in tests/test_quant.py), while

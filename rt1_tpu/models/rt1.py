@@ -124,25 +124,12 @@ class RT1Policy(nn.Module):
     action_decode: str = "argmax"
     return_attention_scores: bool = False
     dtype: jnp.dtype = jnp.float32
-    # "dense" (default), "ring", or "pallas". "ring" shards the token
-    # sequence over the mesh's ``seq`` axis (sequence/context parallelism
-    # for long-horizon variants; requires `mesh` with a >1 seq axis).
-    # "pallas" fuses inference attention into one VMEM kernel on TPU
-    # (training takes the dense math: the kernel is forward-only; off-TPU
-    # it raises unless `pallas_interpret`).
+    # "dense" (default) or "pallas". "pallas" fuses inference attention
+    # into one VMEM kernel on TPU (training takes the dense math: the kernel
+    # is forward-only; off-TPU it raises unless `pallas_interpret`).
     attention_impl: str = "dense"
     mesh: Optional[Any] = None
     pallas_interpret: bool = False  # test-only: run the kernel off-TPU
-    # FFN choice for the decoder blocks: "dense" (reference parity) or "moe"
-    # (Switch-routed expert FFN, rt1_tpu/models/moe.py — expert-parallel when
-    # the stacked expert weights are sharded over 'model'). The Switch
-    # load-balancing aux loss is sown into intermediates and added to the
-    # training loss by the trainer with weight `moe_aux_weight`.
-    ffn_impl: str = "dense"
-    num_experts: int = 4
-    moe_capacity_factor: float = 2.0
-    moe_ff_dim: Optional[int] = None
-    moe_aux_weight: float = 0.01
     # Pipeline parallelism: when `mesh` has a >1 "stage" axis, the decoder's
     # layer stack runs GPipe-pipelined over it (parallel/pipeline.py) with
     # this many microbatches per step; per-(layer, microbatch) dropout rngs
@@ -220,12 +207,7 @@ class RT1Policy(nn.Module):
             return_attention_scores=self.return_attention_scores,
             dtype=self.dtype,
             attention_impl=self.attention_impl,
-            mesh=self.mesh,
             pallas_interpret=self.pallas_interpret,
-            ffn_impl=self.ffn_impl,
-            num_experts=self.num_experts,
-            moe_capacity_factor=self.moe_capacity_factor,
-            moe_ff_dim=self.moe_ff_dim,
             remat=self.remat,
         )
         self._mask = rt1_attention_mask(

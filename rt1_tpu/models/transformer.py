@@ -34,7 +34,7 @@ path is untouched — byte-identical to the pre-cache program.
 
 from __future__ import annotations
 
-from typing import Any, Optional, Tuple
+from typing import Optional, Tuple
 
 import flax.linen as nn
 import jax.numpy as jnp
@@ -45,25 +45,17 @@ NEG_INF = -1e9
 
 
 class TFMultiHeadAttention(nn.Module):
-    """tf.keras-style MHA: qkv project d_model → heads·key_dim, out back to d_model.
-
-    `attention_impl="ring"` + a mesh with a >1 ``seq`` axis computes the same
-    attention ring-parallel over sequence shards (rt1_tpu/parallel/
-    ring_attention.py) — exact, but attention probabilities are never
-    materialized, so prob-dropout is skipped and no scores are returned.
-    """
+    """tf.keras-style MHA: qkv project d_model → heads·key_dim, out back to d_model."""
 
     num_heads: int
     key_dim: int
     d_model: int
     dropout_rate: float = 0.1
     dtype: jnp.dtype = jnp.float32
-    # "dense" | "ring" | "pallas". "ring" needs `mesh` with a >1 seq axis;
-    # "pallas" is the fused inference kernel: it runs whenever train=False
-    # (it has no autodiff rule, so train=True takes the dense math) and
-    # raises off-TPU unless `pallas_interpret` is set.
+    # "dense" | "pallas". "pallas" is the fused inference kernel: it runs
+    # whenever train=False (it has no autodiff rule, so train=True takes the
+    # dense math) and raises off-TPU unless `pallas_interpret` is set.
     attention_impl: str = "dense"
-    mesh: Optional[Any] = None
     # Run the pallas kernel in interpreter mode (tests off-TPU; orders of
     # magnitude slower than dense; never set in production).
     pallas_interpret: bool = False
@@ -143,27 +135,6 @@ class TFMultiHeadAttention(nn.Module):
             out = out.reshape(b, s, h * k)
             return QuantDense(self.d_model, dtype=self.dtype, name="out")(out), None
 
-        use_ring = (
-            self.attention_impl == "ring"
-            and self.mesh is not None
-            and self.mesh.shape.get("seq", 1) > 1
-        )
-        if use_ring:
-            from rt1_tpu.parallel.ring_attention import ring_attention
-
-            if mask is not None and mask.ndim != 2:
-                raise ValueError("ring attention supports (s, s) masks only")
-            out = ring_attention(
-                q,
-                kk,
-                v,
-                self.mesh,
-                mask=mask,
-                scale=1.0 / float(k) ** 0.5,
-            )
-            out = out.reshape(b, s, h * k)
-            return QuantDense(self.d_model, dtype=self.dtype, name="out")(out), None
-
         # (b, h, sq, sk) attention logits; fp32 softmax for stability under bf16.
         logits = jnp.einsum("bshd,bthd->bhst", q, kk, preferred_element_type=jnp.float32)
         logits = logits / jnp.sqrt(jnp.asarray(k, jnp.float32))
@@ -184,10 +155,7 @@ class TFMultiHeadAttention(nn.Module):
 class TransformerLayer(nn.Module):
     """Pre-norm block: x + MHA(LN(x)); x + Dropout(FFN(LN(x))) (reference :130-144).
 
-    ``ffn_impl="dense"`` is the reference-parity single square Dense;
-    ``ffn_impl="moe"`` swaps in the Switch-routed expert FFN
-    (rt1_tpu/models/moe.py) — its load-balancing aux loss is sown into the
-    "intermediates" collection under "moe_aux_loss".
+    The FFN is the reference-parity single square Dense.
     """
 
     key_dim: int
@@ -196,12 +164,7 @@ class TransformerLayer(nn.Module):
     dropout_rate: float = 0.1
     dtype: jnp.dtype = jnp.float32
     attention_impl: str = "dense"
-    mesh: Optional[Any] = None
     pallas_interpret: bool = False
-    ffn_impl: str = "dense"          # "dense" | "moe"
-    num_experts: int = 4
-    moe_capacity_factor: float = 2.0
-    moe_ff_dim: Optional[int] = None  # expert hidden width; None → d_model
 
     @nn.compact
     def __call__(
@@ -217,26 +180,12 @@ class TransformerLayer(nn.Module):
             dropout_rate=self.dropout_rate,
             dtype=self.dtype,
             attention_impl=self.attention_impl,
-            mesh=self.mesh,
             pallas_interpret=self.pallas_interpret,
             name="attn",
         )(y, mask=mask, train=train, kv_cache=kv_cache, cache_index=cache_index)
         x = x + attn_out
         y = nn.LayerNorm(dtype=self.dtype, name="norm_2")(x)
-        if self.ffn_impl == "moe":
-            from rt1_tpu.models.moe import MoEFeedForward
-
-            y, aux = MoEFeedForward(
-                d_model=self.d_model,
-                num_experts=self.num_experts,
-                ff_dim=self.moe_ff_dim,
-                capacity_factor=self.moe_capacity_factor,
-                dtype=self.dtype,
-                name="moe",
-            )(y)
-            self.sow("intermediates", "moe_aux_loss", aux)
-        else:
-            y = QuantDense(self.d_model, dtype=self.dtype, name="ff")(y)
+        y = QuantDense(self.d_model, dtype=self.dtype, name="ff")(y)
         y = nn.Dropout(self.dropout_rate, deterministic=not train)(y)
         return x + y, scores
 
@@ -254,12 +203,7 @@ class CausalTransformer(nn.Module):
     return_attention_scores: bool = False
     dtype: jnp.dtype = jnp.float32
     attention_impl: str = "dense"
-    mesh: Optional[Any] = None
     pallas_interpret: bool = False
-    ffn_impl: str = "dense"          # "dense" | "moe" (expert-parallel FFN)
-    num_experts: int = 4
-    moe_capacity_factor: float = 2.0
-    moe_ff_dim: Optional[int] = None
     # jax.checkpoint each block: recompute activations in the backward pass
     # instead of storing them (O(layers)→O(1) activation memory, ~1/3 extra
     # FLOPs). Semantics-preserving; exactness pinned in tests/test_rt1.py.
@@ -308,14 +252,10 @@ class CausalTransformer(nn.Module):
                     dropout_rate=self.dropout_rate,
                     dtype=self.dtype,
                     # Decode always uses the dense einsum math: the
-                    # ring/pallas kernels are full-sequence (square-mask)
-                    # implementations and decode's prefix attention is a
-                    # (s × cache_len) sliver that doesn't need them.
+                    # pallas kernel is a full-sequence (square-mask)
+                    # implementation and decode's prefix attention is a
+                    # (s × cache_len) sliver that doesn't need it.
                     attention_impl="dense",
-                    ffn_impl=self.ffn_impl,
-                    num_experts=self.num_experts,
-                    moe_capacity_factor=self.moe_capacity_factor,
-                    moe_ff_dim=self.moe_ff_dim,
                     name=f"layer_{i}",
                 )(x, attention_mask, False, kv_cache[:, i], cache_index)
                 new_caches.append(layer_cache)
@@ -323,12 +263,9 @@ class CausalTransformer(nn.Module):
                 self.vocab_size, dtype=self.dtype, name="output_tokens"
             )(x)
             return logits, jnp.stack(new_caches, axis=1)
-        if self.return_attention_scores and self.attention_impl in (
-            "ring",
-            "pallas",
-        ):
+        if self.return_attention_scores and self.attention_impl == "pallas":
             raise ValueError(
-                "attention scores are not materialized under ring/pallas "
+                "attention scores are not materialized under pallas "
                 "attention; use attention_impl='dense' for score "
                 "visualization"
             )
@@ -352,12 +289,7 @@ class CausalTransformer(nn.Module):
                 dropout_rate=self.dropout_rate,
                 dtype=self.dtype,
                 attention_impl=self.attention_impl,
-                mesh=self.mesh,
                 pallas_interpret=self.pallas_interpret,
-                ffn_impl=self.ffn_impl,
-                num_experts=self.num_experts,
-                moe_capacity_factor=self.moe_capacity_factor,
-                moe_ff_dim=self.moe_ff_dim,
                 name=f"layer_{i}",
             )(x, attention_mask, train)
             if self.return_attention_scores:

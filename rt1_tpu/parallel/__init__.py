@@ -8,7 +8,7 @@ we lay out a single `jax.sharding.Mesh` over the slice and let GSPMD insert XLA
 collectives (psum / all-gather / reduce-scatter) over ICI.
 
 Layout policy lives in `plan.py`: one declarative (name-pattern →
-PartitionSpec) plan over the ``('data', 'stage', 'fsdp', 'seq', 'model')``
+PartitionSpec) plan over the ``('data', 'stage', 'fsdp', 'model')``
 mesh, resolved once from `config.parallel` and consumed identically by train,
 eval, and serve — dense/fsdp/tp/pp are config switches, not code paths.
 """
@@ -36,7 +36,6 @@ from rt1_tpu.parallel.plan import (
 )
 from rt1_tpu.parallel.sharding import (
     batch_sharding,
-    moe_parameter_rules,
     replicated,
     rt1_parameter_rules,
     shard_pytree,
@@ -56,7 +55,6 @@ __all__ = [
     "make_mesh",
     "batch_sharding",
     "mixed_precision_from_config",
-    "moe_parameter_rules",
     "pipeline_apply",
     "pp_causal_transformer_apply",
     "replicated",

@@ -11,9 +11,7 @@ all fuse with zero HBM intermediates.
 Scope (documented): forward-only — used for inference; training uses the
 XLA dense path (which autodiffs). Whole-sequence blocks are used rather
 than a flash-style K/V loop because s^2 fp32 fits VMEM comfortably up to
-s ~ 1024 (4 MB); long-context sharding is ring attention's job
-(`rt1_tpu/parallel/ring_attention.py`), and this kernel can serve as its
-per-shard block compute.
+s ~ 1024 (4 MB).
 
 Set `interpret=True` to run on CPU (tests do this; on TPU it lowers to
 Mosaic).
@@ -72,7 +70,7 @@ def fused_attention(
     """Fused multi-head attention. q/k/v: (b, s, h, d); mask: (s, s) 0/1.
 
     Returns (b, s, h, d), matching
-    `rt1_tpu/parallel/ring_attention.py::dense_attention_reference`.
+    `tests/attention_reference.py::dense_attention_reference`.
     """
     b, s_in, h, d_in = q.shape
     if scale is None:

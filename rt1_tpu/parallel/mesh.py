@@ -13,11 +13,8 @@ Axis conventions used throughout rt1_tpu:
   (per the plan in rt1_tpu/parallel/plan.py), so GSPMD emits all-gathers for
   weights at use sites and reduce-scatters for gradients.
 * ``model`` — tensor parallelism (attention heads / FFN columns).
-* ``seq``   — sequence/context parallelism (ring attention); unused for the 66-token
-  RT-1 window (SURVEY.md §5 "long-context: absent") but first-class in the API so
-  long-horizon variants can turn it on.
 * ``stage`` — pipeline parallelism (GPipe-style microbatch rotation over layer
-  stages, rt1_tpu/parallel/pipeline.py). Like ``seq``, beyond reference parity.
+  stages, rt1_tpu/parallel/pipeline.py). Beyond reference parity.
 
 All axes are optional; size-1 axes are free (no collectives are emitted for them).
 """
@@ -39,25 +36,23 @@ class MeshConfig:
     data: int = -1
     fsdp: int = 1
     model: int = 1
-    seq: int = 1
     stage: int = 1
 
     def resolve(self, n_devices: int) -> "MeshConfig":
-        fixed = self.fsdp * self.model * self.seq * self.stage
+        fixed = self.fsdp * self.model * self.stage
         if n_devices % fixed != 0:
             raise ValueError(
                 f"{n_devices} devices not divisible by "
-                f"fsdp*model*seq*stage={fixed}"
+                f"fsdp*model*stage={fixed}"
             )
         data = self.data if self.data != -1 else n_devices // fixed
         if data * fixed != n_devices:
             raise ValueError(
-                f"mesh {data}x{self.stage}x{self.fsdp}x{self.seq}x"
-                f"{self.model} != {n_devices} devices"
+                f"mesh {data}x{self.stage}x{self.fsdp}x{self.model} "
+                f"!= {n_devices} devices"
             )
         return MeshConfig(
-            data=data, fsdp=self.fsdp, model=self.model, seq=self.seq,
-            stage=self.stage,
+            data=data, fsdp=self.fsdp, model=self.model, stage=self.stage
         )
 
 
@@ -65,7 +60,7 @@ def make_mesh(
     config: MeshConfig = MeshConfig(),
     devices: Optional[Sequence[jax.Device]] = None,
 ) -> Mesh:
-    """Build a ('data', 'stage', 'fsdp', 'seq', 'model') mesh over `devices`
+    """Build a ('data', 'stage', 'fsdp', 'model') mesh over `devices`
     (default: all).
 
     Axis order puts ``model`` innermost so tensor-parallel collectives ride the
@@ -75,11 +70,9 @@ def make_mesh(
     its per-layer weight all-gathers are bandwidth-hungry like TP but overlap
     with compute, so it takes the middle hops. ``stage`` sits next to ``data``:
     pipeline ppermutes are point-to-point once per microbatch tick — far less
-    bandwidth-hungry than TP/SP collectives — so they get the longer hops.
+    bandwidth-hungry than TP collectives — so they get the longer hops.
     """
     devices = list(devices if devices is not None else jax.devices())
     cfg = config.resolve(len(devices))
-    arr = np.asarray(devices).reshape(
-        cfg.data, cfg.stage, cfg.fsdp, cfg.seq, cfg.model
-    )
-    return Mesh(arr, axis_names=("data", "stage", "fsdp", "seq", "model"))
+    arr = np.asarray(devices).reshape(cfg.data, cfg.stage, cfg.fsdp, cfg.model)
+    return Mesh(arr, axis_names=("data", "stage", "fsdp", "model"))

@@ -209,17 +209,6 @@ def pp_causal_transformer_apply(
     Bernoulli mask) but not its bitstream — with `dropout_rate > 0` the
     pipelined and sequential losses are equal in expectation, not bitwise;
     exactness tests must set `dropout_rate = 0`.
-
-    MoE caveat (``ffn_impl="moe"``): expert capacity is computed over the
-    tokens of each *forward call*, so under PP it binds per microbatch
-    (b/M·s tokens) rather than per batch — the standard per-device-batch
-    semantics of MoE systems. Outputs match the sequential module exactly
-    whenever no expert overflows its capacity (e.g. capacity_factor ≥
-    num_experts guarantees it for top-1 routing); when drops do occur, the
-    two schedules may drop different tokens. *Training* under PP+MoE is
-    rejected: the Switch load-balancing aux loss is sown via `self.sow`,
-    which an unmutable `layer.apply` inside the stage silently discards —
-    training would lose the regularizer and invite router collapse.
     """
     from rt1_tpu.models.transformer import TransformerLayer
 
@@ -229,18 +218,11 @@ def pp_causal_transformer_apply(
     x = x + p["position_emb"]["embedding"][None, :s, :]
 
     if transformer.attention_impl != "dense":
-        # Ring/pallas attention inside a pipelined stage would nest their
-        # own collectives/kernels under this shard_map; unsupported.
+        # The pallas kernel inside a pipelined stage would nest under this
+        # shard_map; unsupported.
         raise ValueError(
             "pipeline parallelism supports attention_impl='dense' only, "
             f"got {transformer.attention_impl!r}"
-        )
-    if train and transformer.ffn_impl == "moe":
-        raise ValueError(
-            "training with pipeline parallelism + MoE FFN is unsupported: "
-            "the Switch aux loss sown inside the stage would be discarded "
-            "(no mutable collections cross the shard_map); use ffn_impl="
-            "'dense' under PP or train MoE on a stage=1 mesh"
         )
     use_dropout = train and transformer.dropout_rate > 0
     if use_dropout and dropout_rng is None:
@@ -263,10 +245,6 @@ def pp_causal_transformer_apply(
         d_model=transformer.d_model,
         dropout_rate=transformer.dropout_rate,
         dtype=transformer.dtype,
-        ffn_impl=transformer.ffn_impl,
-        num_experts=transformer.num_experts,
-        moe_capacity_factor=transformer.moe_capacity_factor,
-        moe_ff_dim=transformer.moe_ff_dim,
         # Detach from any enclosing module context: this is a stateless
         # stage template applied with explicit params, not a submodule
         # (RT1Policy calls this helper from inside its own apply).

@@ -10,12 +10,13 @@ the eval/serve path. Here the whole layout is ONE ordered list of
 ``(path-regex, PartitionSpec)`` rules in the GSPMD annotation-driven style
 (Xu et al., 2021): annotate where each weight lives, let the partitioner
 propagate everything else. The axes the specs name are the
-``('data', 'stage', 'fsdp', 'seq', 'model')`` mesh of `parallel/mesh.py`:
+``('data', 'stage', 'fsdp', 'model')`` mesh of `parallel/mesh.py`:
 
 * ``fsdp`` — ZeRO-3 weight sharding. The batch is sharded over it together
   with ``data``; weight matrices shard one dimension over it, so GSPMD emits
   per-layer all-gathers at use sites and reduce-scatters for gradients.
-* ``model`` — tensor parallelism (attention heads / FFN columns, MoE experts).
+* ``model`` — tensor parallelism (attention heads / FFN columns; the
+  `models/lm` family's experts).
 
 Every spec is written against all axes; size-1 axes are free, so the same plan
 degenerates to pure DP on a `dp=N` mesh at zero cost. Kernel layouts are Flax
@@ -93,10 +94,9 @@ def rt1_sharding_plan() -> List[Rule]:
     """THE plan: ordered (path-regex, PartitionSpec) over every RT-1 param
     group. First match wins; paths are '/'-joined flax param paths.
 
-    Folds the former `rt1_parameter_rules` + `moe_parameter_rules` (which
-    covered only the decoder) and extends them to the FiLM-EfficientNet
-    tokenizer, TokenLearner, embeddings, and the action head, so the
-    coverage check can demand an explicit decision for every weight matrix.
+    Covers the decoder, the FiLM-EfficientNet tokenizer, TokenLearner,
+    embeddings, and the action head, so the coverage check can demand an
+    explicit decision for every weight matrix.
     Norms/biases/BN stats are explicitly replicated — listed, not fallen
     through, so `coverage` distinguishes "decided small" from "forgotten".
     """
@@ -114,14 +114,6 @@ def rt1_sharding_plan() -> List[Rule]:
         (r"transformer/layer_\d+/ff/kernel$", P("fsdp", "model")),
         (r"transformer/layer_\d+/ff/bias$", P("model")),
         (r"transformer/layer_\d+/norm_\d+/(scale|bias)$", P()),
-        # --- Switch MoE FFN (models/moe.py) ---------------------------------
-        # fp32 router replicated so every shard routes identically.
-        (r"moe/gate/kernel$", P()),
-        # Stacked experts (E, d, ff)/(E, ff, d): experts over `model` (the
-        # dispatch/combine einsums lower to all-to-alls over ICI), the
-        # non-contracting weight dim over `fsdp`.
-        (r"moe/wi$", P("model", "fsdp", None)),
-        (r"moe/wo$", P("model", None, "fsdp")),
         # --- embeddings + action head (the vocab head IS the action head:
         # action tokens decode from its logits) ------------------------------
         (r"transformer/token_emb/kernel$", P("fsdp", "model")),
@@ -143,10 +135,6 @@ def rt1_sharding_plan() -> List[Rule]:
         (r"(conv|conv1|conv2|conv1x1|fc1|fc2)/bias$", P()),
         (r"bn/(scale|bias|mean|var)$", P()),
         (r"token_learner/norm/(scale|bias)$", P()),
-        # Vision-pretrain classifier head (train/pretrain_vision.py grafts
-        # drop it before policy training, but the encoder trains with it).
-        (r"classifier/kernel$", P(None, "fsdp")),
-        (r"classifier/bias$", P()),
         # --- tiny tokenizer (configs/tiny.py) -------------------------------
         (r"image_tokenizer_def/ctx_proj/kernel$", P("fsdp", None)),
         (r"image_tokenizer_def/ctx_proj/bias$", P()),
@@ -185,22 +173,17 @@ QUANT_F32 = "f32"     # never quantized (master/compute dtype)
 def rt1_quant_rules() -> List[Tuple[str, str]]:
     """THE quant plan: ordered (path-regex, group) over every RT-1 param
     group. int8 covers the matmul/conv weights whose bytes dominate the
-    serving tree — transformer qkv/out/FFN, MoE experts, FiLM projections,
+    serving tree — transformer qkv/out/FFN, FiLM projections,
     every EfficientNet/SE/TokenLearner/encoder conv, and the tiny
     tokenizer's projections. Embeddings, the action head (`output_tokens`
-    IS the action decode), norms, biases, BN statistics, and the fp32 MoE
-    router are listed f32 EXPLICITLY — `quant_coverage` distinguishes
+    IS the action decode), norms, biases and BN statistics
+    are listed f32 EXPLICITLY — `quant_coverage` distinguishes
     "decided full-precision" from "forgotten", same philosophy as the
     sharding plan's coverage check.
     """
     return [
         # --- explicit full-precision: embeddings + the action head -------
         (r"transformer/(token_emb|position_emb|output_tokens)/", QUANT_F32),
-        # fp32 router: routing decisions must not flip under quant noise.
-        (r"moe/gate/kernel$", QUANT_F32),
-        # Vision-pretrain classifier head (dropped before policy serving,
-        # but the rule set must decide every path it can meet).
-        (r"classifier/", QUANT_F32),
         # Norm/BN leaves are rank<2 (never quantizable) — listed anyway so
         # the decision is readable here, not implied by rank.
         (r"(norm_\d+|norm|bn)/(scale|bias|mean|var)$", QUANT_F32),
@@ -208,9 +191,6 @@ def rt1_quant_rules() -> List[Tuple[str, str]]:
         (r"transformer/layer_\d+/attn/(query|key|value|out)/kernel$",
          QUANT_INT8),
         (r"transformer/layer_\d+/ff/kernel$", QUANT_INT8),
-        # Stacked Switch-MoE experts (E, d, ff)/(E, ff, d): per-channel on
-        # the output dim, scales shared across experts (conservative).
-        (r"moe/(wi|wo)$", QUANT_INT8),
         # --- int8: FiLM-EfficientNet tokenizer ---------------------------
         (r"projection_(add|mult)/kernel$", QUANT_INT8),
         # Conv kernels (stem/top/expand/project/depthwise, SE fc1/fc2,
@@ -437,22 +417,30 @@ class ShardingPlan:
         n_devices: Optional[int] = None,
         collapse_data: bool = False,
     ) -> "ShardingPlan":
-        """Resolve the plan ONCE from `config.parallel` (dp/fsdp/tp/pp/sp
+        """Resolve the plan ONCE from `config.parallel` (dp/fsdp/tp/pp
         sizes, `auto` mesh-shape selection by device count, `strict`
         coverage), falling back to the legacy `config.mesh` block
-        (data/model/seq/stage) for configs that predate `config.parallel`,
+        (data/model/stage) for configs that predate `config.parallel`,
         and to pure DP when neither block exists (pinned proof configs).
 
         ``collapse_data=True`` is the serving resolution (eval/restore.py
         `serving_plan`): there is no batch axis to shard (sessions are
         slots, not data shards), so `dp` collapses to 1 and the mesh covers
-        exactly the fsdp × tp × pp × sp devices model parallelism needs —
+        exactly the fsdp × tp × pp devices model parallelism needs —
         raising when the host has fewer. One resolver for train AND serve,
         so the ladder/axes can never drift between them.
         """
-        dp, fsdp, tp, pp, sp = -1, 1, 1, 1, 1
+        dp, fsdp, tp, pp = -1, 1, 1, 1
         strict = False
         par = _get(config, "parallel")
+        for block, key in (("parallel", "sp"), ("mesh", "seq")):
+            if int(_get(_get(config, block), key, 1)) > 1:
+                raise ValueError(
+                    f"config.{block}.{key} > 1 asks for sequence parallelism "
+                    "(ring attention), which was removed and has no "
+                    "replacement: the mesh is ('data', 'stage', 'fsdp', "
+                    "'model')"
+                )
         if par is not None:
             if _get(par, "auto", False):
                 # Resolution is against the GLOBAL device set (`jax.
@@ -469,40 +457,35 @@ class ShardingPlan:
                 else:
                     n = n_devices if n_devices is not None else len(devices)
                 pp = int(_get(par, "pp", 1))
-                sp = int(_get(par, "sp", 1))
-                # pp/sp are honored as configured: the auto table splits
-                # only the devices left after the stage/seq axes take
-                # theirs, so auto composes with pp>1 or sp>1 instead of
-                # over-subscribing the mesh.
-                dp, fsdp, tp = auto_mesh_shape(
-                    max(n // max(pp * sp, 1), 1), local
-                )
+                # pp is honored as configured: the auto table splits only
+                # the devices left after the stage axis takes its own, so
+                # auto composes with pp>1 instead of over-subscribing the
+                # mesh.
+                dp, fsdp, tp = auto_mesh_shape(max(n // max(pp, 1), 1), local)
             else:
                 dp = int(_get(par, "dp", -1))
                 fsdp = int(_get(par, "fsdp", 1))
                 tp = int(_get(par, "tp", 1))
                 pp = int(_get(par, "pp", 1))
-                sp = int(_get(par, "sp", 1))
             strict = bool(_get(par, "strict", False))
         else:
             legacy = _get(config, "mesh")
             if legacy is not None:
                 dp = int(_get(legacy, "data", -1))
                 tp = int(_get(legacy, "model", 1))
-                sp = int(_get(legacy, "seq", 1))
                 pp = int(_get(legacy, "stage", 1))
         if collapse_data:
             dp = 1
-            n = fsdp * tp * pp * sp
+            n = fsdp * tp * pp
             pool = list(devices) if devices is not None else jax.devices()
             if len(pool) < n:
                 raise ValueError(
-                    f"config.parallel asks for fsdp*tp*pp*sp={n} devices "
+                    f"config.parallel asks for fsdp*tp*pp={n} devices "
                     f"but this serving host has {len(pool)}"
                 )
             devices = pool[:n]
         mesh = make_mesh(
-            MeshConfig(data=dp, fsdp=fsdp, model=tp, seq=sp, stage=pp),
+            MeshConfig(data=dp, fsdp=fsdp, model=tp, stage=pp),
             devices=devices,
         )
         return cls(mesh=mesh, strict=strict)

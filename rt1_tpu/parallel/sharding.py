@@ -8,10 +8,10 @@ GSPMD propagates everything else.
 
 The rules themselves live in ONE place — `rt1_tpu/parallel/plan.py`'s
 declarative plan, which covers every RT-1 param group over the
-``('data', 'stage', 'fsdp', 'seq', 'model')`` mesh and carries the coverage
+``('data', 'stage', 'fsdp', 'model')`` mesh and carries the coverage
 check that keeps a renamed module from silently replicating. The historical
-entry points below (`rt1_parameter_rules`, `moe_parameter_rules`) are thin
-views into that plan; this module keeps the pure mechanics: path
+entry point below (`rt1_parameter_rules`) is a thin
+view into that plan; this module keeps the pure mechanics: path
 stringification, first-match-wins resolution, pytree mapping.
 
 With every plan axis at size 1 the specs all degenerate to pure data
@@ -65,15 +65,6 @@ def rt1_parameter_rules() -> List[Rule]:
     from rt1_tpu.parallel import plan as planlib
 
     return planlib.rt1_sharding_plan()
-
-
-def moe_parameter_rules() -> List[Rule]:
-    """Expert-parallel subset of the plan (stacked expert weights sharded
-    over ``model`` on the expert axis; the fp32 router stays replicated so
-    every shard routes identically). Kept for callers that shard a bare
-    MoE tree; `rt1_parameter_rules` already includes these.
-    """
-    return [r for r in rt1_parameter_rules() if "moe/" in r[0]]
 
 
 def _path_str(path: Tuple[Any, ...]) -> str:

@@ -39,21 +39,14 @@ def get_config():
     # jax.checkpoint the transformer + MBConv blocks: ~1/3 extra FLOPs for
     # O(1) activation memory — turn on when HBM, not compute, caps batch.
     config.model.remat = False
-    # Attention implementation: "dense" (reference parity), "ring" (sequence-
-    # parallel over the mesh's 'seq' axis), "pallas" (fused inference kernel).
+    # Attention implementation: "dense" (reference parity) or "pallas"
+    # (fused inference kernel).
     # The pallas kernel is forward-only (no autodiff rule): under "pallas"
     # the train step still runs the dense math and only inference/serving
     # runs the kernel, which needs a TPU (it raises elsewhere).
     config.model.attention_impl = "dense"
     # GPipe microbatches per step when mesh.stage > 1 (parallel/pipeline.py).
     config.model.pipeline_microbatches = 4
-    # Decoder FFN: "dense" (reference parity) or "moe" (Switch expert FFN,
-    # expert-parallel over the mesh's 'model' axis — models/moe.py).
-    config.model.ffn_impl = "dense"
-    config.model.num_experts = 4
-    config.model.moe_aux_weight = 0.01
-    config.model.moe_capacity_factor = 2.0
-    config.model.moe_ff_dim = ml_collections.config_dict.placeholder(int)
     # Path to a state-regression-pretrained encoder (train/pretrain_vision
     # .py::save_encoder) grafted into the tokenizer at initialization — the
     # hermetic stand-in for the reference's ImageNet-pretrained B3 tower
@@ -161,22 +154,21 @@ def get_config():
     # dp × fsdp × tp × pp mesh shape plus the declarative param layout, all
     # config-only switches — train, eval, and serve resolve this block
     # identically. -1 dp = all remaining local devices. (Replaces the old
-    # `config.mesh` block: data→dp, model→tp, seq→sp, stage→pp; legacy
+    # `config.mesh` block: data→dp, model→tp, stage→pp; legacy
     # configs with a `mesh` block still resolve via the same fallback.)
     config.parallel = ml_collections.ConfigDict()
     config.parallel.dp = -1
     # ZeRO-3 weight sharding: batch shards over dp×fsdp, weight matrices /
     # optimizer masters shard one dim over fsdp.
     config.parallel.fsdp = 1
-    # Tensor parallelism (attention heads / FFN columns / MoE experts).
+    # Tensor parallelism (attention heads / FFN columns; the lfm2_moe
+    # family's experts).
     config.parallel.tp = 1
     # Pipeline stages (GPipe over the decoder's layer stack); num_layers
     # must be divisible by this.
     config.parallel.pp = 1
-    # Sequence/context parallelism (ring attention).
-    config.parallel.sp = 1
     # Pick (dp, fsdp, tp) automatically from the device count
-    # (plan.AUTO_MESH_SHAPES); pp/sp still honored as configured.
+    # (plan.AUTO_MESH_SHAPES); pp still honored as configured.
     config.parallel.auto = False
     # Plan-coverage strictness: True turns the "weight matrix matched no
     # rule" warning into a hard error at step-build time.

@@ -44,12 +44,30 @@ from rt1_tpu.trainer.metrics import (
 def build_model(model_config, mesh=None):
     """Construct the RT-1 policy from `config.model`.
 
-    `mesh` enables mesh-coupled features: a >1 "stage" axis pipelines the
-    decoder (GPipe, parallel/pipeline.py), a >1 "seq" axis is required for
-    attention_impl="ring". Eval/restore callers may omit it — parameter
-    layout does not depend on the mesh.
+    `mesh` enables the one mesh-coupled feature: a >1 "stage" axis
+    pipelines the decoder (GPipe, parallel/pipeline.py). Eval/restore
+    callers may omit it — parameter layout does not depend on the mesh.
+
+    Configs written before the Switch MoE FFN and ring attention were
+    removed may still carry their keys: the values that select the paths
+    that stayed are accepted (and stale `num_experts` / `moe_*` keys
+    ignored), the removed ones are refused here.
     """
     from rt1_tpu.models.rt1 import RT1Policy
+
+    if model_config.get("ffn_impl", "dense") != "dense":
+        raise ValueError(
+            f"model.ffn_impl={model_config.ffn_impl!r}: the RT-1 decoder's "
+            "Switch MoE FFN was removed; routed experts live in the decoder "
+            'LM family (model.family="lfm2_moe", rt1_tpu/models/lm)'
+        )
+    attention_impl = model_config.get("attention_impl", "dense")
+    if attention_impl not in ("dense", "pallas"):
+        raise ValueError(
+            f"model.attention_impl={attention_impl!r}: expected 'dense' or "
+            "'pallas' (ring attention was removed and has no replacement: "
+            "no RT-1 input is longer than 66 tokens)"
+        )
 
     tokenizer_def = None
     if model_config.image_tokenizer == "tiny":
@@ -97,16 +115,9 @@ def build_model(model_config, mesh=None):
         aux_mse_weight=model_config.get("aux_mse_weight", 0.0),
         action_decode=model_config.get("action_decode", "argmax"),
         remat=model_config.get("remat", False),
-        attention_impl=model_config.get("attention_impl", "dense"),
+        attention_impl=attention_impl,
         mesh=mesh,
         pipeline_microbatches=model_config.get("pipeline_microbatches", 4),
-        # Opt-in Switch MoE decoder FFN (models/moe.py); "dense" is
-        # reference parity.
-        ffn_impl=model_config.get("ffn_impl", "dense"),
-        num_experts=model_config.get("num_experts", 4),
-        moe_aux_weight=model_config.get("moe_aux_weight", 0.01),
-        moe_capacity_factor=model_config.get("moe_capacity_factor", 2.0),
-        moe_ff_dim=model_config.get("moe_ff_dim", None),
         dtype=jnp.bfloat16
         if model_config.dtype == "bfloat16"
         else jnp.float32,

@@ -106,48 +106,22 @@ def _loss_fn(model, params, batch_stats, batch: Batch, rng: jax.Array, train: bo
         "dropout": jax.random.fold_in(rng, 1),
         "augment": jax.random.fold_in(rng, 2),
     }
-    # MoE decoders sow a Switch load-balancing aux loss into intermediates
-    # (models/moe.py); without it top-1 routing collapses onto one expert.
-    # Train-only: eval loss stays the pure task loss so checkpoint selection
-    # and dense-baseline comparisons are unaffected by the regularizer.
-    use_moe = train and getattr(model, "ffn_impl", "dense") == "moe"
-    mutable = []
     if train and batch_stats:
-        mutable.append("batch_stats")
-    if use_moe:
-        mutable.append("intermediates")
-
-    if mutable:
         out, mutated = model.apply(
             variables,
             obs,
             actions,
             train=train,
-            rngs=rngs if train else None,
-            mutable=mutable,
+            rngs=rngs,
+            mutable=["batch_stats"],
         )
         new_bs = mutated.get("batch_stats", batch_stats)
     else:
         out = model.apply(
             variables, obs, actions, train=train, rngs=rngs if train else None
         )
-        mutated = {}
         new_bs = batch_stats
-
-    loss = out["loss"]
-    if use_moe and "intermediates" in mutated:
-        aux_leaves = [
-            jnp.asarray(v, jnp.float32)
-            for path, v in jax.tree_util.tree_flatten_with_path(
-                mutated["intermediates"]
-            )[0]
-            if "moe_aux_loss" in jax.tree_util.keystr(path)
-        ]
-        if aux_leaves:
-            aux = sum(jnp.mean(a) for a in aux_leaves) / len(aux_leaves)
-            loss = loss + getattr(model, "moe_aux_weight", 0.01) * aux
-            out = dict(out, loss=loss, moe_aux_loss=aux)
-    return loss, (out, new_bs)
+    return out["loss"], (out, new_bs)
 
 
 def make_train_step_fns(
@@ -349,16 +323,14 @@ def make_train_step_fns(
             extra = float(accum_steps) if ref_scale else 1.0
 
             def micro(carry, xs):
-                grads_acc, loss_acc, aux_acc, mse_acc, bs = carry
+                grads_acc, loss_acc, mse_acc, bs = carry
                 mb, r = xs
                 (l, (mb_out, bs)), g = grad_fn(state.params, bs, mb, r)
-                # Metric only: the aux terms' gradients already flow via l.
-                aux_acc = aux_acc + mb_out.get("moe_aux_loss", jnp.zeros(()))
+                # Metric only: the aux term's gradient already flows via l.
                 mse_acc = mse_acc + mb_out.get("aux_mse", jnp.zeros(()))
                 return (
                     jax.tree.map(jnp.add, grads_acc, g),
                     loss_acc + l,
-                    aux_acc,
                     mse_acc,
                     bs,
                 ), None
@@ -369,17 +341,14 @@ def make_train_step_fns(
             micro_batches = jax.tree.map(split, batch)
             rngs = jax.random.split(rng, accum_steps)
             zero_grads = jax.tree.map(jnp.zeros_like, state.params)
-            (grads, loss, aux, mse, new_bs), _ = jax.lax.scan(
+            (grads, loss, mse, new_bs), _ = jax.lax.scan(
                 micro,
-                (zero_grads, jnp.zeros(()), jnp.zeros(()), jnp.zeros(()),
-                 state.batch_stats),
+                (zero_grads, jnp.zeros(()), jnp.zeros(()), state.batch_stats),
                 (micro_batches, rngs),
             )
             grads = jax.tree.map(lambda g: g / (accum_steps * extra), grads)
             loss = loss / (accum_steps * extra)
             out = {"loss": loss}
-            if getattr(model, "ffn_impl", "dense") == "moe":
-                out["moe_aux_loss"] = aux / accum_steps  # mean over micros
             if getattr(model, "aux_mse_weight", 0.0) > 0:
                 out["aux_mse"] = mse / accum_steps  # mean over micros
 
@@ -398,8 +367,6 @@ def make_train_step_fns(
         }
         if "action_loss" in out:
             metrics["action_loss_mean"] = jnp.mean(out["action_loss"])
-        if "moe_aux_loss" in out:  # routing-collapse monitor
-            metrics["moe_aux_loss"] = out["moe_aux_loss"]
         if "aux_mse" in out:  # soft-argmax regression monitor
             metrics["aux_mse"] = out["aux_mse"]
         # A family's own per-step counters (the routed layers' rows).

@@ -175,9 +175,9 @@ def main():
     # mesh axis over the host-major device list) and fsdp intra-host.
     plan = ShardingPlan.from_config(config)
     assert dict(plan.mesh.shape) == {
-        "data": 2, "stage": 1, "fsdp": 2, "seq": 1, "model": 1
+        "data": 2, "stage": 1, "fsdp": 2, "model": 1
     }, dict(plan.mesh.shape)
-    mesh_devs = plan.mesh.devices  # (data, stage, fsdp, seq, model)
+    mesh_devs = plan.mesh.devices  # (data, stage, fsdp, model)
     for d in range(2):
         hosts = {
             dev.process_index for dev in mesh_devs[d].reshape(-1)

@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 
 from rt1_tpu.parallel.flash_attention import fused_attention
-from rt1_tpu.parallel.ring_attention import dense_attention_reference
+from tests.attention_reference import dense_attention_reference
 
 B, S, H, D = 2, 66, 4, 16  # RT-1's actual window: 6 x (8 + 3) = 66 tokens
 

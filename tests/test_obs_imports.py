@@ -190,7 +190,7 @@ from rt1_tpu.parallel import (
 )
 
 assert auto_mesh_shape(8) == (2, 2, 2)
-assert any("moe/wi" in pat for pat, _ in rt1_sharding_plan())
+assert any("ffn/experts" in pat for pat, _ in rt1_sharding_plan())
 plan = ShardingPlan(mesh=make_mesh(MeshConfig()))
 assert plan.coverage({"transformer": {"layer_0": {"ff": {
     "kernel": _np.zeros((4, 4))}}}}) == []

@@ -3,9 +3,10 @@ precision (trainer/train.py mixed_precision).
 
 Pins the PR's contracts:
 
-* plan coverage — every weight matrix of the flagship, tiny, and MoE
-  configs matches an explicit rule (no silent-replication fallthrough);
-  strict mode raises, default warns loudly.
+* plan coverage — every weight matrix of the flagship, tiny and
+  efficientnet_small configs matches an explicit rule (no
+  silent-replication fallthrough), and every rule matches a leaf of a
+  shipped configuration; strict mode raises, default warns loudly.
 * auto mesh-shape selection by device count (SNIPPETS.md [1] ladder).
 * config-only equivalence on a forced multi-device host mesh: dense vs
   fsdp vs tp vs pp train-step losses/updates agree within tolerance
@@ -92,9 +93,7 @@ def _tiny_model_config(**overrides):
     "name,mc_fn",
     [
         ("tiny", _tiny_model_config),
-        ("tiny_moe", lambda: _tiny_model_config(ffn_impl="moe")),
         ("flagship", _flagship_model_config),
-        ("flagship_moe", lambda: _flagship_model_config(ffn_impl="moe")),
         (
             "effnet_small",
             lambda: _tiny_model_config(image_tokenizer="efficientnet_small"),
@@ -102,13 +101,59 @@ def _tiny_model_config(**overrides):
     ],
 )
 def test_plan_covers_every_weight_matrix(name, mc_fn):
-    """Satellite 1: flagship, tiny, and MoE configs match a non-default
-    rule for every weight matrix — nothing falls through to P()."""
+    """Satellite 1: flagship, tiny and efficientnet_small configs match a
+    non-default rule for every weight matrix — nothing falls through to
+    P()."""
     params = _param_shapes(mc_fn())
     plan = ShardingPlan(mesh=make_mesh(MeshConfig()))
     assert plan.coverage(params) == [], (
         f"{name}: weight matrices with no plan rule"
     )
+
+
+@pytest.fixture(scope="module")
+def shipped_param_paths():
+    """Every parameter path of the configurations the repo ships: tiny,
+    flagship, efficientnet_small and the lfm2_moe decoder LM."""
+    from rt1_tpu.parallel import sharding as shardlib
+    from rt1_tpu.train.configs import lfm2_moe
+    from rt1_tpu.train.train import build_family
+
+    trees = [
+        _param_shapes(mc)
+        for mc in (
+            _tiny_model_config(),
+            _flagship_model_config(),
+            _tiny_model_config(image_tokenizer="efficientnet_small"),
+        )
+    ]
+    lm_model, lm_init, _ = build_family(lfm2_moe.get_config().model)
+    ids = jnp.zeros((1, 16), jnp.int32)
+    trees.append(jax.eval_shape(
+        lambda r: lm_init(lm_model, r, {"tokens": ids}, {"targets": ids}),
+        jax.random.PRNGKey(0),
+    )["params"])
+    return {
+        shardlib._path_str(path)
+        for tree in trees
+        for path, _ in jax.tree_util.tree_flatten_with_path(tree)[0]
+    }
+
+
+@pytest.mark.parametrize("which", ["sharding", "quant"])
+def test_every_rule_matches_a_leaf(which, shipped_param_paths):
+    """The converse of coverage: a rule no shipped configuration's leaf
+    matches describes a module that is gone, and is deleted with it."""
+    import re
+
+    from rt1_tpu.parallel.plan import rt1_quant_rules, rt1_sharding_plan
+
+    rules = rt1_sharding_plan() if which == "sharding" else rt1_quant_rules()
+    dead = [
+        pattern for pattern, _ in rules
+        if not any(re.search(pattern, p) for p in shipped_param_paths)
+    ]
+    assert dead == []
 
 
 def test_plan_coverage_warns_and_strict_raises(caplog):
@@ -204,29 +249,23 @@ def test_auto_mesh_shape_host_contiguous_rebalance():
 
 
 def test_plan_from_config_parallel_block():
-    cfg = {"parallel": {"dp": 2, "fsdp": 2, "tp": 2, "pp": 1, "sp": 1}}
+    cfg = {"parallel": {"dp": 2, "fsdp": 2, "tp": 2, "pp": 1}}
     plan = ShardingPlan.from_config(cfg)
-    assert plan.mesh.shape == {
-        "data": 2, "stage": 1, "fsdp": 2, "seq": 1, "model": 2
-    }
+    assert plan.mesh.shape == {"data": 2, "stage": 1, "fsdp": 2, "model": 2}
     assert plan.data_parallel_size == 4  # batch shards over dp x fsdp
     assert not plan.strict
 
 
 def test_plan_from_config_auto():
     plan = ShardingPlan.from_config({"parallel": {"auto": True}})
-    assert plan.mesh.shape == {
-        "data": 2, "stage": 1, "fsdp": 2, "seq": 1, "model": 2
-    }
+    assert plan.mesh.shape == {"data": 2, "stage": 1, "fsdp": 2, "model": 2}
 
 
 def test_plan_from_config_auto_composes_with_pp():
-    """auto splits only the devices left after pp/sp take theirs — auto+pp
+    """auto splits only the devices left after pp takes its own — auto+pp
     on 8 devices used to resolve a 16-device mesh and raise at startup."""
     plan = ShardingPlan.from_config({"parallel": {"auto": True, "pp": 2}})
-    assert plan.mesh.shape == {
-        "data": 2, "stage": 2, "fsdp": 2, "seq": 1, "model": 1
-    }
+    assert plan.mesh.shape == {"data": 2, "stage": 2, "fsdp": 2, "model": 1}
 
 
 def test_serving_plan_honors_auto_and_backend_failure_raises(monkeypatch):
@@ -239,9 +278,7 @@ def test_serving_plan_honors_auto_and_backend_failure_raises(monkeypatch):
 
     plan = R.serving_plan({"parallel": {"auto": True}})
     # 8 forced host devices -> ladder (2, 2, 2); dp collapses to 1.
-    assert plan.mesh.shape == {
-        "data": 1, "stage": 1, "fsdp": 2, "seq": 1, "model": 2
-    }
+    assert plan.mesh.shape == {"data": 1, "stage": 1, "fsdp": 2, "model": 2}
 
     def _no_backend(*a, **k):
         raise RuntimeError("Backend 'cpu' failed to initialize")
@@ -314,15 +351,56 @@ def test_trainer_check_coverage_gate(caplog):
 
 def test_plan_from_config_legacy_mesh_fallback():
     """Configs that predate config.parallel (pinned proof configs) resolve
-    through their old mesh block: data->dp, model->tp, seq->sp, stage->pp."""
+    through their old mesh block: data->dp, model->tp, stage->pp (a stale
+    seq of 1 is accepted)."""
     cfg = {"mesh": {"data": -1, "model": 2, "seq": 1, "stage": 1}}
     plan = ShardingPlan.from_config(cfg)
-    assert plan.mesh.shape == {
-        "data": 4, "stage": 1, "fsdp": 1, "seq": 1, "model": 2
-    }
+    assert plan.mesh.shape == {"data": 4, "stage": 1, "fsdp": 1, "model": 2}
     # No block at all -> pure DP over every device.
     plan = ShardingPlan.from_config(None)
     assert plan.mesh.shape["data"] == len(jax.devices())
+
+
+_REMOVED_SWITCHES = {
+    "ffn_impl=moe": ("model", {"ffn_impl": "moe"}, "lfm2_moe"),
+    "attention_impl=ring": ("model", {"attention_impl": "ring"}, "ring attention was removed"),
+    "parallel.sp=2": ("parallel", {"sp": 2}, "ring attention"),
+    "mesh.seq=2": ("mesh", {"seq": 2}, "ring attention"),
+}
+
+
+@pytest.mark.parametrize("case", list(_REMOVED_SWITCHES))
+def test_removed_switches_are_refused(case):
+    """The Switch MoE FFN and ring attention are gone: a config that still
+    selects one is refused at the config seam, by name, with what replaced
+    it — never silently built dense."""
+    from rt1_tpu.train.train import build_model
+
+    block, values, names = _REMOVED_SWITCHES[case]
+    with pytest.raises(ValueError, match=names):
+        if block == "model":
+            build_model(_tiny_model_config(**values))
+        else:
+            ShardingPlan.from_config({block: values})
+
+
+@pytest.mark.parametrize("name", ["language_table", "tiny"])
+def test_stale_dense_keys_build_the_same_model(name):
+    """A config written before the removal (benchmarks/configs/rt1-b3-lt.json
+    is one) still carries ffn_impl/attention_impl="dense" and the four
+    num_experts / moe_* keys: it builds the parameter tree of a config
+    without them."""
+    mc_fn = _flagship_model_config if name == "language_table" else _tiny_model_config
+    stale = mc_fn().unlock()
+    stale.update(
+        ffn_impl="dense", attention_impl="dense", num_experts=4,
+        moe_aux_weight=0.01, moe_capacity_factor=2.0, moe_ff_dim=None,
+    )
+    clean = mc_fn().unlock()
+    for key in ("ffn_impl", "attention_impl"):
+        if key in clean:
+            del clean[key]
+    assert _param_shapes(stale) == _param_shapes(clean)
 
 
 def test_mixed_precision_from_config():
@@ -342,8 +420,7 @@ def test_write_hparams_emits_parallel_block():
     flat = flatten_hparams(dict(tiny.get_config().to_dict()))
     for key in (
         "parallel.dp", "parallel.fsdp", "parallel.tp", "parallel.pp",
-        "parallel.sp", "parallel.auto", "parallel.strict",
-        "parallel.mixed_precision",
+        "parallel.auto", "parallel.strict", "parallel.mixed_precision",
     ):
         assert key in flat, key
 
@@ -415,7 +492,6 @@ _PR6_RULES = [
     (r"transformer/layer_\d+/ff/bias$", P("model")),
     (r"transformer/output_tokens/kernel$", P(None, "model")),
     (r"transformer/output_tokens/bias$", P("model")),
-    (r"moe/(wi|wo)$", P("model", None, None)),
 ]
 
 

@@ -69,14 +69,12 @@ def test_per_channel_conv_kernels_and_edge_cases():
 
 
 def test_quant_rules_groups_for_key_paths():
-    """The declared split: matmul/conv weights int8; embeddings, the
-    action head, and the fp32 MoE router explicitly full-precision."""
+    """The declared split: matmul/conv weights int8; embeddings and the
+    action head explicitly full-precision."""
     int8_paths = [
         "params/transformer/layer_0/attn/query/kernel",
         "params/transformer/layer_0/attn/out/kernel",
         "params/transformer/layer_3/ff/kernel",
-        "params/transformer/layer_1/moe/wi",
-        "params/transformer/layer_1/moe/wo",
         "params/image_tokenizer_def/blocks_3/film/projection_add/kernel",
         "params/image_tokenizer_def/net/stem/conv/kernel",
         "params/image_tokenizer_def/token_learner/conv1/kernel",
@@ -87,7 +85,6 @@ def test_quant_rules_groups_for_key_paths():
         "params/transformer/token_emb/embedding",
         "params/transformer/position_emb/embedding",
         "params/transformer/output_tokens/kernel",  # IS the action decode
-        "params/transformer/layer_1/moe/gate/kernel",  # fp32 router
     ]
     for path in int8_paths:
         assert quant_group_for_path(path) == QUANT_INT8, path

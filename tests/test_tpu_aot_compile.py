@@ -17,11 +17,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+from jax.sharding import SingleDeviceSharding
 
 from rt1_tpu.models.action_tokenizer import tokens_per_action
 from rt1_tpu.parallel.flash_attention import fused_attention
-from rt1_tpu.parallel.ring_attention import ring_attention
 from rt1_tpu.serve.engine import pow2_buckets
 from rt1_tpu.specs import language_table_action_space
 from rt1_tpu.train.configs import language_table
@@ -84,29 +83,6 @@ def test_fused_attention_compiles_for_v5e(v5e, batch, dtype, masked):
 
     compiled = jax.jit(attend).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
-
-
-def test_ring_attention_compiles_on_four_chip_mesh(v5e):
-    """One small program across the four described chips: ring attention
-    over a dp 2 x seq 2 mesh must hold the K/V rotation collective."""
-    mesh = Mesh(
-        np.array(v5e.devices).reshape(2, 2),
-        axis_names=("data", "seq"),
-    )
-    qkv = jax.ShapeDtypeStruct(
-        (4, 64, HEADS, HEAD_DIM),
-        jnp.bfloat16,
-        sharding=NamedSharding(mesh, P("data", "seq", None, None)),
-    )
-    mask = jax.ShapeDtypeStruct(
-        (64, 64), jnp.int32, sharding=NamedSharding(mesh, P())
-    )
-
-    def attend(q, k, v, mask):
-        return ring_attention(q, k, v, mesh, mask=mask)
-
-    compiled = jax.jit(attend).lower(qkv, qkv, qkv, mask).compile()
-    assert "collective-permute" in compiled.as_text()
 
 
 def test_health_pack_copies_no_state_in_the_compiled_step(v5e):

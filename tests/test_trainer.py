@@ -39,15 +39,15 @@ def test_multistep_lr_schedule():
 def test_mesh_shapes():
     mesh = make_mesh(MeshConfig())
     assert mesh.shape == {
-        "data": 8, "stage": 1, "fsdp": 1, "seq": 1, "model": 1
+        "data": 8, "stage": 1, "fsdp": 1, "model": 1
     }
     mesh = make_mesh(MeshConfig(model=4))
     assert mesh.shape == {
-        "data": 2, "stage": 1, "fsdp": 1, "seq": 1, "model": 4
+        "data": 2, "stage": 1, "fsdp": 1, "model": 4
     }
     mesh = make_mesh(MeshConfig(fsdp=2, model=2))
     assert mesh.shape == {
-        "data": 2, "stage": 1, "fsdp": 2, "seq": 1, "model": 2
+        "data": 2, "stage": 1, "fsdp": 2, "model": 2
     }
     with pytest.raises(ValueError):
         make_mesh(MeshConfig(data=3, model=3))

@@ -3,9 +3,11 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from rt1_tpu.models.rt1 import action_token_positions, rt1_attention_mask
-from rt1_tpu.models.transformer import CausalTransformer
+from rt1_tpu.models.transformer import CausalTransformer, TFMultiHeadAttention
+from tests.attention_reference import dense_attention_reference
 
 
 def tiny_transformer(**kw):
@@ -48,8 +50,6 @@ def test_batched_mask_and_seq_len_guard(rng):
     out3d = model.apply(params, x, mask3d)
     np.testing.assert_allclose(np.asarray(out2d), np.asarray(out3d), atol=1e-6)
     # Sequences longer than max_seq_len are rejected, not silently clamped.
-    import pytest
-
     long_x = jax.random.normal(rng, (1, 65, 12))
     with pytest.raises(ValueError, match="max_seq_len"):
         model.apply(params, long_x, jnp.tril(jnp.ones((65, 65), jnp.uint8)))
@@ -66,6 +66,41 @@ def test_causal_mask_blocks_future(rng):
     cut = model.apply(params, x_cut, mask)
     np.testing.assert_allclose(np.asarray(full[:, :5]), np.asarray(cut[:, :5]), atol=1e-5)
     assert not np.allclose(np.asarray(full[:, 5:]), np.asarray(cut[:, 5:]))
+
+
+_T = 32
+_ATTENTION_MASKS = {
+    "no_mask": lambda: None,
+    "causal": lambda: jnp.tril(jnp.ones((_T, _T), jnp.int32)),
+    # The action-blind causal mask, from the real generator: 2 frames of
+    # 13 image + 3 action tokens.
+    "rt1_mask": lambda: jnp.asarray(rt1_attention_mask(2, 13, 3)),
+    # A fully-masked query row degenerates to a uniform average (the
+    # additive mask is a finite NEG_INF): finite everywhere.
+    "fully_masked_rows_finite": lambda: jnp.zeros((_T, _T), jnp.int32).at[1:, :].set(1),
+}
+
+
+@pytest.mark.parametrize("case", list(_ATTENTION_MASKS))
+def test_dense_attention_matches_the_reference(rng, case):
+    """The attention module against the single-device reference applied to
+    the module's own projections, under every mask shape RT-1 meets."""
+    b, t, h, d, d_model = 2, _T, 4, 16, 24
+    mask = _ATTENTION_MASKS[case]()
+    attn = TFMultiHeadAttention(num_heads=h, key_dim=d, d_model=d_model, dropout_rate=0.0)
+    x = jax.random.normal(rng, (b, t, d_model))
+    variables = attn.init(jax.random.fold_in(rng, 1), x, mask)
+    out, _ = attn.apply(variables, x, mask)
+
+    p = variables["params"]
+    q, k, v = (
+        (x @ p[name]["kernel"] + p[name]["bias"]).reshape(b, t, h, d)
+        for name in ("query", "key", "value")
+    )
+    ref = dense_attention_reference(q, k, v, mask=mask).reshape(b, t, h * d)
+    ref = ref @ p["out"]["kernel"] + p["out"]["bias"]
+    assert np.isfinite(np.asarray(out)).all()
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
 # ---------------------------------------------------------------- RT-1 mask unit
