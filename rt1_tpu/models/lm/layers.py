@@ -8,6 +8,7 @@ bias.  Leaves are named ``kernel``, ``scale``, ``bias`` or ``embedding``.
 from __future__ import annotations
 
 import functools
+import logging
 from typing import Any, Tuple
 
 import flax.linen as nn
@@ -16,7 +17,9 @@ import jax.numpy as jnp
 from jax import lax
 
 from rt1_tpu.models.lm.spec import LMSpec
+from rt1_tpu.obs.trace import span
 
+_LOG = logging.getLogger(__name__)
 _KERNEL_INIT = nn.initializers.lecun_normal()
 
 
@@ -70,7 +73,14 @@ def rotary(x, theta: float):
 
 # ---------------------------------------------------------------- attention
 #
-# q: (b, s, kv_heads, group, d); k, v: (b, s, kv_heads, d); causal, exact.
+# q: (b, s, kv_heads, group, d); k, v: (b, s, kv_heads, d); causal, exact:
+# bfloat16 operands, float32 scores, softmax and accumulation, the
+# probabilities cast to v's type for PV, on every path.  Past 512 positions a
+# TPU runs the library's splash attention (jax.experimental.pallas.ops.tpu.
+# splash_attention): q goes in head-major as (b, heads, s, d), k and v as
+# (b, kv_heads, s, d) with their own head count (the kernel maps query head h to
+# KV head h // group), all three head-size-minor; the backward is one kernel
+# that makes dq beside dk and dv.
 
 def _scores_to_out(q, k, v, scale, q_start: int):
     scores = jnp.einsum("bqhgd,bkhd->bhgqk", q, k, preferred_element_type=jnp.float32) * scale
@@ -85,18 +95,35 @@ def dense_attention(q, k, v, scale):
     return _scores_to_out(q, k, v, scale, 0)
 
 
-FLASH_BLOCK = 1024      # the largest the kernel's scratch allows at head size 64
+# The splash kernels' blocks and backward, from the chip at 2 x 8,192 positions,
+# 32 heads over 8 KV heads of 64, both ways with the layout changes in
+# (scripts/lm_kernel_probe.py --only attention; PERF.md section 6, PR 30; the old
+# flash kernel with its KV heads repeated read 47.0 ms).  Queries and keys go in
+# blocks of the largest of these that divides the sequence: 38.2-45.0 ms at
+# blocks of 512 against 31.5-37.9 at 1,024, and a query block of 2,048 leaves
+# the forward no faster (9.0 ms) and the fused backward no room in VMEM.  The
+# backward is the fused kernel (dq as one partial sum a key block, added up
+# outside): 31.5 ms against 37.9 with a dq kernel of its own, at 0.6 GB more
+# scratch.  k sequence-minor reads the same (31.5), so all stay head-size-minor.
+SPLASH_BLOCKS = (1024, 512, 256, 128)
+# The forward's inner block of keys: 8.8 ms at 512 against 9.8 at 1,024 and 9.1
+# at 256; the fused backward reads the other way, 17.8 at 1,024 against 18.2,
+# so it keeps the whole block.  30.5 ms in all.
+SPLASH_FORWARD_COMPUTE = 512
 BLOCKWISE_BLOCK = 512
 
 
 def causal_attention(q, k, v, scale):
     """Exact causal attention without an (s, s) tensor a head, either way: the
-    library's Pallas kernel on a TPU, query blocks in plain ``lax`` elsewhere
-    (the Pallas kernel compiles for TPUs only); short sequences take the square."""
+    library's splash kernels on a TPU (grouped-query and block-sparse: k and v
+    unrepeated, the blocks above the diagonal never visited), query blocks in
+    plain ``lax`` elsewhere (the Pallas kernels compile for TPUs only); short
+    sequences take the square.  The choice is the backend's and the shape's;
+    the kernel's blocks are a rule of the sequence length (``splash_blocks``)."""
     if q.shape[1] <= BLOCKWISE_BLOCK:
         return dense_attention(q, k, v, scale)
     if jax.default_backend() == "tpu":
-        return flash_attention(q, k, v, scale, FLASH_BLOCK)
+        return splash_attention(q, k, v, scale)
     return blockwise_attention(q, k, v, scale, BLOCKWISE_BLOCK)
 
 
@@ -118,22 +145,52 @@ def blockwise_attention(q, k, v, scale, block: int):
     return jnp.concatenate(outs, axis=1)
 
 
-def flash_attention(q, k, v, scale, block: int):
-    """The library's Pallas TPU kernel (forward and backward kernels of its
-    own).  It has one head count, so each KV head is repeated for its group."""
-    from jax.experimental.pallas.ops.tpu import flash_attention as fa
+def splash_blocks(s: int) -> int:
+    """The kernel's block for a sequence of ``s`` positions, queries and keys
+    alike, forward and backward."""
+    for block in SPLASH_BLOCKS:
+        if s % block == 0:
+            return block
+    raise ValueError(
+        f"sequence {s} is not a multiple of the attention kernel's smallest block "
+        f"{SPLASH_BLOCKS[-1]}")
 
+
+@functools.lru_cache(maxsize=None)
+def _splash_kernel(s: int, heads: int, interpret: bool):
+    """One kernel object a shape: its mask tables are numpy work over every
+    (head, query block, key block), made once and not once a trace."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as sk
+    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask as sm
+
+    block = splash_blocks(s)
+    sizes = sk.BlockSizes(
+        block_q=block, block_kv=block, block_kv_compute=min(block, SPLASH_FORWARD_COMPUTE),
+        block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=block,
+        use_fused_bwd_kernel=True)
+    chosen = dict(attention_impl="splash", seq=s, heads=heads, block_q=block, block_kv=block,
+                  block_kv_compute=sizes.block_kv_compute, fused_bwd=sizes.use_fused_bwd_kernel,
+                  k_layout=sizes.k_layout.name)
+    _LOG.info("attention kernel: %s", chosen)        # once a shape: which kernel a run timed
+    with span("lm/attention_kernel", **chosen):
+        mask = sm.MultiHeadMask([sm.CausalMask((s, s))] * heads)
+        with jax.ensure_compile_time_eval():        # the tables are constants, not tracers
+            return sk.make_splash_mha(
+                mask, block_sizes=sizes, head_shards=1, q_seq_shards=1, interpret=interpret)
+
+
+def splash_attention(q, k, v, scale, interpret: bool = False):
+    """The library's splash attention (Pallas TPU kernels, forward and
+    backward): grouped-query, so k and v go in with their own head count, and
+    block-sparse, so the blocks above the diagonal are never visited and the
+    mask is applied on the diagonal blocks only.  The scale is folded into q
+    (in float32, so a scale that is no power of two rounds once)."""
     b, s, kvh, g, d = q.shape
-    qh = q.reshape(b, s, kvh * g, d).transpose(0, 2, 1, 3)
-    kh = jnp.repeat(k, g, axis=2).transpose(0, 2, 1, 3)
-    vh = jnp.repeat(v, g, axis=2).transpose(0, 2, 1, 3)
-    blk = min(block, s)
-    sizes = fa.BlockSizes(
-        block_q=blk, block_k_major=blk, block_k=blk, block_b=1,
-        block_q_major_dkv=blk, block_k_major_dkv=blk, block_k_dkv=blk, block_q_dkv=blk,
-        block_k_major_dq=blk, block_k_dq=blk, block_q_dq=blk)
+    kernel = _splash_kernel(s, kvh * g, interpret)
+    qh = (q.astype(jnp.float32) * scale).astype(q.dtype).reshape(b, s, kvh * g, d)
+    qh, kh, vh = (x.transpose(0, 2, 1, 3) for x in (qh, k, v))
     with jax.named_scope("kernel"):
-        out = fa.flash_attention(qh, kh, vh, causal=True, sm_scale=scale, block_sizes=sizes)
+        out = jax.vmap(kernel)(qh, kh, vh)
     return out.transpose(0, 2, 1, 3).reshape(b, s, kvh, g, d)
 
 

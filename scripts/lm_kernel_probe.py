@@ -1,7 +1,8 @@
 """Times the candidate kernels of the lfm2_moe family's step on the chip, at
 the benchmark cell's shapes: causal attention forward + backward (the
-library's Pallas flash kernel at several block sizes against the blockwise
-lax form) and the grouped expert product forward + backward
+library's splash kernel over block sizes, fused and unfused backward and k's
+layout, against the older flash kernel with its KV heads repeated and the
+blockwise lax form) and the grouped expert product forward + backward
 (``lax.ragged_dot`` against megablox ``gmm`` at several tilings) with an
 eighth of the row buffer in groups; and the routed layer's ways between
 tokens and expert rows (``--only routed``): the slot gather against a
@@ -50,33 +51,148 @@ def main() -> int:
     return 0
 
 
-def attention(args) -> None:
+def attention_candidates(d: int = 64):
+    """``(row, fn)`` pairs: each ``fn(q, k, v)`` takes the program's layout,
+    q ``(b, s, kv heads, group, d)``, k and v ``(b, s, kv heads, d)``, so the
+    transposes (and the old kernel's repeats) a step pays are in its time.  A
+    splash row gives each kernel's blocks as (block_q, block_kv,
+    block_kv_compute); ``dq`` null is the fused backward."""
+    from jax.experimental.pallas.ops.tpu import flash_attention as fa
+    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as sk
+    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask as sm
+
     from rt1_tpu.models.lm import layers
 
+    scale = d ** -0.5
+
+    def flash_old(q, k, v, blk=1024):
+        """What ``layers.py`` ran through PR 29: the library's older kernel,
+        one head count, every KV head repeated for its group."""
+        b, s, kvh, g, _ = q.shape
+        qh = q.reshape(b, s, kvh * g, d).transpose(0, 2, 1, 3)
+        kh = jnp.repeat(k, g, axis=2).transpose(0, 2, 1, 3)
+        vh = jnp.repeat(v, g, axis=2).transpose(0, 2, 1, 3)
+        sizes = fa.BlockSizes(
+            block_q=blk, block_k_major=blk, block_k=blk, block_b=1,
+            block_q_major_dkv=blk, block_k_major_dkv=blk, block_k_dkv=blk, block_q_dkv=blk,
+            block_k_major_dq=blk, block_k_dq=blk, block_q_dq=blk)
+        out = fa.flash_attention(qh, kh, vh, causal=True, sm_scale=scale, block_sizes=sizes)
+        return out.transpose(0, 2, 1, 3).reshape(b, s, kvh, g, d)
+
+    def splash(fwd, dkv, dq, k_layout):
+        sizes = sk.BlockSizes(
+            block_q=fwd[0], block_kv=fwd[1], block_kv_compute=fwd[2],
+            block_q_dkv=dkv[0], block_kv_dkv=dkv[1], block_kv_dkv_compute=dkv[2],
+            block_q_dq=dq and dq[0], block_kv_dq=dq and dq[1],
+            use_fused_bwd_kernel=dq is None, k_layout=sk.QKVLayout[k_layout])
+
+        def fn(q, k, v):
+            b, s, kvh, g, _ = q.shape
+            kernel = sk.make_splash_mha(
+                sm.MultiHeadMask([sm.CausalMask((s, s))] * (kvh * g)), block_sizes=sizes,
+                head_shards=1, q_seq_shards=1)
+            qh = (q * scale).reshape(b, s, kvh * g, d).transpose(0, 2, 1, 3)
+            out = jax.vmap(kernel)(qh, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
+            return out.transpose(0, 2, 1, 3).reshape(b, s, kvh, g, d)
+        return fn
+
+    head, seq = "HEAD_DIM_MINOR", "SEQ_MINOR"
+    out = [({"attention": "flash_old", "block_q": 1024, "block_kv": 1024}, flash_old),
+           ({"attention": "program"}, lambda q, k, v: layers.splash_attention(q, k, v, scale))]
+    # one block pair for all three kernels, unfused and fused; then k's layout,
+    # the inner compute block, and a query block of 2,048 (the scratch allows
+    # it only with a compute block of 512)
+    same = [((bq, bkv, min(bkv, 1024)), fused, head)
+            for fused in (False, True) for bq in (512, 1024) for bkv in (512, 1024, 2048)]
+    same += [((1024, 1024, 1024), False, seq), ((1024, 1024, 1024), True, seq),
+             ((1024, 2048, 1024), True, seq),
+             ((1024, 1024, 512), False, head), ((1024, 2048, 512), False, head),
+             ((1024, 1024, 512), True, head), ((1024, 2048, 512), True, head),
+             ((512, 512, 256), False, head),
+             ((2048, 1024, 512), False, head), ((2048, 1024, 512), True, head),
+             ((2048, 512, 512), False, head)]
+    settings = [(blocks, blocks, None if fused else blocks[:2], layout)
+                for blocks, fused, layout in same]
+    # each kernel with the blocks it read fastest with in the rows above
+    settings += [((1024, 1024, 512), (1024, 1024, 1024), None, head),
+                 ((1024, 1024, 512), (1024, 2048, 1024), None, head),
+                 ((1024, 1024, 512), (2048, 1024, 512), None, head),
+                 ((1024, 1024, 256), (1024, 1024, 1024), None, head),
+                 ((1024, 1024, 512), (1024, 1024, 1024), (1024, 1024), head)]
+    for fwd, dkv, dq, k_layout in settings:
+        out.append(({"attention": "splash", "block_q": fwd[0], "block_kv": fwd[1],
+                     "fwd": fwd, "dkv": dkv, "dq": dq, "fused": dq is None,
+                     "k_layout": k_layout}, splash(fwd, dkv, dq, k_layout)))
+    out.append(({"attention": "blockwise", "block_q": 512, "block_kv": 512},
+                lambda q, k, v: layers.blockwise_attention(q, k, v, scale, 512)))
+    out.append(({"attention": "flash_old", "block_q": 1024, "block_kv": 1024,
+                 "again": "the chip's clock at the end of the sweep"}, flash_old))
+    return out
+
+
+def attention_inputs(seq: int, d: int = 64):
     key = jax.random.PRNGKey(0)
-    b, s, kvh, g, d = 2, args.seq, 8, 4, 64
-    q = jax.random.normal(key, (b, s, kvh, g, d), jnp.bfloat16)
-    k = jax.random.normal(jax.random.fold_in(key, 1), (b, s, kvh, d), jnp.bfloat16)
-    v = jax.random.normal(jax.random.fold_in(key, 2), (b, s, kvh, d), jnp.bfloat16)
+    b, kvh, g = 2, 8, 4
+    q = jax.random.normal(key, (b, seq, kvh, g, d), jnp.bfloat16)
+    k = jax.random.normal(jax.random.fold_in(key, 1), (b, seq, kvh, d), jnp.bfloat16)
+    v = jax.random.normal(jax.random.fold_in(key, 2), (b, seq, kvh, d), jnp.bfloat16)
+    return q, k, v
 
-    def attn(impl, block):
-        def loss(q, k, v):
-            if impl == "flash":
-                out = layers.flash_attention(q, k, v, d ** -0.5, block)
-            else:
-                out = layers.blockwise_attention(q, k, v, d ** -0.5, block)
-            return jnp.sum(out.astype(jnp.float32))
-        return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
 
-    for impl, block in (("flash", 512), ("flash", 1024), ("flash", 256), ("flash", 2048),
-                        ("blockwise", 512), ("blockwise", 1024)):
+def both_ways(fn):
+    return jax.jit(jax.grad(
+        lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32)), argnums=(0, 1, 2)))
+
+
+def device_ms_by_op(fn, *args, runs=3):
+    """Device time a run of each op of ``fn``'s program, from a profile of
+    ``runs`` runs: the Pallas kernels under their own names, all else summed
+    as ``around`` (transposes, repeats, the backward's row sums)."""
+    import collections
+    import shutil
+    import tempfile
+
+    from benchmarks.trace import xplane
+
+    logdir = tempfile.mkdtemp(prefix="lm_kernel_probe_")
+    try:
+        jax.profiler.start_trace(logdir)
+        for _ in range(runs):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        jax.profiler.stop_trace()
+        total = collections.Counter()
+        for _, line, name, _, duration_ns in xplane.rows_from_xplane(xplane.find_xplane(logdir)):
+            if line == "XLA Ops":
+                name = name.split(".")[0]
+                kernel = any(word in name for word in ("splash", "flash"))
+                total[name if kernel else "around"] += duration_ns
+        return {name: round(ns / runs / 1e6, 3) for name, ns in sorted(total.items())}
+    finally:
+        shutil.rmtree(logdir, ignore_errors=True)
+
+
+def attention(args) -> None:
+    """The token cell's attention layer both ways, 2 x ``--seq`` positions, 32
+    heads over 8 KV heads of 64: the library's splash kernel over its block
+    sizes, fused and unfused backward and k's layout, the older flash kernel
+    the program ran through PR 29, and what ``layers.splash_attention`` picks."""
+    q, k, v = attention_inputs(args.seq)
+    reference = None
+    for row, fn in attention_candidates():
         try:
-            ms = timed(attn(impl, block), q, k, v)
-            print(json.dumps({"attention": impl, "block": block, "fwd_bwd_ms": ms}), flush=True)
+            step = both_ways(fn)
+            grads = step(q, k, v)
+            if reference is None:
+                reference = grads
+            # the candidates are one function: the largest gap of dq to the first row's
+            row["dq_gap"] = float(jnp.max(jnp.abs(
+                grads[0].astype(jnp.float32) - reference[0].astype(jnp.float32))))
+            row["fwd_bwd_ms"] = timed(step, q, k, v, repeats=20)
+            row["device_ms"] = device_ms_by_op(step, q, k, v)
         except Exception as exc:  # noqa: BLE001 - a probe reports and goes on
-            print(json.dumps({"attention": impl, "block": block, "error": repr(exc)[:300]}),
-                  flush=True)
-
+            row["error"] = repr(exc)[:300]
+        print(json.dumps(row), flush=True)
 
 
 def experts(args) -> None:

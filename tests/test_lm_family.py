@@ -310,6 +310,69 @@ def test_blockwise_attention_is_dense_attention_both_ways():
         close(a, b, "attention gradient")
 
 
+BF16_TOL = 2.0 ** -6      # of a leaf's largest element: bfloat16 keeps 8 bits, read 0.003-0.004
+
+
+@pytest.mark.parametrize("s,blocks", [(1024, 1), (1536, 3)])
+def test_splash_attention_is_dense_attention_both_ways(s, blocks):
+    """The kernel a TPU runs, in the library's interpret mode: bfloat16
+    operands, 4 heads over 2 KV heads that go in unrepeated; one block, and
+    three a side so that diagonal, whole and skipped blocks are all there;
+    against the float32 square on the same rounded operands."""
+    key = jax.random.PRNGKey(8)
+    q = jax.random.normal(key, (2, s, 2, 2, 64), jnp.bfloat16)
+    k = jax.random.normal(jax.random.fold_in(key, 1), (2, s, 2, 64), jnp.bfloat16)
+    v = jax.random.normal(jax.random.fold_in(key, 2), (2, s, 2, 64), jnp.bfloat16)
+    probe = jax.random.normal(jax.random.fold_in(key, 3), q.shape)
+    assert s // layers.splash_blocks(s) == blocks
+
+    def both_ways(fn, *operands):
+        return jax.value_and_grad(
+            lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32) * probe),
+            argnums=(0, 1, 2))(*operands)
+
+    scale = 64 ** -0.5
+    out, grads = both_ways(
+        lambda q, k, v: layers.splash_attention(q, k, v, scale, interpret=True), q, k, v)
+    with jax.default_matmul_precision("highest"):
+        dense, dense_grads = both_ways(
+            lambda q, k, v: layers.dense_attention(q, k, v, scale),
+            *(x.astype(jnp.float32) for x in (q, k, v)))
+    assert abs(float(out) - float(dense)) <= BF16_TOL * abs(float(dense))
+    for a, b, what in zip(grads, dense_grads, "qkv"):
+        assert a.dtype == jnp.bfloat16 and a.shape == b.shape
+        gap = float(jnp.max(jnp.abs(a.astype(jnp.float32) - b))) / float(jnp.max(jnp.abs(b)))
+        assert gap <= BF16_TOL, (what, gap)
+    # the kernel object, mask tables and all, is made once a shape
+    before = layers._splash_kernel.cache_info()
+    jax.eval_shape(lambda: layers.splash_attention(q, k, v, scale, interpret=True))
+    after = layers._splash_kernel.cache_info()
+    assert (after.misses, after.hits) == (before.misses, before.hits + 1)
+
+
+def test_the_kernel_refuses_a_sequence_it_cannot_tile():
+    q = jnp.zeros((1, 1000, 2, 2, 64), jnp.bfloat16)
+    kv = jnp.zeros((1, 1000, 2, 64), jnp.bfloat16)
+    with pytest.raises(ValueError, match=r"sequence 1000 .* block 128"):
+        layers.splash_attention(q, kv, kv, 0.125)
+
+
+@pytest.mark.parametrize("backend,s,path", [
+    ("tpu", 8192, "splash"), ("tpu", 1024, "splash"), ("tpu", 512, "dense"),
+    ("cpu", 8192, "blockwise"), ("cpu", 512, "dense")])
+def test_which_attention_a_backend_and_a_length_take(monkeypatch, backend, s, path):
+    """Short sequences the square; past 512 positions the kernel on a TPU and
+    query blocks in ``lax`` elsewhere: from the backend and the shape alone."""
+    taken = []
+    for name in ("splash", "blockwise", "dense"):
+        monkeypatch.setattr(layers, name + "_attention",
+                            lambda *a, _name=name, **kw: taken.append(_name))
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    q = jax.ShapeDtypeStruct((1, s, 2, 2, 64), jnp.bfloat16)
+    layers.causal_attention(q, None, None, 0.125)
+    assert taken == [path]
+
+
 def _batches(seed, n=3, **kw):
     feed = PackedTokenFeed(batch_size=2, seq_len=256, vocab=128, seed=seed, documents=64,
                            doc_len_median=48, doc_len_sigma=1.0, doc_len_min=4, **kw)
