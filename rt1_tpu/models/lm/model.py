@@ -1,5 +1,6 @@
 """The decoder: embedding, blocks as the spec lists them, final norm, the
-tied output head over the rows of the embedding held here, and the loss.
+output head over the vocabulary rows held here (the embedding again where the
+spec ties them, else a leaf of its own), and the loss.
 
     h = x + Mixer(RMSNorm(x));  y = h + FFN(RMSNorm(h))
 """
@@ -47,7 +48,8 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x, live):
         sp = self.spec
-        mixer = (ShortConv if self.block.mixer == "conv" else GQAttention)(sp, name="mixer")
+        mixer = (ShortConv(sp, name="mixer") if self.block.mixer == "conv"
+                 else GQAttention(sp, self.block.mixer, name="mixer"))
         h = x + mixer(RMSNorm(sp.norm_eps, sp.dtype, name="mixer_norm")(x))
         normed = RMSNorm(sp.norm_eps, sp.dtype, name="ffn_norm")(h)
         if self.block.ffn == "dense":
@@ -86,6 +88,9 @@ class DecoderLM(nn.Module):
                 mean = mean + rows["rows_mean"]
                 fallbacks = fallbacks + rows["fallback"]
         x = RMSNorm(sp.norm_eps, sp.dtype, name="final_norm")(x)
+        if not sp.tie_word_embeddings:
+            embedding = Leaf("embedding", (sp.vocab_held, sp.hidden_size),
+                             nn.initializers.normal(0.02), name="lm_head")()
         head = embedding.astype(sp.dtype)
         with jax.named_scope("lm_loss"):
             out = {"loss": next_token_loss(x, head, targets)}
@@ -96,6 +101,14 @@ class DecoderLM(nn.Module):
                 "moe/load_max_over_mean": largest / jnp.maximum(mean, 1e-9),
                 "moe/fallback_layers": fallbacks,
             }
+        mixers = [b.mixer for b in sp.blocks]
+        if "sliding_attention" in mixers:
+            # static: which program a run timed (a decoder of full layers alone
+            # keeps the outputs it had)
+            out.setdefault("counters", {}).update({
+                "attention/window_layers": jnp.float32(mixers.count("sliding_attention")),
+                "attention/full_layers": jnp.float32(mixers.count("full_attention")),
+            })
         if return_logits:
             out["logits"] = jnp.einsum("bsd,vd->bsv", x, head,
                                        preferred_element_type=jnp.float32)

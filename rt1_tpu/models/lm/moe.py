@@ -1,4 +1,5 @@
-"""Sigmoid-routed experts, top-k, dropless, for the experts held here.
+"""Routed experts (sigmoid or softmax scores), top-k, dropless, for the
+experts held here.
 
 The layer is told which experts it holds (``spec.experts_held``).  It scores
 and selects over ALL the router's experts, normalises the selected scores as
@@ -50,7 +51,27 @@ from rt1_tpu.models.lm.spec import LMSpec
 
 _STACK_INIT = nn.initializers.variance_scaling(1.0, "fan_in", "normal", in_axis=-2, out_axis=-1,
                                                batch_axis=(0,))
-MEGABLOX_TILING = (512, 1024, 1024)
+# Rows of one tile of the grouped product: a group's rows are padded to it, and
+# the row buffer is a whole number of them.
+ROW_TILE = 512
+
+
+def megablox_tiling(k: int, n: int) -> Tuple[int, int, int]:
+    """The grouped product's tiles (rows, contraction, columns) for stacks of
+    (k, n).  A side takes 1,024 where 512 divides it (2048, 3072; 1536 runs as
+    one whole tile and a half one, masked: the tiling the chip chose at those
+    widths, PERF.md section 6, PR 27), else the largest multiple of the 128
+    lanes up to 1,024 that divides it (2304 -> 768, 1792 -> 896, 896 -> 896:
+    no tile of 1,024 divides these; both products of 16 experts over 32,768
+    rows, both ways, read 10.27 ms against 11.50 at (512, 1024, 1024) and 11.91
+    at (512, 512, 512): scripts/lm_kernel_probe.py --only experts, PERF.md
+    section 6, PR 31)."""
+    def side(size: int) -> int:
+        if size % 512 == 0:
+            return 1024
+        return max((t for t in range(128, 1025, 128) if size % t == 0), default=1024)
+
+    return ROW_TILE, side(k), side(n)
 
 
 class _Experts(nn.Module):
@@ -71,6 +92,16 @@ def route(x, router_kernel, expert_bias, spec: LMSpec):
 
     ``expert_bias`` (or None) enters the selection only."""
     logits = jnp.dot(x.astype(jnp.float32), router_kernel, precision=lax.Precision.HIGHEST)
+    if spec.scoring_func == "softmax":
+        # over all the router's experts, before the selection; the selected
+        # ones' shares of their own sum are the weights
+        scores = jax.nn.softmax(logits, axis=-1)
+        _, idx = lax.top_k(scores, spec.experts_per_tok)
+        weights = jnp.take_along_axis(scores, idx, axis=-1)
+        if spec.norm_topk_prob:
+            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        scaling = spec.routed_scaling_factor
+        return idx, weights if scaling == 1.0 else weights * scaling
     scores = jax.nn.sigmoid(logits)
     select = scores if expert_bias is None else scores + lax.stop_gradient(expert_bias)
     _, idx = lax.top_k(select, spec.experts_per_tok)
@@ -88,7 +119,8 @@ def grouped_matmul(rows, stack, group_sizes):
     if jax.default_backend() == "tpu":
         from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
 
-        return megablox.gmm(rows, stack, group_sizes, rows.dtype, MEGABLOX_TILING)
+        return megablox.gmm(rows, stack, group_sizes, rows.dtype,
+                            megablox_tiling(*stack.shape[1:]))
     return lax.ragged_dot(rows, stack, group_sizes, preferred_element_type=rows.dtype)
 
 
@@ -96,7 +128,7 @@ def row_capacity(n: int, held: int, num_experts: int) -> int:
     """Rows of the row buffer for ``n`` assignment slots: twice the balanced
     share of the held experts, in whole row tiles of the grouped product,
     never more than ``n``."""
-    tile = MEGABLOX_TILING[0]
+    tile = ROW_TILE
     balanced = -(-n * held // num_experts)
     return min(n, -(-2 * balanced // tile) * tile)
 

@@ -148,8 +148,9 @@ def rt1_sharding_plan() -> List[Rule]:
         # fp32 router and its selection bias: every shard routes alike.
         (r"ffn/router/kernel$", P()),
         (r"ffn/expert_bias/kernel$", P()),
-        # The (sliced) embedding, tied to the output head: rows over `model`.
-        (r"embed/embedding$", P("model", None)),
+        # The (sliced) embedding, and the output head where it is a leaf of
+        # its own (untied): rows over `model`.
+        (r"(embed|lm_head)/embedding$", P("model", None)),
         # Mixers, the dense FFN and the norms: replicated.
         (r"mixer/(in_proj|out_proj|[qkvo]_proj)/kernel$", P()),
         (r"mixer/kernel$", P()),

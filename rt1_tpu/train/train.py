@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from rt1_tpu.specs import language_table_action_space, sample_space
+from rt1_tpu.train.families import family_of
 from rt1_tpu.trainer import (
     create_train_state,
     make_optimizer,
@@ -124,85 +125,32 @@ def build_model(model_config, mesh=None):
     )
 
 
-# Families whose parameter paths parallel/plan.py's rules describe.
-PLANNED_FAMILIES = ("rt1", "lfm2_moe")
-
-
 def build_family(model_config, mesh=None):
-    """(model, init_fn, loss_fn) for config.model.family = "rt1" | "lava" |
-    "lfm2_moe".
+    """(model, init_fn, loss_fn) for ``config.model.family``, from the
+    family's record (rt1_tpu/train/families.py).
 
     The reference trains its two model families from separate stacks
     (Stack A `distribute_train.py` for RT-1, Stack B
     `language_table/train/train.py:105-116` for LAVA/BC); here one train
-    loop serves every family — the family only selects the model
-    constructor, the init signature, and the loss closure plugged into the
-    jitted SPMD step. "lfm2_moe" is a decoder language model built from a
-    block description (rt1_tpu/models/lm, docs/lm_family.md); its batches
-    are token ids (rt1_tpu/data/tokens.py).
+    loop serves every family — the record only selects the model
+    constructor, the init signature, the loss closure plugged into the
+    jitted SPMD step, and the host feed. The decoder language models built
+    from a block description (rt1_tpu/models/lm, docs/lm_family.md) are
+    entries that share one builder; their batches are token ids
+    (rt1_tpu/data/tokens.py).
     """
-    family = model_config.get("family", "rt1")
-    if family == "rt1":
-        return build_model(model_config, mesh=mesh), None, None
+    family = family_of(model_config)
     if (
-        mesh is not None
+        not family.pipelined
+        and mesh is not None
         and getattr(mesh, "shape", {}).get("stage", 1) > 1
     ):
         raise ValueError(
             f"mesh.stage > 1 (pipeline parallelism) is only supported for "
-            f"the 'rt1' family; family={family!r} would silently replicate "
-            f"all compute across the stage axis"
+            f"the 'rt1' family; family={model_config.family!r} would "
+            f"silently replicate all compute across the stage axis"
         )
-    if family == "lava":
-        from rt1_tpu.models.lava import SequenceLAVMSE
-        from rt1_tpu.trainer.bc import adapt_obs_for_lava, make_bc_step_loss_fn
-
-        lv = model_config.lava
-        text_encoder_def = None
-        if lv.lang_encoder == "clip":
-            from rt1_tpu.models.lava.clip_text import CLIPTextEncoder
-
-            text_encoder_def = CLIPTextEncoder(
-                vocab_size=lv.get("text_vocab", 514),
-                context_length=lv.get("text_context", 77),
-                width=lv.get("text_width", 512),
-                num_layers=lv.get("text_layers", 12),
-                num_heads=lv.get("text_heads", 8),
-                embed_dim=lv.get("text_embed_dim", 512),
-            )
-        model = SequenceLAVMSE(
-            action_size=lv.action_size,
-            dense_resnet_width=lv.dense_resnet_width,
-            dense_resnet_num_blocks=lv.dense_resnet_num_blocks,
-            lava_num_layers=lv.num_layers,
-            lava_sequence_length=model_config.time_sequence_length,
-            lava_temporal_transformer_num_layers=lv.temporal_num_layers,
-            lava_d_model=lv.d_model,
-            lava_num_heads=lv.num_heads,
-            lava_pyramid_fuse_layers=tuple(lv.pyramid_fuse_layers),
-            lava_image_encoder=lv.image_encoder,
-            lava_lang_encoder=lv.lang_encoder,
-            text_encoder_def=text_encoder_def,
-        )
-
-        def init_fn(model, rng, obs, actions):
-            return model.init(
-                {"params": rng}, adapt_obs_for_lava(obs), train=False
-            )
-
-        return model, init_fn, make_bc_step_loss_fn(model)
-    if family == "lfm2_moe":
-        from rt1_tpu.models.lm import DecoderLM, LMSpec, make_lm_step_loss_fn
-
-        model = DecoderLM(LMSpec.from_config(
-            model_config.lm, jnp.dtype(model_config.get("dtype", "float32"))
-        ))
-
-        def init_fn(model, rng, obs, actions):
-            return model.init({"params": rng}, obs, actions, train=False)
-
-        return model, init_fn, make_lm_step_loss_fn(model)
-    raise ValueError(f"Unknown model family: {family!r}")
+    return family.build(model_config, mesh)
 
 
 def _make_clip_tokenizer(config):
@@ -253,28 +201,6 @@ def _check_clip_token_config(config):
             "data.clip_tokens=True but no model consumes "
             "instruction_tokenized_clip (set model.lava.lang_encoder='clip')"
         )
-
-
-def synthetic_batches(config, seed=0) -> Iterator:
-    """Random fixed batches when no dataset is configured (smoke/bench)."""
-    rng = np.random.default_rng(seed)
-    b = config.per_host_batch_size
-    t = config.model.time_sequence_length
-    h, w = config.data.height, config.data.width
-    while True:
-        obs = {
-            "image": rng.random((b, t, h, w, 3), dtype=np.float32),
-            "natural_language_embedding": rng.standard_normal(
-                (b, t, 512), dtype=np.float32
-            ),
-        }
-        actions = {
-            "terminate_episode": rng.integers(
-                0, 2, (b, t), dtype=np.int32
-            ),
-            "action": rng.uniform(-0.1, 0.1, (b, t, 2)).astype(np.float32),
-        }
-        yield {"observations": obs, "actions": actions}
 
 
 def _packed_batches(
@@ -349,8 +275,8 @@ def _packed_batches(
     # streams stay the unweighted pinned corpus walk): weights come from
     # `config.data.task_weights` ("task:weight,..." string, docs/data.md);
     # task-id emission arms exactly when the step's health pack will
-    # consume it (model_health on, RT-1 family), so health-off runs keep a
-    # byte-identical batch stream.
+    # consume it (model_health on, a family whose pack reads them), so
+    # health-off runs keep a byte-identical batch stream.
     task_weights = None
     emit_task_ids = False
     if split == "train":
@@ -360,7 +286,7 @@ def _packed_batches(
         task_weights = parse_task_weights(config.data.get("task_weights"))
         emit_task_ids = (
             obs_lib.ObsOptions.from_config(config).model_health
-            and config.model.get("family", "rt1") == "rt1"
+            and family_of(config.model).task_ids
         )
     return _build(
         SampleAheadFeeder,
@@ -597,6 +523,7 @@ def train_and_evaluate(config, workdir: str):
     write_hparams(
         writer, dict(config.to_dict()) if hasattr(config, "to_dict") else {}
     )
+    family = family_of(config.model)
     model, init_fn, loss_fn = build_family(config.model, mesh=mesh)
     data_size = sharding_plan.data_parallel_size
     # The batch the jitted step sees is GLOBAL: per-host rows × processes
@@ -651,12 +578,8 @@ def train_and_evaluate(config, workdir: str):
             os.makedirs(workdir, exist_ok=True)
             with open(os.path.join(workdir, "data_manifest.json"), "w") as f:
                 json.dump(manifest, f, indent=2, sort_keys=True)
-    elif config.model.get("family", "rt1") == "lfm2_moe":
-        from rt1_tpu.data.tokens import feed_from_config
-
-        train_iter = feed_from_config(config, config.seed)
     else:
-        train_iter = synthetic_batches(config, config.seed)
+        train_iter = family.host_feed(config, config.seed)
 
     first = next(train_iter)
     # Model init must not see the feeder's per-task telemetry member — the
@@ -749,7 +672,7 @@ def train_and_evaluate(config, workdir: str):
         ),
         plan=sharding_plan,
         mixed_precision=mixed_precision,
-        check_coverage=config.model.get("family", "rt1") in PLANNED_FAMILIES,
+        check_coverage=family.planned,
     )
     state = fns.shard_state(state)
     # What the runtime placed, read off the shards (not the plan): under
@@ -796,12 +719,8 @@ def train_and_evaluate(config, workdir: str):
                 eval_iter = dataset_batches(config, "val")
             except FileNotFoundError:
                 eval_iter = None
-        elif config.model.get("family", "rt1") == "lfm2_moe":
-            from rt1_tpu.data.tokens import feed_from_config
-
-            eval_iter = feed_from_config(config, config.seed + 1)
         else:
-            eval_iter = synthetic_batches(config, config.seed + 1)
+            eval_iter = family.host_feed(config, config.seed + 1)
 
     meter = ThroughputMeter(
         config.per_host_batch_size * jax.process_count(),
@@ -1128,7 +1047,7 @@ def train_and_evaluate(config, workdir: str):
                         config, "train", seed=fresh_seed
                     )
                 else:
-                    train_iter = synthetic_batches(config, fresh_seed)
+                    train_iter = family.host_feed(config, fresh_seed)
                 live_iter["host"] = train_iter
                 feeder_stats = getattr(train_iter, "stats", None)
                 flywheel_stats = getattr(train_iter, "flywheel_stats", None)

@@ -1,5 +1,8 @@
-"""Times the candidate kernels of the lfm2_moe family's step on the chip, at
-the benchmark cell's shapes: causal attention forward + backward (the
+"""Times the candidate kernels of a decoder LM's step on the chip, at a
+benchmark cell's shapes (the defaults are ``lfm2-24b-a2b.train-tokens-8k``'s;
+``--seq 16384 --batch 1 --heads 32 --kv-heads 4 --head-dim 128 --window 1024
+--hidden 2304 --expert-width 896 --experts-held 16 --top-k 8`` are
+``mellum2-12b-a2.5b.train-tokens-16k``'s): causal attention forward + backward (the
 library's splash kernel over block sizes, fused and unfused backward and k's
 layout, against the older flash kernel with its KV heads repeated and the
 blockwise lax form) and the grouped expert product forward + backward
@@ -11,6 +14,10 @@ at a row buffer of a quarter of the slots and of all of them, the index each
 needs (the sort of all slots against a token-major compaction that sorts the
 buffer's keys only), and ``models/lm/moe.py``'s whole layer both ways at both
 capacities.  A tool for PERF.md section 6; no benchmark metric reads it.
+
+With ``--window`` the attention rows are a sliding layer's (``LocalMask``,
+the query's own key and the ``window - 1`` before it): blocks of 256, 512 and
+1,024, fused and unfused backward, and the blockwise lax form.
 
     python scripts/lm_kernel_probe.py [--rows 65536 --held_rows 8192] [--only routed]
 """
@@ -40,6 +47,16 @@ def main() -> int:
     ap.add_argument("--rows", type=int, default=65536)
     ap.add_argument("--held_rows", type=int, default=8192)
     ap.add_argument("--seq", type=int, default=8192)
+    ap.add_argument("--batch", type=int, default=2)
+    ap.add_argument("--heads", type=int, default=32)
+    ap.add_argument("--kv-heads", type=int, default=8)
+    ap.add_argument("--head-dim", type=int, default=64)
+    ap.add_argument("--window", type=int, default=None,
+                    help="keys a query sees, its own among them (default: causal)")
+    ap.add_argument("--hidden", type=int, default=2048)
+    ap.add_argument("--expert-width", type=int, default=1536)
+    ap.add_argument("--experts-held", type=int, default=8)
+    ap.add_argument("--top-k", type=int, default=4)
     ap.add_argument("--only", choices=("attention", "experts", "routed"), default=None)
     args = ap.parse_args()
 
@@ -51,7 +68,7 @@ def main() -> int:
     return 0
 
 
-def attention_candidates(d: int = 64):
+def attention_candidates(d: int = 64, window=None):
     """``(row, fn)`` pairs: each ``fn(q, k, v)`` takes the program's layout,
     q ``(b, s, kv heads, group, d)``, k and v ``(b, s, kv heads, d)``, so the
     transposes (and the old kernel's repeats) a step pays are in its time.  A
@@ -88,8 +105,10 @@ def attention_candidates(d: int = 64):
 
         def fn(q, k, v):
             b, s, kvh, g, _ = q.shape
+            one = (sm.CausalMask((s, s)) if window is None
+                   else sm.LocalMask((s, s), window_size=(window - 1, 0), offset=0))
             kernel = sk.make_splash_mha(
-                sm.MultiHeadMask([sm.CausalMask((s, s))] * (kvh * g)), block_sizes=sizes,
+                sm.MultiHeadMask([one] * (kvh * g)), block_sizes=sizes,
                 head_shards=1, q_seq_shards=1)
             qh = (q * scale).reshape(b, s, kvh * g, d).transpose(0, 2, 1, 3)
             out = jax.vmap(kernel)(qh, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
@@ -97,8 +116,32 @@ def attention_candidates(d: int = 64):
         return fn
 
     head, seq = "HEAD_DIM_MINOR", "SEQ_MINOR"
-    out = [({"attention": "flash_old", "block_q": 1024, "block_kv": 1024}, flash_old),
-           ({"attention": "program"}, lambda q, k, v: layers.splash_attention(q, k, v, scale))]
+    program = ({"attention": "program", "window": window},
+               lambda q, k, v: layers.splash_attention(q, k, v, scale, window))
+    if window is not None:
+        # a sliding layer: a query block visits ceil(window / block) + 1 key blocks
+        # (2 at 1,024, 3 at 512, 5 at 256 for a window of 1,024), so smaller
+        # blocks visit fewer keys and pay more grid steps; the old kernel has no window
+        out = [program]
+        for fused in (True, False):
+            for blocks in ((1024, 1024, 512), (1024, 1024, 1024), (512, 512, 512),
+                           (256, 256, 256), (1024, 512, 512), (512, 1024, 512),
+                           (2048, 1024, 512)):
+                out.append(({"attention": "splash", "window": window, "block_q": blocks[0],
+                             "block_kv": blocks[1], "fwd": blocks, "dkv": blocks,
+                             "dq": None if fused else blocks[:2], "fused": fused,
+                             "k_layout": head},
+                            splash(blocks, blocks, None if fused else blocks[:2], head)))
+        for fwd, dkv in (((1024, 1024, 512), (512, 512, 512)), ((512, 512, 512), (1024, 1024, 1024)),
+                         ((256, 256, 256), (512, 512, 512))):
+            out.append(({"attention": "splash", "window": window, "block_q": fwd[0],
+                         "block_kv": fwd[1], "fwd": fwd, "dkv": dkv, "dq": None, "fused": True,
+                         "k_layout": head}, splash(fwd, dkv, None, head)))
+        out.append(({"attention": "blockwise", "window": window, "block_q": 512, "block_kv": 512},
+                    lambda q, k, v: layers.blockwise_attention(q, k, v, scale, 512, window)))
+        out.append((dict(program[0], again="the chip's clock at the end of the sweep"), program[1]))
+        return out
+    out = [({"attention": "flash_old", "block_q": 1024, "block_kv": 1024}, flash_old), program]
     # one block pair for all three kernels, unfused and fused; then k's layout,
     # the inner compute block, and a query block of 2,048 (the scratch allows
     # it only with a compute block of 512)
@@ -130,9 +173,8 @@ def attention_candidates(d: int = 64):
     return out
 
 
-def attention_inputs(seq: int, d: int = 64):
+def attention_inputs(seq: int, d: int = 64, b: int = 2, kvh: int = 8, g: int = 4):
     key = jax.random.PRNGKey(0)
-    b, kvh, g = 2, 8, 4
     q = jax.random.normal(key, (b, seq, kvh, g, d), jnp.bfloat16)
     k = jax.random.normal(jax.random.fold_in(key, 1), (b, seq, kvh, d), jnp.bfloat16)
     v = jax.random.normal(jax.random.fold_in(key, 2), (b, seq, kvh, d), jnp.bfloat16)
@@ -173,13 +215,16 @@ def device_ms_by_op(fn, *args, runs=3):
 
 
 def attention(args) -> None:
-    """The token cell's attention layer both ways, 2 x ``--seq`` positions, 32
-    heads over 8 KV heads of 64: the library's splash kernel over its block
-    sizes, fused and unfused backward and k's layout, the older flash kernel
-    the program ran through PR 29, and what ``layers.splash_attention`` picks."""
-    q, k, v = attention_inputs(args.seq)
+    """One attention layer both ways, ``--batch`` x ``--seq`` positions,
+    ``--heads`` over ``--kv-heads`` KV heads of ``--head-dim`` (the defaults: 2 x
+    8,192, 32 over 8 of 64): the library's splash kernel over its block sizes,
+    fused and unfused backward and k's layout, the older flash kernel the
+    program ran through PR 29, and what ``layers.splash_attention`` picks;
+    under ``--window`` a sliding layer's rows."""
+    q, k, v = attention_inputs(args.seq, args.head_dim, args.batch, args.kv_heads,
+                               args.heads // args.kv_heads)
     reference = None
-    for row, fn in attention_candidates():
+    for row, fn in attention_candidates(args.head_dim, args.window):
         try:
             step = both_ways(fn)
             grads = step(q, k, v)
@@ -198,8 +243,10 @@ def attention(args) -> None:
 def experts(args) -> None:
     from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
 
+    from rt1_tpu.models.lm import moe
+
     key = jax.random.PRNGKey(0)
-    held, dm, f = 8, 2048, 1536
+    held, dm, f = args.experts_held, args.hidden, args.expert_width
     rows = jax.random.normal(key, (args.rows, dm), jnp.bfloat16)
     w13 = jax.random.normal(key, (held, dm, 2 * f), jnp.bfloat16) * 0.02
     w2 = jax.random.normal(key, (held, f, dm), jnp.bfloat16) * 0.02
@@ -214,13 +261,21 @@ def experts(args) -> None:
                 return jnp.sum(jnp.where(valid, out, 0).astype(jnp.float32))
             return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
 
-        candidates = [("ragged_dot", None)] + [("megablox", t) for t in (
+        # the program's rule (a tiling a product), then one tiling for both
+        # products: whole and half sides, sides of 1,024 with a masked remainder,
+        # lane multiples that divide both widths where there are any
+        both = sorted({t for t in range(128, 1025, 128) if dm % t == 0 and (2 * f) % t == 0
+                       and f % t == 0})
+        candidates = [("ragged_dot", None), ("megablox", "rule")] + [("megablox", t) for t in [
             (512, 1024, 1024), (512, 512, 512), (256, 1024, 1024), (1024, 1024, 1024),
-            (512, 2048, 1536), (128, 128, 128))]
+            (512, dm, f), (128, 128, 128)] + [(512, t, t) for t in both]]
         for impl, tiling in candidates:
             if impl == "ragged_dot":
                 product = lambda a, w, gs: lax.ragged_dot(  # noqa: E731
                     a, w, gs, preferred_element_type=a.dtype)
+            elif tiling == "rule":
+                product = lambda a, w, gs: megablox.gmm(  # noqa: E731
+                    a, w, gs, a.dtype, moe.megablox_tiling(*w.shape[1:]))
             else:
                 product = lambda a, w, gs, t=tiling: megablox.gmm(  # noqa: E731
                     a, w, gs, a.dtype, t)
@@ -234,8 +289,10 @@ def experts(args) -> None:
 
 
 def routed(args) -> None:
-    """2 x ``--seq`` tokens, top-4 of 64 experts, 8 held, 82 % of the tokens
-    live, width 2048: the token cell's routed layer."""
+    """``--batch`` x ``--seq`` tokens, top-``--top-k`` of 64 experts,
+    ``--experts-held`` held, 82 % of the tokens live, widths ``--hidden`` and
+    ``--expert-width`` (the defaults: 2 x 8,192, top-4, 8 held, 2048 and 1536:
+    the lfm2 token cell's routed layer)."""
     import functools
 
     import numpy as np
@@ -244,8 +301,11 @@ def routed(args) -> None:
     from rt1_tpu.models.lm.spec import LMSpec
     from rt1_tpu.train.configs import lfm2_moe
 
-    spec = LMSpec.from_config(lfm2_moe.get_config().model.lm, jnp.bfloat16)
-    tokens, k = 2 * args.seq, spec.experts_per_tok
+    lm = lfm2_moe.get_config().model.lm
+    lm.hidden_size, lm.moe_intermediate_size = args.hidden, args.expert_width
+    lm.num_experts_per_tok, lm.experts_held = args.top_k, (0, args.experts_held)
+    spec = LMSpec.from_config(lm, jnp.bfloat16)
+    tokens, k = args.batch * args.seq, spec.experts_per_tok
     d, f = spec.hidden_size, spec.moe_intermediate_size
     held, num_experts = spec.experts_held[1], spec.num_experts
     n = tokens * k

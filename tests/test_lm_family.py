@@ -226,7 +226,7 @@ def test_the_row_path_is_the_slot_path(monkeypatch, room):
 ])
 def test_the_capacity_rule(n, held, experts, rows):
     assert moe.row_capacity(n, held, experts) == rows
-    assert rows == n or (rows % moe.MEGABLOX_TILING[0] == 0 and rows >= 2 * n * held / experts)
+    assert rows == n or (rows % moe.ROW_TILE == 0 and rows >= 2 * n * held / experts)
 
 
 @pytest.mark.parametrize("held, branches", [((4, 4), True), ((0, 16), False)])

@@ -216,3 +216,79 @@ def test_lm_routed_experts_compile_both_ways_at_the_published_widths(v5e, monkey
     # the slot path's gathers go back as gathers, the row path adds up a
     # buffer's rows: no scatter over 65,536 rows of 2048 on either
     assert not re.findall(r"\[65536,2048\]\S* scatter\(", text)
+
+
+def _mellum_spec():
+    from rt1_tpu.models.lm.spec import LMSpec
+    from rt1_tpu.train.configs import mellum
+
+    return LMSpec.from_config(mellum.get_config().model.lm, jnp.bfloat16)
+
+
+@pytest.mark.parametrize("mixer", ["sliding_attention", "full_attention"])
+def test_mellum_attention_compiles_both_ways_at_the_published_widths(v5e, monkeypatch, mixer):
+    """Both kinds of layer of ``mellum2-12b-a2.5b`` at the cell's 1 x 16,384
+    positions, 32 heads over 4 KV heads of 128: the splash kernels with the
+    layer's mask (a window of 1,024 keys at blocks of 512 with an unfused
+    backward, or causal at blocks of 1,024 with the fused one), inside the
+    chip's VMEM at head size 128."""
+    from rt1_tpu.models.lm import layers
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")     # as on the chip
+    sp = _mellum_spec()
+    window = sp.window(mixer)
+    assert window == (1024 if mixer == "sliding_attention" else None)
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    seq, heads, kv_heads, d = 16384, sp.num_heads, sp.num_kv_heads, sp.head_dim
+    q = jax.ShapeDtypeStruct((1, seq, kv_heads, heads // kv_heads, d), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, seq, kv_heads, d), jnp.bfloat16, sharding=one_chip)
+
+    def both_ways(q, k, v):
+        return jax.grad(lambda *a: jnp.sum(
+            layers.causal_attention(*a, d ** -0.5, window).astype(jnp.float32)),
+            argnums=(0, 1, 2))(q, k, v)
+
+    compiled = jax.jit(both_ways).lower(q, kv, kv).compile()
+    text = compiled.as_text()
+    kernels = {name for line in text.splitlines() if "tpu_custom_call" in line
+               for name in re.findall(r"%(splash_mha_\w+?)[.\d]* = ", line)}
+    # a causal layer's backward is one fused kernel; a sliding layer's has a dq kernel of its own
+    assert sorted(kernels) == (
+        ["splash_mha_dkv_no_residuals", "splash_mha_fwd_residuals"] if window is None else
+        ["splash_mha_dkv_no_residuals", "splash_mha_dq_no_residuals", "splash_mha_fwd_residuals"])
+    # k and v enter with their own 4 heads (a batch of one is squeezed away)
+    assert re.search(rf"bf16\[(1,)?{kv_heads},{seq},{d}\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.2e9
+
+
+def test_mellum_routed_experts_compile_both_ways_at_the_published_widths(v5e, monkeypatch):
+    """One routed layer of ``mellum2-12b-a2.5b`` at 16,384 tokens, top-8, 16 of
+    64 experts held: a row buffer of 65,536 of 131,072 slots (the slot path
+    behind a branch), the grouped products at 2304 x 1792 and 896 x 2304 with
+    the tiles ``megablox_tiling`` gives them."""
+    from rt1_tpu.models.lm import moe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    sp = _mellum_spec()
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    tokens, d, f, held = 16384, sp.hidden_size, sp.moe_intermediate_size, sp.experts_held[1]
+    n = tokens * sp.experts_per_tok
+    capacity = moe.row_capacity(n, held, sp.num_experts)
+    assert (n, capacity, d, f, held) == (131072, 65536, 2304, 896, 16)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def both_ways(x, weights, w1, w3, w2, idx, live):
+        return jax.grad(lambda x, weights, w1, w3, w2: jnp.sum(moe.held_experts_ffn(
+            x, idx, weights, live, w1, w3, w2, sp, capacity)[0].astype(jnp.float32)),
+            argnums=(0, 1, 2, 3, 4))(x, weights, w1, w3, w2)
+
+    compiled = jax.jit(both_ways).lower(
+        shape((tokens, d), jnp.bfloat16), shape((tokens, sp.experts_per_tok), jnp.float32),
+        shape((held, d, f), jnp.float32), shape((held, d, f), jnp.float32),
+        shape((held, f, d), jnp.float32), shape((tokens, sp.experts_per_tok), jnp.int32),
+        shape((tokens,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and " conditional(" in text
