@@ -22,7 +22,9 @@ num_experts`` when the router is balanced; its row buffer has
 ``row_capacity`` = twice that, in whole row tiles of the grouped product, at
 most ``n``.  A step whose held rows fit takes the row path: the first
 ``capacity`` assignments in dispatch order are gathered, computed, weighed in
-float32 and added up per token, every array ``capacity`` rows long.  A step
+float32 and added up per token, every array ``capacity`` rows long, every
+move one op over the whole buffer (``token_sums`` says what its way back
+moves, and why it is written by hand).  A step
 whose rows do not fit takes the slot path under ``lax.cond`` in the same
 compiled step: buffers of all ``n`` slots, the order a permutation, so both
 gathers (tokens to rows, rows back to the tokens' slots) go back as gathers
@@ -193,6 +195,41 @@ def _held_rows_bwd(res, d_rows):
 held_rows.defvjp(_held_rows_fwd, _held_rows_bwd)
 
 
+@jax.custom_vjp
+def token_sums(out_rows, weights, token, slot, valid):
+    """``out[t] = sum of weights[t, j] * out_rows[r]`` over the valid rows of
+    a compact buffer, row r that of assignment ``slot[r]`` = t x top-k + j
+    (``token[r]`` = t), weighed and added per token in float32.
+
+    The way back is written by hand for the bytes it moves: jax's own
+    transpose of the scatter-add widens the (tokens, d) cotangent to float32
+    and gathers float32 rows; here the rows are gathered as they are and
+    widened after, half the bytes, and the same numbers."""
+    weight = weights.reshape(-1).at[slot].get(unique_indices=True)
+    weighed = jnp.where(valid[:, None], out_rows, 0).astype(jnp.float32) * weight[:, None]
+    out = jnp.zeros((weights.shape[0], out_rows.shape[1]), jnp.float32).at[token].add(weighed)
+    return out.astype(out_rows.dtype)
+
+
+def _token_sums_fwd(out_rows, weights, *index):
+    return token_sums(out_rows, weights, *index), (out_rows, weights, index)
+
+
+def _token_sums_bwd(res, d_out):
+    out_rows, weights, (token, slot, valid) = res
+    weight = weights.reshape(-1).at[slot].get(unique_indices=True)
+    d_weighed = d_out[token].astype(jnp.float32)
+    d_out_rows = jnp.where(valid[:, None], d_weighed * weight[:, None], 0).astype(out_rows.dtype)
+    d_weight = jnp.sum(
+        d_weighed * jnp.where(valid[:, None], out_rows, 0).astype(jnp.float32), axis=1)
+    d_weights = jnp.zeros((weights.size,), jnp.float32).at[slot].add(
+        d_weight, unique_indices=True)
+    return (d_out_rows, d_weights.reshape(weights.shape).astype(weights.dtype)) + (None,) * 3
+
+
+token_sums.defvjp(_token_sums_fwd, _token_sums_bwd)
+
+
 def _experts(rows, w1, w3, w2, group_sizes):
     dtype = rows.dtype
     both = grouped_matmul(rows, jnp.concatenate([w1, w3], axis=-1).astype(dtype), group_sizes)
@@ -231,11 +268,7 @@ def _row_dispatch(x, index):
 
 
 def _row_combine(out_rows, weights, index):
-    token, slot, valid = index
-    weight = weights.reshape(-1).at[slot].get(unique_indices=True)
-    weighed = jnp.where(valid[:, None], out_rows, 0).astype(jnp.float32) * weight[:, None]
-    out = jnp.zeros((weights.shape[0], out_rows.shape[1]), jnp.float32).at[token].add(weighed)
-    return out.astype(out_rows.dtype)
+    return token_sums(out_rows, weights, *index)
 
 
 def _path(capacity):

@@ -10,10 +10,19 @@ blockwise lax form) and the grouped expert product forward + backward
 eighth of the row buffer in groups; and the routed layer's ways between
 tokens and expert rows (``--only routed``): the slot gather against a
 scatter-add of the rows (expert order, and token order with the sorted hint)
-at a row buffer of a quarter of the slots and of all of them, the index each
-needs (the sort of all slots against a token-major compaction that sorts the
-buffer's keys only), and ``models/lm/moe.py``'s whole layer both ways at both
-capacities.  A tool for PERF.md section 6; no benchmark metric reads it.
+at the rule's row buffer and at all the slots, the index each needs (the sort
+of all slots against a token-major compaction that sorts the buffer's keys
+only), the combine's way back gathering float32 rows (jax's own transpose)
+against bfloat16 rows widened after (``moe.token_sums``), and what was tried
+for the row path's moves and not taken (PERF.md section 6, PR 32; what a
+Pallas row mover has to beat): each move as a loop over the tiles that hold
+rows (tiles of 512, 1,024 and 2,048; the rows the routing holds, and a full
+buffer), a tile's scatter-add with its rows sorted inside the trip, and a
+whole buffer's scatter-add with the compiler's sort done by hand (one sort,
+bfloat16 rows permuted); then the whole layer both ways at both capacities
+and with each of those in the program's place (``--only layer``: those rows
+alone, 5-6 min a shape: seven programs compile).  A tool for PERF.md section
+6; no benchmark metric reads it.
 
 With ``--window`` the attention rows are a sliding layer's (``LocalMask``,
 the query's own key and the ``window - 1`` before it): blocks of 256, 512 and
@@ -57,13 +66,13 @@ def main() -> int:
     ap.add_argument("--expert-width", type=int, default=1536)
     ap.add_argument("--experts-held", type=int, default=8)
     ap.add_argument("--top-k", type=int, default=4)
-    ap.add_argument("--only", choices=("attention", "experts", "routed"), default=None)
+    ap.add_argument("--only", choices=("attention", "experts", "routed", "layer"), default=None)
     args = ap.parse_args()
 
     sys.path.insert(0, ".")
     print(json.dumps({"device": jax.devices()[0].device_kind}), flush=True)
     for part in ("attention", "experts", "routed"):
-        if args.only in (None, part):
+        if args.only in (None, part) or (part, args.only) == ("routed", "layer"):
             {"attention": attention, "experts": experts, "routed": routed}[part](args)
     return 0
 
@@ -319,102 +328,264 @@ def routed(args) -> None:
     order = jnp.argsort(sort_key, stable=True).astype(jnp.int32)
     total = int(jnp.sum(is_held))
 
-    def report(name, rows, fn, *operands):
+    def report(name, rows, fn, *operands, **fields):
+        row = dict({"routed": name, "rows": rows, "held_rows": total}, **fields)
         try:
-            print(json.dumps({"routed": name, "rows": rows, "held_rows": total,
-                              "ms": timed(jax.jit(fn), *operands)}), flush=True)
+            print(json.dumps(dict(row, ms=timed(jax.jit(fn), *operands))), flush=True)
         except Exception as exc:  # noqa: BLE001
-            print(json.dumps({"routed": name, "rows": rows, "error": repr(exc)[:300]}),
-                  flush=True)
+            print(json.dumps(dict(row, error=repr(exc)[:300])), flush=True)
 
-    # the index: what each way needs before a row moves
-    report("argsort_keys", n, lambda a: jnp.argsort(a, stable=True), sort_key)
-    report("bincount", n, lambda a: jnp.bincount(a, length=held + 1), sort_key)
-    report("count_by_compare", n,
-           lambda a: jnp.sum(a[:, None] == jnp.arange(held + 1)[None, :], axis=0), sort_key)
-    report("inverse_permutation", n, lambda o: jnp.zeros((n,), jnp.int32).at[o].set(
-        jnp.arange(n, dtype=jnp.int32), unique_indices=True), order)
+    capacity = moe.row_capacity(n, held, num_experts)
+    d_out = jax.random.normal(jax.random.fold_in(key, 4), (tokens, d), jnp.bfloat16)
 
-    for rows in (n // 4, n):
-        def compaction(is_held, idx, rows=rows):
-            flat = is_held.reshape(n)
-            slot = jnp.nonzero(flat, size=rows, fill_value=n - 1)[0].astype(jnp.int32)
-            keys = jnp.where(jnp.arange(rows) < jnp.sum(flat), idx.reshape(n)[slot], held)
-            by_expert = jnp.argsort(keys, stable=True).astype(jnp.int32)
-            back = jnp.zeros((rows,), jnp.int32).at[by_expert].set(
-                jnp.arange(rows, dtype=jnp.int32), unique_indices=True)
-            return slot[by_expert], back
+    if args.only != "layer":
+        # the index: what each way needs before a row moves
+        report("argsort_keys", n, lambda a: jnp.argsort(a, stable=True), sort_key)
+        report("bincount", n, lambda a: jnp.bincount(a, length=held + 1), sort_key)
+        report("count_by_compare", n,
+               lambda a: jnp.sum(a[:, None] == jnp.arange(held + 1)[None, :], axis=0), sort_key)
+        report("inverse_permutation", n, lambda o: jnp.zeros((n,), jnp.int32).at[o].set(
+            jnp.arange(n, dtype=jnp.int32), unique_indices=True), order)
 
-        report("token_major_compaction_and_sort", rows, compaction, is_held, idx)
+        for rows in (capacity, n):
+            def compaction(is_held, idx, rows=rows):
+                flat = is_held.reshape(n)
+                slot = jnp.nonzero(flat, size=rows, fill_value=n - 1)[0].astype(jnp.int32)
+                keys = jnp.where(jnp.arange(rows) < jnp.sum(flat), idx.reshape(n)[slot], held)
+                by_expert = jnp.argsort(keys, stable=True).astype(jnp.int32)
+                back = jnp.zeros((rows,), jnp.int32).at[by_expert].set(
+                    jnp.arange(rows, dtype=jnp.int32), unique_indices=True)
+                return slot[by_expert], back
 
-        valid = jnp.arange(rows) < total
-        by_expert = order[:rows] // k                       # tokens in expert order
-        by_token = jnp.sort(jnp.where(valid, by_expert, tokens - 1))     # and in token order
-        position = jnp.zeros((n,), jnp.int32).at[order].set(
-            jnp.arange(n, dtype=jnp.int32), unique_indices=True)
-        position = jnp.where(is_held, jnp.minimum(position, rows - 1).reshape(tokens, k), 0)
-        out_rows = jax.random.normal(jax.random.fold_in(key, 2), (rows, d), jnp.bfloat16)
-        row_weights = jax.random.uniform(jax.random.fold_in(key, 3), (rows,))
-        d_out = jax.random.normal(jax.random.fold_in(key, 4), (tokens, d), jnp.bfloat16)
+            report("token_major_compaction_and_sort", rows, compaction, is_held, idx)
 
-        report("gather_rows_of_tokens", rows,
-               lambda x, t, v: jnp.where(v[:, None], x[t], 0), x, by_expert, valid)
+            valid = jnp.arange(rows) < total
+            by_expert = order[:rows] // k                       # tokens in expert order
+            by_token = jnp.sort(jnp.where(valid, by_expert, tokens - 1))     # and in token order
+            position = jnp.zeros((n,), jnp.int32).at[order].set(
+                jnp.arange(n, dtype=jnp.int32), unique_indices=True)
+            position = jnp.where(is_held, jnp.minimum(position, rows - 1).reshape(tokens, k), 0)
+            out_rows = jax.random.normal(jax.random.fold_in(key, 2), (rows, d), jnp.bfloat16)
+            row_weights = jax.random.uniform(jax.random.fold_in(key, 3), (rows,))
 
-        def slot_gather_combine(out_rows, weights, position, is_held):
-            picked = jnp.where(is_held[..., None], out_rows[position], 0)
-            return jnp.sum(picked.astype(jnp.float32) * weights[..., None], axis=1).astype(
-                out_rows.dtype)
+            report("gather_rows_of_tokens", rows,
+                   lambda x, t, v: jnp.where(v[:, None], x[t], 0), x, by_expert, valid)
 
-        def scatter_combine(out_rows, row_weights, token, valid, sorted_=False, drop=False):
-            weighed = jnp.where(valid[:, None], out_rows, 0).astype(jnp.float32) \
-                * row_weights[:, None]
-            if drop:        # the rows after the last group go nowhere
-                token = jnp.where(valid, token, tokens)
-            return jnp.zeros((tokens, d), jnp.float32).at[token].add(
-                weighed, indices_are_sorted=sorted_, mode="drop").astype(out_rows.dtype)
+            def slot_gather_combine(out_rows, weights, position, is_held):
+                picked = jnp.where(is_held[..., None], out_rows[position], 0)
+                return jnp.sum(picked.astype(jnp.float32) * weights[..., None], axis=1).astype(
+                    out_rows.dtype)
 
-        report("combine_slot_gather", rows, slot_gather_combine,
-               out_rows, weights, position, is_held)
-        report("combine_scatter_add_expert_order", rows, scatter_combine,
-               out_rows, row_weights, by_expert, valid)
-        report("combine_scatter_add_expert_order_invalid_dropped", rows,
-               functools.partial(scatter_combine, drop=True),
-               out_rows, row_weights, by_expert, valid)
-        report("combine_scatter_add_bfloat16_rows", rows,
-               lambda r, t: jnp.zeros((tokens, d), r.dtype).at[t].add(r), out_rows, by_expert)
-        report("combine_scatter_add_token_order_sorted", rows,
-               functools.partial(scatter_combine, sorted_=True),
-               out_rows, row_weights, by_token, valid)
-        # dispatch's way back: a token's rows added up in float32
-        report("dispatch_back_slot_gather", rows, lambda r, p, h: jnp.sum(
-            jnp.where(h[..., None], r[p], 0).astype(jnp.float32), axis=1).astype(r.dtype),
-            out_rows, position, is_held)
-        report("dispatch_back_scatter_add", rows, lambda r, t, v: jnp.zeros(
-            (tokens, d), jnp.float32).at[t].add(
-                jnp.where(v[:, None], r, 0).astype(jnp.float32)).astype(r.dtype),
-            out_rows, by_expert, valid)
-        report("permute_rows", rows, lambda r, p: r[p], out_rows,
-               jax.random.permutation(key, rows))
+            def scatter_combine(out_rows, row_weights, token, valid, sorted_=False, drop=False):
+                weighed = jnp.where(valid[:, None], out_rows, 0).astype(jnp.float32) \
+                    * row_weights[:, None]
+                if drop:        # the rows after the last group go nowhere
+                    token = jnp.where(valid, token, tokens)
+                return jnp.zeros((tokens, d), jnp.float32).at[token].add(
+                    weighed, indices_are_sorted=sorted_, mode="drop").astype(out_rows.dtype)
 
-    # the layer as the program runs it, forward and backward, at both capacities
+            report("combine_slot_gather", rows, slot_gather_combine,
+                   out_rows, weights, position, is_held)
+            report("combine_scatter_add_expert_order", rows, scatter_combine,
+                   out_rows, row_weights, by_expert, valid)
+            report("combine_scatter_add_expert_order_invalid_dropped", rows,
+                   functools.partial(scatter_combine, drop=True),
+                   out_rows, row_weights, by_expert, valid)
+            report("combine_scatter_add_bfloat16_rows", rows,
+                   lambda r, t: jnp.zeros((tokens, d), r.dtype).at[t].add(r), out_rows, by_expert)
+            report("combine_scatter_add_token_order_sorted", rows,
+                   functools.partial(scatter_combine, sorted_=True),
+                   out_rows, row_weights, by_token, valid)
+            # dispatch's way back: a token's rows added up in float32
+            report("dispatch_back_slot_gather", rows, lambda r, p, h: jnp.sum(
+                jnp.where(h[..., None], r[p], 0).astype(jnp.float32), axis=1).astype(r.dtype),
+                out_rows, position, is_held)
+            report("dispatch_back_scatter_add", rows, lambda r, t, v: jnp.zeros(
+                (tokens, d), jnp.float32).at[t].add(
+                    jnp.where(v[:, None], r, 0).astype(jnp.float32)).astype(r.dtype),
+                out_rows, by_expert, valid)
+            report("permute_rows", rows, lambda r, p: r[p], out_rows,
+                   jax.random.permutation(key, rows))
+
+            def combine_back(d_out, out_rows, row_weights, token, valid, gathered=jnp.float32):
+                d_weighed = d_out.astype(gathered)[token].astype(jnp.float32)
+                kept = jnp.where(valid[:, None], out_rows, 0).astype(jnp.float32)
+                return (jnp.where(valid[:, None], d_weighed * row_weights[:, None], 0).astype(
+                    out_rows.dtype), jnp.sum(d_weighed * kept, axis=1))
+
+            report("combine_back_gather", rows, combine_back,
+                   d_out, out_rows, row_weights, by_expert, valid)
+            report("combine_back_gather_bfloat16_rows", rows,
+                   functools.partial(combine_back, gathered=jnp.bfloat16),
+                   d_out, out_rows, row_weights, by_expert, valid)
+            if rows != capacity:
+                continue
+            report("scatter_add_sorted_by_hand", rows,
+                   lambda r, t, v: sum_sorted_by_hand(r, None, t, v, tokens).astype(r.dtype),
+                   out_rows, by_expert, valid)
+            # each move as a loop over the tiles that hold rows, the trip count an
+            # argument as in a step: the rows the routing holds, and a full buffer
+            for tile in (512, 1024, 2048):
+                for moved in (total, rows):
+                    loop = {"tile": tile, "rows_moved": moved}
+                    moved = jnp.int32(moved)
+                    report("gather_rows_of_tokens_loop", rows,
+                           functools.partial(gather_loop, tile), x, by_expert, moved, **loop)
+                    report("dispatch_back_scatter_add_loop", rows,
+                           lambda r, t, m, tile=tile: sum_loop(
+                               tile, r, None, t, m, tokens).astype(r.dtype),
+                           out_rows, by_expert, moved, **loop)
+                    report("combine_scatter_add_loop", rows,
+                           lambda r, w, t, m, tile=tile: sum_loop(
+                               tile, r, w, t, m, tokens).astype(r.dtype),
+                           out_rows, row_weights, by_expert, moved, **loop)
+                    if tile == 1024:
+                        report("dispatch_back_scatter_add_loop_sorted_in_the_trip", rows,
+                               lambda r, t, m, tile=tile: sum_loop(
+                                   tile, r, None, t, m, tokens, sort=True).astype(r.dtype),
+                               out_rows, by_expert, moved, **loop)
+
+    # the layer forward and backward: as the program runs it at both capacities,
+    # then with each alternative in the place of the row path's moves
     w1 = jax.random.normal(jax.random.fold_in(key, 5), (held, d, f)) * 0.02
     w3 = jax.random.normal(jax.random.fold_in(key, 6), (held, d, f)) * 0.02
     w2 = jax.random.normal(jax.random.fold_in(key, 7), (held, f, d)) * 0.02
-    for capacity in (moe.row_capacity(n, held, num_experts), n):
-        def layer(x, weights, w1, w3, w2, capacity=capacity):
+    program = moe._row_dispatch, moe._row_combine
+    for rows, moves, (dispatch, combine) in [
+            (capacity, "program", program), (n, "program", program),
+            (capacity, "combine_back_by_jax", (moe._row_dispatch, combine_transposed_by_jax)),
+            (capacity, "sums_sorted_by_hand", sorted_by_hand_moves()),
+    ] + [(capacity, f"loops_of_{tile}", loop_moves(tile)) for tile in (512, 1024, 2048)]:
+        moe._row_dispatch, moe._row_combine = dispatch, combine    # read when a path is traced
+        moe._forward.clear_cache()          # and a path is traced once a capacity
+        moe._backward.clear_cache()
+
+        def layer(x, weights, w1, w3, w2, rows=rows):
             out, _, fell_back = moe.held_experts_ffn(
-                x, idx, weights, live, w1, w3, w2, spec, capacity)
+                x, idx, weights, live, w1, w3, w2, spec, rows)
             return jnp.sum((out * d_out).astype(jnp.float32)), fell_back
 
         def forward(*a):
             return layer(*a)[0]
 
-        report("layer_forward", capacity, forward, x, weights, w1, w3, w2)
-        report("layer_both_ways", capacity,
-               jax.grad(layer, argnums=(0, 1, 2, 3, 4), has_aux=True), x, weights, w1, w3, w2)
+        report("layer_forward", rows, forward, x, weights, w1, w3, w2, moves=moves)
+        report("layer_both_ways", rows,
+               jax.grad(layer, argnums=(0, 1, 2, 3, 4), has_aux=True), x, weights, w1, w3, w2,
+               moves=moves)
+    moe._row_dispatch, moe._row_combine = program
+    moe._forward.clear_cache()
+    moe._backward.clear_cache()
     print(json.dumps({"routed": "fallback_at_capacity", "value": np.asarray(
-        moe.held_experts_ffn(x, idx, weights, live, w1, w3, w2, spec,
-                             moe.row_capacity(n, held, num_experts))[2]).item()}), flush=True)
+        moe.held_experts_ffn(x, idx, weights, live, w1, w3, w2, spec, capacity)[2]).item()}),
+        flush=True)
+
+
+# What PR 32 tried for the row path's moves and did not take.  ``index`` is
+# ``moe._row_index``'s: (token, slot, valid) of each row of the buffer.
+
+def combine_transposed_by_jax(out_rows, weights, index):
+    """``moe.token_sums`` with the way back jax derives: float32 rows gathered."""
+    from rt1_tpu.models.lm import moe
+
+    return moe.token_sums.__wrapped__(out_rows, weights, *index)
+
+
+def sum_sorted_by_hand(rows, scale, token, valid, tokens):
+    """``out[t]`` = the float32 sum of ``rows[r] * scale[r]`` over the valid
+    rows of token t, the compiler's rewrite of a scatter-add done by hand: one
+    sort of the rows by token, the rows permuted as they are (bfloat16), and a
+    scatter-add told that its indices are sorted."""
+    at, by_token = lax.sort(
+        (jnp.where(valid, token, tokens), jnp.arange(token.shape[0], dtype=jnp.int32)),
+        num_keys=1)
+    kept = jnp.where((at < tokens)[:, None], rows[by_token], 0).astype(jnp.float32)
+    if scale is not None:
+        kept = kept * scale[by_token][:, None]
+    return jnp.zeros((tokens, rows.shape[1]), jnp.float32).at[at].add(
+        kept, indices_are_sorted=True, mode="drop")
+
+
+def sum_loop(tile, rows, scale, token, moved, tokens, sort=False):
+    """The same sum over the first ``moved`` rows, ``tile`` rows a trip of a
+    loop whose carry is the (tokens, d) accumulator; ``sort``: a tile's rows
+    in the order of their tokens."""
+    def trip(i, out):
+        at = lax.dynamic_slice_in_dim(token, i * tile, tile)
+        row = i * tile + jnp.arange(tile)
+        kept = lax.dynamic_slice_in_dim(rows, i * tile, tile)
+        weight = None if scale is None else lax.dynamic_slice_in_dim(scale, i * tile, tile)
+        if sort:
+            at, by_token = lax.sort((at, jnp.arange(tile)), num_keys=1)
+            row, kept = row[by_token], kept[by_token]
+            weight = None if weight is None else weight[by_token]
+        kept = jnp.where((row < moved)[:, None], kept, 0).astype(jnp.float32)
+        if weight is not None:
+            kept = kept * weight[:, None]
+        return out.at[at].add(kept, indices_are_sorted=sort)
+
+    return lax.fori_loop(0, (moved + tile - 1) // tile, trip,
+                         jnp.zeros((tokens, rows.shape[1]), jnp.float32))
+
+
+def gather_loop(tile, x, token, moved):
+    """``rows[r] = x[token[r]]`` for the first ``moved`` rows, zero after
+    them, ``tile`` rows a trip into a buffer that starts as zeros."""
+    def trip(i, rows):
+        valid = i * tile + jnp.arange(tile) < moved
+        picked = jnp.where(valid[:, None], x[lax.dynamic_slice_in_dim(token, i * tile, tile)], 0)
+        return lax.dynamic_update_slice_in_dim(rows, picked, i * tile, 0)
+
+    return lax.fori_loop(0, (moved + tile - 1) // tile, trip,
+                         jnp.zeros((token.shape[0], x.shape[1]), x.dtype))
+
+
+def _moves(sum_per_token, gather):
+    """(dispatch, combine) for ``moe._path``'s row path from a sum per token
+    ``(rows, scale, token, valid, tokens)`` and a gather ``(x, token, valid)``:
+    both ways of both written out, the combine's way back the program's."""
+    @jax.custom_vjp
+    def dispatch(x, index):
+        return gather(x, index[0], index[2])
+
+    def dispatch_back(res, d_rows):
+        (token, _, valid), tokens = res
+        return sum_per_token(d_rows, None, token, valid, tokens).astype(d_rows.dtype), None
+
+    dispatch.defvjp(lambda x, index: (dispatch(x, index), (index, x.shape[0])), dispatch_back)
+
+    @jax.custom_vjp
+    def combine(out_rows, weights, index):
+        token, slot, valid = index
+        weight = weights.reshape(-1).at[slot].get(unique_indices=True)
+        return sum_per_token(out_rows, weight, token, valid, weights.shape[0]).astype(
+            out_rows.dtype)
+
+    def combine_back(res, d_out):
+        from rt1_tpu.models.lm import moe
+
+        out_rows, weights, index = res
+        return moe._token_sums_bwd((out_rows, weights, index), d_out)[:2] + (None,)
+
+    combine.defvjp(lambda r, w, index: (combine(r, w, index), (r, w, index)), combine_back)
+    return dispatch, combine
+
+
+def sorted_by_hand_moves():
+    """Both scatter-adds sorted by hand, the gather the program's."""
+    return _moves(sum_sorted_by_hand,
+                  lambda x, token, valid: jnp.where(valid[:, None], x[token], 0))
+
+
+def loop_moves(tile):
+    """ISSUE 32's design: the gather and both scatter-adds ``tile`` rows a
+    trip, the trips the tiles that hold rows."""
+    def held(valid):
+        return jnp.sum(valid, dtype=jnp.int32)
+
+    return _moves(
+        lambda rows, scale, token, valid, tokens: sum_loop(
+            tile, rows, scale, token, held(valid), tokens),
+        lambda x, token, valid: gather_loop(tile, x, token, held(valid)))
 
 
 if __name__ == "__main__":

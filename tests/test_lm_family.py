@@ -215,6 +215,108 @@ def test_the_row_path_is_the_slot_path(monkeypatch, room):
     assert float(jnp.max(jnp.abs(flat_got["router/kernel"]))) > 0.0
 
 
+def _poisoned_grouped_matmul():
+    """``moe.grouped_matmul`` as megablox leaves it on the chip: the rows after
+    the last group are not computed, and here hold NaN both ways
+    (``lax.ragged_dot`` on the CPU writes zeros there, which hides a read)."""
+    def live_rows(rows, group_sizes):
+        return (jnp.arange(rows.shape[0]) < jnp.sum(group_sizes))[:, None]
+
+    @jax.custom_vjp
+    def product(rows, stack, group_sizes):
+        live = live_rows(rows, group_sizes)
+        out = jax.lax.ragged_dot(jnp.where(live, rows, 0), stack, group_sizes)
+        return jnp.where(live, out, jnp.nan)
+
+    def forward(rows, stack, group_sizes):
+        return product(rows, stack, group_sizes), (rows, stack, group_sizes)
+
+    def backward(res, d_out):
+        rows, stack, group_sizes = res
+        live = live_rows(rows, group_sizes)
+        _, back = jax.vjp(lambda r, s: jax.lax.ragged_dot(r, s, group_sizes),
+                          jnp.where(live, rows, 0), stack)
+        d_rows, d_stack = back(jnp.where(live, d_out, 0))
+        return jnp.where(live, d_rows, jnp.nan), d_stack, None
+
+    product.defvjp(forward, backward)
+    return product
+
+
+# rows held in a buffer of 64 rows: none, one, a few, one short of all, all
+@pytest.mark.parametrize("rows_held", [0, 1, 16, 63, 64])
+def test_the_row_path_reads_no_row_after_the_last_held(monkeypatch, rows_held):
+    """The row path (the rows after the last held row unspecified: NaN here,
+    both ways) against the slot path with no branch: the output and the
+    gradients of x, the three stacks and the router."""
+    monkeypatch.setattr(moe, "grouped_matmul", _poisoned_grouped_matmul())
+    config = small_config()
+    spec = _routed_layer(config, (4, 4)).spec
+    tokens, k, d = 64, spec.experts_per_tok, spec.hidden_size
+    n, capacity = tokens * k, 64
+    assert capacity < n
+    params = _share(_full_layer_params(config), 4, 4)
+    stacks = [params["experts"][name]["kernel"] for name in ("w1", "w3", "w2")]
+    x = jax.random.normal(jax.random.PRNGKey(3), (tokens, d))
+    probe = jax.random.normal(jax.random.PRNGKey(4), (tokens, d))
+    # every assignment to an absent expert, then ``rows_held`` of them moved to
+    # held experts 4..7: slots 0, 3, 6, ... so that some tokens hold two rows
+    idx = np.tile(np.arange(8, 8 + k), (tokens, 1))
+    flat = idx.reshape(-1)
+    chosen = (np.arange(rows_held) * 3) % n
+    flat[chosen] = 4 + np.arange(rows_held) % 4
+    idx = jnp.asarray(flat.reshape(tokens, k), jnp.int32)
+    live = jnp.ones((tokens,), bool)
+
+    def both_ways(capacity):
+        def loss(x, router, w1, w3, w2):
+            scores = jax.nn.sigmoid(x @ router)
+            weights = jnp.take_along_axis(scores, idx, axis=-1)
+            out, group_sizes, fell_back = moe.held_experts_ffn(
+                x, idx, weights, live, w1, w3, w2, spec, capacity)
+            return jnp.sum(out * probe), (out, group_sizes, fell_back)
+
+        (_, aux), grads = jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4), has_aux=True)(
+            x, params["router"]["kernel"], *stacks)
+        return aux, grads
+
+    with jax.default_matmul_precision("highest"):
+        (want, _, _), want_grads = both_ways(n)
+        (got, group_sizes, fell_back), got_grads = both_ways(capacity)
+    assert int(jnp.sum(group_sizes)) == rows_held and not bool(fell_back)
+    for what, a, b in [("out", got, want)] + [
+            (name, a, b) for name, a, b in zip(
+                ("x", "router", "w1", "w3", "w2"), got_grads, want_grads)]:
+        a, b = np.asarray(a), np.asarray(b)
+        assert np.isfinite(a).all() and np.isfinite(b).all(), what
+        assert float(np.max(np.abs(a - b))) <= 1e-6 * float(np.max(np.abs(b))) + 1e-30, what
+        assert rows_held == 0 or float(np.max(np.abs(b))) > 0.0, what
+
+
+def test_the_combine_goes_back_as_jax_would_take_it():
+    """``token_sums``'s hand-written way back (bfloat16 rows gathered, widened
+    after) against jax's own transpose of the same sums, in bfloat16 as the
+    step runs it: the same numbers to the last bit."""
+    tokens, k, d, capacity = 32, 4, 16, 48
+    key = jax.random.PRNGKey(5)
+    out_rows = jax.random.normal(key, (capacity, d), jnp.bfloat16)
+    weights = jax.random.uniform(jax.random.fold_in(key, 1), (tokens, k))
+    d_out = jax.random.normal(jax.random.fold_in(key, 2), (tokens, d), jnp.bfloat16)
+    slot = jax.random.permutation(jax.random.fold_in(key, 3), tokens * k)[:capacity]
+    index = (slot // k, slot, jnp.arange(capacity) < 40)
+
+    def plain(out_rows, weights):
+        return moe.token_sums.__wrapped__(out_rows, weights, *index)
+
+    want, back = jax.vjp(plain, out_rows, weights)
+    got, hand = jax.vjp(lambda r, w: moe.token_sums(r, w, *index), out_rows, weights)
+    assert np.array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
+    for a, b in zip(hand(d_out), back(d_out)):
+        assert a.dtype == b.dtype
+        assert np.array_equal(np.asarray(a, np.float32), np.asarray(b, np.float32))
+    assert float(jnp.max(jnp.abs(hand(d_out)[1]))) > 0.0
+
+
 @pytest.mark.parametrize("n, held, experts, rows", [
     (2 * 8192 * 4, 8, 64, 16384),       # the token cell: a quarter of the slots
     (2 * 512 * 4, 4, 16, 2048),         # twice the balanced share
