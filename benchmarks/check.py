@@ -12,6 +12,15 @@ by round-off alone and are left out of the change.
 
 The limits are in the configuration's file, set from readings as PERF.md
 section 2 records.
+
+The reference's three steps hold four copies of the parameters' size on the
+device, the trained program's own 16 B a parameter, beside the loss's
+temporaries: a step is two programs, the loss's gradient (the masters read,
+one gradient written) and Adam's update, to which masters and both moments
+are donated, so it runs in place.  (One program with the masters
+donated to it holds more: the compiler copies what the reference's loops read
+before it lets the update overwrite it; PERF.md section 2.)  On the host every
+norm is taken a leaf at a time.
 """
 
 from __future__ import annotations
@@ -39,12 +48,22 @@ def adam_mu(opt_state):
     return found[0].mu
 
 
-def leaf_norms(tree) -> Dict[str, float]:
+def leaf_norms(tree, *more, of=None) -> Dict[str, float]:
+    """Norm, in float64, of every leaf of ``tree``; with ``of``, of
+    ``of(leaf, *the same leaf of each further tree)``.  A leaf at a time: no
+    float64 copy of a whole tree is ever held."""
     import flax
 
-    flat = flax.traverse_util.flatten_dict(tree)
-    return {"/".join(map(str, k)): float(np.linalg.norm(np.asarray(v, np.float64)))
-            for k, v in flat.items()}
+    flats = [flax.traverse_util.flatten_dict(t) for t in (tree, *more)]
+    for flat in flats:      # a device's leaves start for the host together
+        for v in flat.values():
+            if hasattr(v, "copy_to_host_async"):
+                v.copy_to_host_async()
+    out = {}
+    for k, v in flats[0].items():
+        leaf = v if of is None else of(v, *(f[k] for f in flats[1:]))
+        out["/".join(map(str, k))] = float(np.linalg.norm(np.asarray(leaf, np.float64)))
+    return out
 
 
 def leaf_gaps(program: Dict[str, float], reference: Dict[str, float],
@@ -62,9 +81,9 @@ _STEPS: Dict[Any, Any] = {}
 
 
 def _reference_step(ref, sz, lr: float, prec: str):
-    """One jitted Adam step of the reference, built once per process for a
-    (reference, sizes, precision): a tool that reads many seeds compiles it
-    once."""
+    """(gradient, update): the two jitted programs of one Adam step of the
+    reference, built once per process for a (reference, sizes, precision): a
+    tool that reads many seeds compiles them once."""
     import jax
     import jax.numpy as jnp
 
@@ -72,10 +91,13 @@ def _reference_step(ref, sz, lr: float, prec: str):
     if key in _STEPS:
         return _STEPS[key]
 
-    def step(params, batch_stats, mu, nu, batch, rng, count):
+    def gradient(params, batch_stats, batch, rng):
         (loss, batch_stats), grads = jax.value_and_grad(
             lambda p: ref.loss_fn(p, batch_stats, batch, rng, sz, prec), has_aux=True
         )(params)
+        return loss, batch_stats, grads
+
+    def update(params, mu, nu, grads, count):
         mu = jax.tree.map(lambda m, g: ADAM_B1 * m + (1 - ADAM_B1) * g, mu, grads)
         nu = jax.tree.map(lambda v, g: ADAM_B2 * v + (1 - ADAM_B2) * g * g, nu, grads)
         c1, c2 = 1 - ADAM_B1 ** count, 1 - ADAM_B2 ** count
@@ -83,33 +105,44 @@ def _reference_step(ref, sz, lr: float, prec: str):
             lambda p, m, v: p - lr * (m / c1) / (jnp.sqrt(v / c2) + ADAM_EPS),
             params, mu, nu,
         )
-        return params, batch_stats, mu, nu, loss, grads
+        return params, mu, nu
 
-    _STEPS[key] = jax.jit(step, donate_argnums=(2, 3))
+    _STEPS[key] = (jax.jit(gradient), jax.jit(update, donate_argnums=(0, 1, 2)))
     return _STEPS[key]
 
 
 def follow(ref, sz, params, batch_stats, batches, keys, lr: float, prec: str):
-    """Three plain Adam steps of the reference.  Returns the losses, the
-    first gradient and the change of the parameters (all as numpy trees)."""
+    """Three plain Adam steps of the reference.  Returns the losses, the leaf
+    norms of the first gradient and of the parameters' change, and the seconds
+    spent waiting for the steps and reading and reducing trees on the host.
+    ``params`` is donated to the first update: a caller that follows twice
+    makes the weights twice (they come from the seed)."""
     import jax
     import jax.numpy as jnp
 
-    step = _reference_step(ref, sz, lr, prec)
-    start = jax.device_get(params)
+    gradient, update = _reference_step(ref, sz, lr, prec)
+    clock = {"steps": 0.0, "host": 0.0}
+
+    def timed(part, fn, *args):
+        t0 = time.perf_counter()
+        out = fn(*args)
+        clock[part] += time.perf_counter() - t0
+        return out
+
+    start = timed("host", jax.device_get, params)
     # one program, not a zeros_like per leaf shape
     mu, nu = jax.jit(lambda t: (jax.tree.map(jnp.zeros_like, t),) * 2)(params)
     losses, grad1 = [], None
     for i, (batch, key) in enumerate(zip(batches, keys)):
-        params, batch_stats, mu, nu, loss, grads = step(
-            params, batch_stats, mu, nu, batch, key, jnp.float32(i + 1)
-        )
-        losses.append(float(loss))
+        loss, batch_stats, grads = gradient(params, batch_stats, batch, key)
+        losses.append(timed("steps", float, loss))
         if i == 0:
-            grad1 = jax.device_get(grads)
+            grad1 = timed("host", leaf_norms, grads)
+        params, mu, nu = update(params, mu, nu, grads, jnp.float32(i + 1))
         del grads
-    delta = jax.tree.map(np.subtract, jax.device_get(params), start)
-    return losses, grad1, delta
+    timed("steps", jax.block_until_ready, params)
+    delta = timed("host", lambda: leaf_norms(params, start, of=np.subtract))
+    return losses, grad1, delta, clock
 
 
 def model_batch(host_batch) -> Tuple[Dict[str, Any], Dict[str, Any]]:
@@ -120,7 +153,9 @@ def model_batch(host_batch) -> Tuple[Dict[str, Any], Dict[str, Any]]:
     return obs, dict(host_batch["actions"])
 
 
-def reference_readings(config_file, abstract, seed, batches, prec: str, log) -> Dict[str, Any]:
+def reference_readings(config_file, abstract, seed, batches, prec: str, log,
+                       as_model=model_batch) -> Dict[str, Any]:
+    """``as_model`` turns a batch of the feed into what the reference takes."""
     import jax
 
     from benchmarks import program, weights
@@ -132,12 +167,14 @@ def reference_readings(config_file, abstract, seed, batches, prec: str, log) -> 
     base = weights.seed_key(seed)
     keys = [jax.random.fold_in(base, i) for i in range(len(batches))]
     t0 = time.perf_counter()
-    losses, grad1, delta = follow(
-        ref, sz, params, batch_stats, [model_batch(b) for b in batches], keys,
+    losses, grad1, delta, clock = follow(
+        ref, sz, params, batch_stats, [as_model(b) for b in batches], keys,
         float(config_file["overrides"]["learning_rate"]), prec,
     )
-    log(f"reference ({prec}): {len(batches)} steps in {time.perf_counter() - t0:.1f}s")
-    return {"losses": losses, "grad1": leaf_norms(grad1), "delta": leaf_norms(delta)}
+    log(f"reference ({prec}): {len(batches)} steps in {time.perf_counter() - t0:.1f}s "
+        f"(waiting for the steps {clock['steps']:.1f}s, reading trees to the host and their "
+        f"norms {clock['host']:.1f}s)")
+    return {"losses": losses, "grad1": grad1, "delta": delta}
 
 
 NUMBERS = ("loss_1", "loss_2", "loss_3", "grad_1", "grad_1_median_leaf", "grad_1_p90_leaf",
@@ -181,13 +218,12 @@ def program_readings(abstract, seed, config_file, losses, mu1, params3) -> Dict[
 
     params0, _ = weights.make_weights(
         abstract[0], abstract[1], seed, program.weight_gains(config_file))
-    params0 = jax.device_get(params0)
-    grad1 = jax.tree.map(lambda m: np.asarray(m, np.float64) / (1 - ADAM_B1), mu1)
-    delta = jax.tree.map(
-        lambda a, b: np.asarray(a, np.float64) - np.asarray(b, np.float64),
+    params0 = jax.device_get(params0)     # and the device's copy is dropped
+    delta = leaf_norms(
         params3, params0,
-    )
-    return {"losses": list(losses), "grad1": leaf_norms(grad1), "delta": leaf_norms(delta)}
+        of=lambda a, b: np.asarray(a, np.float64) - np.asarray(b, np.float64))
+    grad1 = leaf_norms(mu1, of=lambda m: np.asarray(m, np.float64) / (1 - ADAM_B1))
+    return {"losses": list(losses), "grad1": grad1, "delta": delta}
 
 
 def judge(nums: Dict[str, Tuple[float, str]], limits: Dict[str, float]) -> List[Dict[str, Any]]:
@@ -199,11 +235,22 @@ def judge(nums: Dict[str, Tuple[float, str]], limits: Dict[str, float]) -> List[
     return checks
 
 
-def compare_training(*, config_file, config, abstract, seed, batches, losses, mu1,
-                     params3, log) -> List[Dict[str, Any]]:
+def compare_training(*, config_file, abstract, seed, batches, losses, mu1, params3, log,
+                     as_model=model_batch) -> List[Dict[str, Any]]:
+    import jax
+
+    from benchmarks import devices
+
     program = program_readings(abstract, seed, config_file, losses, mu1, params3)
-    reference = reference_readings(config_file, abstract, seed, batches, "highest", log)
+    reference = reference_readings(
+        config_file, abstract, seed, batches, "highest", log, as_model)
     nums = numbers(program, reference)
+    # a process's peak never falls: this is the larger of the window's and the reference's
+    mem = devices.memory(jax.local_devices())
+    log(f"memory after the comparison: peak_bytes_in_use {mem['peak_bytes_in_use']}, "
+        f"peak_bytes_reserved {mem['peak_bytes_reserved']}")
+    log(f"losses: program {['%.6f' % x for x in losses]}, reference "
+        f"{['%.6f' % x for x in reference['losses']]}")
     log(f"leaves left out of the change (reference gradient under "
         f"{DEAD_GRADIENT} of the median leaf's): {int(nums['_left_out'][0])}")
     limits = config_file["limits"]

@@ -172,7 +172,7 @@ def run(ctx) -> Dict[str, Any]:
     abstract = (prog.abstract_params, prog.abstract_batch_stats)
     del prog, dev_iter, pending, metrics
     checks = check.compare_training(
-        config_file=config_file, config=config, abstract=abstract, seed=ctx.seed,
+        config_file=config_file, abstract=abstract, seed=ctx.seed,
         batches=first_batches, losses=losses, mu1=mu1, params3=params3, log=log,
     )
     harness_faults = []

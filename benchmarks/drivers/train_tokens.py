@@ -9,7 +9,19 @@ reference over the same batches).  What differs is what a batch is: the
 program is built here from a token batch's shapes, the feed is the program's
 packed-token feed, and the reference is handed (tokens, targets).  A traced
 run also reduces the profile by scope (trace/scopes_lm.json) and reads the
-routed layers' counters of the traced steps, for the per-layer readers.
+step's counters over the traced steps, for the per-layer readers.
+
+A configuration's file may bring scopes and counters of its own, and readers
+under benchmarks/metrics/ for them, with no edit here:
+
+* ``scope_tallies``: rules as in scopes_lm.json (``group``, ``pattern``,
+  ``why``), counted beside the family's: the family's rules still partition
+  the step (``trace["program"]["scope_s"]``), and an op's self time is added
+  to every tally whose pattern its scope holds
+  (``trace["program"]["tally_s"][group]``);
+* ``counters``: names of further step metrics, logged every step of the
+  window; ``trace["counters"][name]`` is the mean over the traced steps.  A
+  name the step does not return is a fault of the run.
 """
 
 from __future__ import annotations
@@ -24,7 +36,9 @@ from benchmarks.drivers.train import CHECK_STEPS, WARM_STEPS, _annotator, first_
 
 SCOPE_RULES = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "trace", "scopes_lm.json")
+# what every routed decoder's step returns; a step without them logs none
 COUNTERS = ("moe/assignments_held", "moe/load_max_over_mean")
+HELD, LOAD = COUNTERS
 
 
 def batch_spec(config, seq_len: int):
@@ -81,45 +95,32 @@ def build_feed(mix: Dict[str, Any], config, seed: int):
         doc_len_min=corpus["doc_len_min"], depth=int(mix.get("feed_depth", 2)))
 
 
+def token_batch(host_batch):
+    """(observations, actions) as the reference takes them."""
+    return host_batch["observations"], host_batch["actions"]
+
+
 def reference_readings(config_file, abstract, seed, batches, prec: str, log) -> Dict[str, Any]:
     """check.reference_readings for (tokens, targets) batches."""
-    import jax
-
-    from benchmarks import check, program, weights
-
-    ref = check.load_reference(config_file["reference"])
-    sz = ref.sizes(config_file["overrides"])
-    params, batch_stats = weights.make_weights(
-        abstract[0], abstract[1], seed, program.weight_gains(config_file))
-    base = weights.seed_key(seed)
-    keys = [jax.random.fold_in(base, i) for i in range(len(batches))]
-    t0 = time.perf_counter()
-    losses, grad1, delta = check.follow(
-        ref, sz, params, batch_stats, [(b["observations"], b["actions"]) for b in batches],
-        keys, float(config_file["overrides"]["learning_rate"]), prec)
-    log(f"reference ({prec}): {len(batches)} steps in {time.perf_counter() - t0:.1f}s")
-    return {"losses": losses, "grad1": check.leaf_norms(grad1), "delta": check.leaf_norms(delta)}
-
-
-def compare_training(*, config_file, abstract, seed, batches, losses, mu1, params3,
-                     log) -> List[Dict[str, Any]]:
     from benchmarks import check
 
-    program_side = check.program_readings(abstract, seed, config_file, losses, mu1, params3)
-    reference = reference_readings(config_file, abstract, seed, batches, "highest", log)
-    nums = check.numbers(program_side, reference)
-    log(f"losses: program {['%.6f' % x for x in losses]}, reference "
-        f"{['%.6f' % x for x in reference['losses']]}")
-    log(f"leaves left out of the change (reference gradient under "
-        f"{check.DEAD_GRADIENT} of the median leaf's): {int(nums['_left_out'][0])}")
-    limits = config_file["limits"]
-    log("read and not compared (PERF.md section 2 says why): " + ", ".join(
-        f"{k} {v[0]:.4g}" for k, v in nums.items() if k not in limits and k != "_left_out"))
-    return check.judge(nums, limits)
+    return check.reference_readings(
+        config_file, abstract, seed, batches, prec, log, as_model=token_batch)
 
 
-def _traced_counters(counter_log, first: int, count: int, config, mix) -> Dict[str, float]:
-    """The routed layers' counters, mean over the steps of the traced slice."""
+def counter_names(config_file: Dict[str, Any], metrics):
+    """(the step metrics logged every step, the configuration's ``counters``
+    that the step does not return).  Logged: the routed layers' two where the
+    step returns them, then the configuration's own."""
+    own = list(config_file.get("counters", []))
+    missing = [k for k in own if k not in metrics]
+    names = [k for k in COUNTERS if k in metrics and k not in own]
+    return names + [k for k in own if k in metrics], missing
+
+
+def _traced_counters(names, counter_log, first: int, count: int, config, mix) -> Dict[str, float]:
+    """Each logged counter by its name, mean over the steps of the traced
+    slice, beside the shapes the readers divide by."""
     import jax
     import numpy as np
 
@@ -131,11 +132,20 @@ def _traced_counters(counter_log, first: int, count: int, config, mix) -> Dict[s
            "attention_layers": layer_types.count("full_attention"),
            "assignments_total": float(tokens * int(lm.num_experts_per_tok) * routed_layers)}
     steps = counter_log[first:first + count] or counter_log[-1:]
-    if steps and steps[0]:
+    if names and steps:
         values = np.asarray(jax.device_get(steps), np.float64)
-        out["assignments_held"] = float(values[:, 0].mean())
-        out["load_max_over_mean"] = float(values[:, 1].mean())
+        out.update(zip(names, values.mean(axis=0).tolist()))
     return out
+
+
+def reduce_profile(events, scopes, config_file) -> Dict[str, Any]:
+    """The device's time by the family's scope groups, and by the
+    configuration's own tallies beside them."""
+    from benchmarks.trace import program as trace_program
+
+    return trace_program.reduce_events(
+        events, scopes, trace_program.load_rules(SCOPE_RULES),
+        trace_program.compile_rules(config_file.get("scope_tallies", [])))
 
 
 def run(ctx) -> Dict[str, Any]:
@@ -144,7 +154,7 @@ def run(ctx) -> Dict[str, Any]:
 
     from rt1_tpu.data.pipeline import device_feeder
 
-    from benchmarks import devices, program, stats, traffic, weights
+    from benchmarks import check, devices, program, stats, traffic, weights
     from benchmarks.trace import program as trace_program
     from benchmarks.trace import xplane
 
@@ -180,7 +190,9 @@ def run(ctx) -> Dict[str, Any]:
     losses, mu1, params3 = first_steps(prog, one_step)
     skips = int(jax.device_get(prog.skips)) if prog.skips is not None else 0
     for i in range(CHECK_STEPS, CHECK_STEPS + WARM_STEPS):
-        one_step(i)["loss"].block_until_ready()
+        metrics = one_step(i)
+        metrics["loss"].block_until_ready()
+    names, no_such_counter = counter_names(config_file, metrics)
     first_batches = list(host.taps)
     host.taps = []
     t_warm = time.perf_counter()
@@ -220,7 +232,7 @@ def run(ctx) -> Dict[str, Any]:
             if was == "waiting" and tracer.state == "tracing":
                 traced_from = len(completions)
         metrics = one_step(i)
-        counter_log.append([metrics[k] for k in COUNTERS if k in metrics])
+        counter_log.append([metrics[k] for k in names])
         i += 1
         if pending is not None:
             with span("bench/sync"):
@@ -250,11 +262,12 @@ def run(ctx) -> Dict[str, Any]:
         f"{(host.wait_s - wait0) / max(1, host.calls - calls0) * 1e3:.3f} ms, "
         f"next(dev_iter) {(h2d_s[0] - h2d0) / steps * 1e3:.3f} ms; padding share of the "
         f"sequences packed so far {feed.padding_share * 100:.2f} %")
-    if counter_log and counter_log[-1]:
+    if HELD in names and LOAD in names:
         # what a step's time follows: interval j ends with step j + 1's completion
         values = np.asarray(jax.device_get(counter_log), np.float64)
         intervals = np.diff(np.asarray(completions)) * 1e3
-        rows = values[1:len(intervals) + 1, 0]
+        rows = values[1:len(intervals) + 1, names.index(HELD)]
+        load = values[:, names.index(LOAD)]
         keep = intervals < 2 * np.median(intervals)      # not the profiler's stop
         slope = np.polyfit(rows[keep], intervals[keep], 1)[0] if keep.sum() > 2 else float("nan")
         q = np.percentile(intervals[keep], [0, 25, 50, 75, 100])
@@ -262,7 +275,7 @@ def run(ctx) -> Dict[str, Any]:
             f"{q[4]:.3f}; moe/assignments_held a step {rows.min():.0f}-{rows.max():.0f} (mean "
             f"{rows.mean():.0f}), an interval grows {slope * 1e3:.3f} us a row held (correlation "
             f"{np.corrcoef(rows[keep], intervals[keep])[0, 1]:.2f}); moe/load_max_over_mean "
-            f"{values[:, 1].min():.3f}-{values[:, 1].max():.3f}")
+            f"{load.min():.3f}-{load.max():.3f}")
 
     mem = devices.memory(jax.local_devices())
     log(f"memory: peak_bytes_in_use {mem['peak_bytes_in_use']}, peak_bytes_reserved "
@@ -272,26 +285,30 @@ def run(ctx) -> Dict[str, Any]:
     if tracer is not None:
         # the program's own scopes, before summary() removes the profile
         events, scopes = trace_program.events_from_xplane(xplane.find_xplane(tracer.dir))
-        by_scope = trace_program.reduce_events(
-            events, scopes, trace_program.load_rules(SCOPE_RULES))
+        by_scope = reduce_profile(events, scopes, config_file)
         del events, scopes
         for line in trace_program.describe(by_scope):
             log(line)
         trace_summary = tracer.summary()
         trace_summary["program"] = {
-            k: by_scope.get(k) for k in ("step_program", "runs", "scope_s", "op_self_s",
-                                         "step_s", "spans")}
+            k: by_scope.get(k) for k in ("step_program", "runs", "scope_s", "tally_s",
+                                         "op_self_s", "step_s", "spans")}
         trace_summary["counters"] = _traced_counters(
-            counter_log, traced_from, int(tracer.slice["steps"]), config, mix)
+            names, counter_log, traced_from, int(tracer.slice["steps"]), config, mix)
+        log("counters, mean over the traced steps: " + ", ".join(
+            f"{k} {trace_summary['counters'][k]:.6g}" for k in names))
 
     # -- free the program, then let the reference follow the first steps
     feed.close()
     abstract = (prog.abstract_params, prog.abstract_batch_stats)
     del prog, dev_iter, pending, metrics, counter_log
-    checks = compare_training(
+    checks = check.compare_training(
         config_file=config_file, abstract=abstract, seed=ctx.seed, batches=first_batches,
-        losses=losses, mu1=mu1, params3=params3, log=log)
+        losses=losses, mu1=mu1, params3=params3, log=log, as_model=token_batch)
     harness_faults = []
+    if no_such_counter:
+        harness_faults.append(f"the configuration's counters {no_such_counter} are not among "
+                              f"the step's metrics")
     if in_window["traces"] or in_window["compiles"]:
         harness_faults.append(f"compiled inside the window: {in_window}")
     if skips or skips_end:

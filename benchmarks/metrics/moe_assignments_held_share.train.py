@@ -7,8 +7,9 @@ positions (tail padding takes no rows) when the router is balanced."""
 
 def read(r):
     counters = r["trace"].get("counters") or {}
-    if not counters.get("assignments_held") or not counters.get("assignments_total"):
+    held = counters.get("moe/assignments_held")
+    if not held or not counters.get("assignments_total"):
         return None
     r["log"](f"largest held expert's rows over the mean, over the traced steps: "
-             f"{counters.get('load_max_over_mean', float('nan')):.4f}")
-    return counters["assignments_held"] / counters["assignments_total"]
+             f"{counters.get('moe/load_max_over_mean', float('nan')):.4f}")
+    return held / counters["assignments_total"]

@@ -13,7 +13,7 @@ def read(r):
     trace = r["trace"]
     seconds = ((trace.get("program") or {}).get("scope_s") or {}).get("moe_experts")
     counters = trace.get("counters") or {}
-    rows = counters.get("assignments_held")
+    rows = counters.get("moe/assignments_held")
     if not seconds or not rows:
         return None
     lm = flops_lm.lm_sizes(r["config_file"]["overrides"])

@@ -18,6 +18,9 @@ name (the cache's key leaves metadata out).
   rules, first match wins), each op counted by its self time (its duration
   minus the events nested in it on the "XLA Ops" line, a ``while``'s body),
   only ops inside whole runs of the step program, divided by the runs;
+* ``tally_s``, where the caller hands tallies over: the same self times added
+  to every tally whose pattern the op's scope holds.  Tallies do not
+  partition: they overlap the groups and each other, and an op may be in none;
 * ``spans``: per ``rt1/*`` span name the count, total and mean seconds, and
   the mean of every numeric argument (``ready`` of ``rt1/feeder/next``);
 * ``gaps``: each idle gap of the kept window (as reduce.py finds and keeps
@@ -164,10 +167,13 @@ def events_from_xplane(path: str) -> Tuple[List[Event], Dict[str, str]]:
 
 # -- scopes to groups
 
+def compile_rules(rules: Sequence[Dict[str, Any]]) -> List[Dict[str, Any]]:
+    return [dict(r, regex=re.compile(r["pattern"])) for r in rules]
+
+
 def load_rules(path: str = RULES_FILE) -> List[Dict[str, Any]]:
     with open(path) as f:
-        rules = json.load(f)["rules"]
-    return [dict(r, regex=re.compile(r["pattern"])) for r in rules]
+        return compile_rules(json.load(f)["rules"])
 
 
 def group_names(rules: Sequence[Dict[str, Any]]) -> List[str]:
@@ -225,7 +231,8 @@ def _window(events: Sequence[Event]) -> Optional[Tuple[int, int]]:
 
 
 def reduce_events(events: Sequence[Event], scopes: Dict[str, str],
-                  rules: Optional[Sequence[Dict[str, Any]]] = None) -> Dict[str, Any]:
+                  rules: Optional[Sequence[Dict[str, Any]]] = None,
+                  tallies: Sequence[Dict[str, Any]] = ()) -> Dict[str, Any]:
     rules = load_rules() if rules is None else rules
     window = _window(events)
     planes = sorted({e[0] for e in events if is_device_plane(e[0])})
@@ -244,7 +251,7 @@ def reduce_events(events: Sequence[Event], scopes: Dict[str, str],
 
     out: Dict[str, Any] = {"step_program": step, "runs": len(runs)}
     if runs:
-        out.update(_scope_seconds(ops, runs, scopes, rules))
+        out.update(_scope_seconds(ops, runs, scopes, rules, tallies))
     out["spans"] = _span_table(program_spans)
     if window is not None and ops:
         step_ns = sum(r[4] for r in runs) // len(runs) if runs else 0
@@ -252,8 +259,9 @@ def reduce_events(events: Sequence[Event], scopes: Dict[str, str],
     return out
 
 
-def _scope_seconds(ops, runs, scopes, rules) -> Dict[str, Any]:
+def _scope_seconds(ops, runs, scopes, rules, tallies=()) -> Dict[str, Any]:
     by_group: Dict[str, int] = defaultdict(int)
+    by_tally: Dict[str, int] = defaultdict(int)
     by_op: Dict[str, int] = defaultdict(int)
     bounds = [(r[3], r[3] + r[4]) for r in runs]
     k = 0
@@ -264,10 +272,15 @@ def _scope_seconds(ops, runs, scopes, rules) -> Dict[str, Any]:
             break
         if e[3] < bounds[k][0]:
             continue
-        by_group[group_of(scopes.get(e[2]), rules)] += self_ns
         by_op[e[2]] += self_ns
+    for name, ns in by_op.items():
+        scope = scopes.get(name)
+        by_group[group_of(scope, rules)] += ns
+        for t in tallies:
+            if scope and t["regex"].search(scope):
+                by_tally[t["group"]] += ns
     n = len(runs)
-    return {
+    out = {
         "scope_s": {g: by_group.get(g, 0) / n / 1e9 for g in group_names(rules)},
         "op_self_s": sum(by_group.values()) / n / 1e9,
         "step_s": sum(r[4] for r in runs) / n / 1e9,
@@ -275,6 +288,9 @@ def _scope_seconds(ops, runs, scopes, rules) -> Dict[str, Any]:
             [name, group_of(scopes.get(name), rules), ns / n / 1e9, scopes.get(name, "")]
             for name, ns in sorted(by_op.items(), key=lambda kv: -kv[1])[:10]],
     }
+    if tallies:
+        out["tally_s"] = {t["group"]: by_tally.get(t["group"], 0) / n / 1e9 for t in tallies}
+    return out
 
 
 def _span_table(program_spans) -> Dict[str, Dict[str, Any]]:
@@ -356,6 +372,9 @@ def describe(summary: Dict[str, Any]) -> List[str]:
         if empty:
             lines.append(f"  no op under {', '.join(empty)}: not in this program, renamed, or the "
                          "executable was compiled before the scope existed (persistent cache hit)")
+        for group, s in summary.get("tally_s", {}).items():
+            lines.append(f"  tally {group:18s} {s * 1e3:9.3f} ms  {s / step * 100:6.2f} %  "
+                         "(beside the groups above, not one of them)")
         for name, group, s, scope in summary["top_ops"]:
             lines.append(f"  op {name} {s * 1e3:.3f} ms in {group}: {scope}")
     for name, s in sorted(summary["spans"].items()):

@@ -51,6 +51,26 @@ def temp_checkout(tmp_path, extra_workloads=()):
     return root
 
 
+def add_rehearsal_cell(root, cell, config, mix="train-tokens-test", per_layer=()):
+    """One more rehearsal cell in a temporary checkout: its configuration and
+    mix copied from tests/benchmark/data, its entries (and any per-layer
+    entries of its own) appended to the checkout's manifest."""
+    shutil.copy(os.path.join(DATA, config + ".json"),
+                os.path.join(root, "benchmarks", "configs"))
+    shutil.copy(os.path.join(DATA, mix + ".json"), os.path.join(root, "benchmarks", "traffic"))
+    path = os.path.join(root, "BENCHMARK.json")
+    with open(path) as f:
+        m = json.load(f)
+    m["configs"].append({"name": config, "source": "rehearsal", "reduced": [],
+                         "file": f"benchmarks/configs/{config}.json", "why": "CPU rehearsal"})
+    m["workloads"].append({"name": cell, "config": config, "traffic": mix, "chips": 1,
+                           "why": "CPU rehearsal"})
+    m["per_layer"].extend(per_layer)
+    with open(path, "w") as f:
+        json.dump(m, f)
+    return root
+
+
 def pretend_chip(monkeypatch):
     from benchmarks import devices
 

@@ -11,12 +11,12 @@ parent commit has not)."""
 import io
 import json
 import os
-import shutil
 from contextlib import redirect_stdout
 
 import pytest
 
-from bench_testlib import DATA, REPO, manifest, pretend_chip, run_cell, temp_checkout
+from bench_testlib import (REPO, add_rehearsal_cell, manifest, pretend_chip, run_cell,
+                           temp_checkout)
 from benchmarks import flops_lm_mixed, program, run, traffic
 
 CONFIG = "mellum2-12b-a2.5b"
@@ -26,22 +26,7 @@ NEW_READERS = ("attention_ms.train", "attention_masked_roofline", "lm_loss_ms.tr
 
 def mellum_checkout(tmp_path):
     """bench_testlib's temporary checkout with the mellum rehearsal cell."""
-    root = temp_checkout(tmp_path)
-    shutil.copy(os.path.join(DATA, "mellum-small-test.json"),
-                os.path.join(root, "benchmarks", "configs"))
-    shutil.copy(os.path.join(DATA, "train-tokens-test.json"),
-                os.path.join(root, "benchmarks", "traffic"))
-    path = os.path.join(root, "BENCHMARK.json")
-    with open(path) as f:
-        m = json.load(f)
-    m["configs"].append({"name": "mellum-small-test", "source": "rehearsal", "reduced": [],
-                         "file": "benchmarks/configs/mellum-small-test.json",
-                         "why": "CPU rehearsal"})
-    m["workloads"].append({"name": "small.mellum", "config": "mellum-small-test",
-                           "traffic": "train-tokens-test", "chips": 1, "why": "CPU rehearsal"})
-    with open(path, "w") as f:
-        json.dump(m, f)
-    return root
+    return add_rehearsal_cell(temp_checkout(tmp_path), "small.mellum", "mellum-small-test")
 
 
 def config_file():
@@ -120,9 +105,11 @@ def test_every_new_reader_is_in_the_manifest_for_the_cell_alone():
         assert by_name[name]["workloads"] == [CELL]
         assert by_name[name]["moves"] == "train_samples_per_s"
         assert by_name[name]["source"] == "device_trace"
-    # the five that list the lfm2 token cell keep their lists
-    for name in ("moe_ms.train", "mixer_ms.train", "moe_experts_roofline", "attention_roofline",
-                 "moe_assignments_held_share.train"):
+    # of the five that list the lfm2 token cell, the routed layers' three list
+    # this cell too since PR 33 (the same routed layer, PERF.md section 3)
+    for name in ("moe_ms.train", "moe_experts_roofline", "moe_assignments_held_share.train"):
+        assert by_name[name]["workloads"] == ["lfm2-24b-a2b.train-tokens-8k", CELL]
+    for name in ("mixer_ms.train", "attention_roofline"):
         assert by_name[name]["workloads"] == ["lfm2-24b-a2b.train-tokens-8k"]
 
 
