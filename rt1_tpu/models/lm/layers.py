@@ -209,9 +209,12 @@ def splash_blocks(s: int, window: Optional[int] = None) -> int:
 
 
 @functools.lru_cache(maxsize=None)
-def _splash_kernel(s: int, heads: int, window: Optional[int], interpret: bool):
+def _splash_kernel(s: int, heads: int, window: Optional[int], interpret: bool,
+                   head_dim_v: int = 0):
     """One kernel object a shape and mask: its mask tables are numpy work over
-    every (head, query block, key block), made once and not once a trace."""
+    every (head, query block, key block), made once and not once a trace.
+    ``head_dim_v`` (the values' width where it is not the keys': the kernel
+    reads both from its operands) is there for the span alone."""
     from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as sk
     from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask as sm
 
@@ -226,7 +229,7 @@ def _splash_kernel(s: int, heads: int, window: Optional[int], interpret: bool):
                   mask="causal" if window is None else "local", window=window or 0,
                   block_q=block, block_kv=block,
                   block_kv_compute=sizes.block_kv_compute, fused_bwd=sizes.use_fused_bwd_kernel,
-                  k_layout=sizes.k_layout.name)
+                  k_layout=sizes.k_layout.name, head_dim_v=head_dim_v)
     _LOG.info("attention kernel: %s", chosen)        # once a kernel object: which a run timed
     with span("lm/attention_kernel", **chosen):
         one = (sm.CausalMask((s, s)) if window is None
@@ -243,14 +246,15 @@ def splash_attention(q, k, v, scale, window: Optional[int] = None, interpret: bo
     block-sparse, so the blocks above the diagonal (and, under a window, those
     wholly left of it) are never visited and the mask is applied on the blocks
     it cuts only.  The scale is folded into q (in float32, so a scale that is
-    no power of two rounds once)."""
+    no power of two rounds once).  v may be narrower than q and k (latent
+    attention: keys of 192, values of 128); the output has v's width."""
     b, s, kvh, g, d = q.shape
-    kernel = _splash_kernel(s, kvh * g, window, interpret)
+    kernel = _splash_kernel(s, kvh * g, window, interpret, v.shape[-1])
     qh = (q.astype(jnp.float32) * scale).astype(q.dtype).reshape(b, s, kvh * g, d)
     qh, kh, vh = (x.transpose(0, 2, 1, 3) for x in (qh, k, v))
     with jax.named_scope("kernel"), jax.named_scope("full" if window is None else "window"):
         out = jax.vmap(kernel)(qh, kh, vh)
-    return out.transpose(0, 2, 1, 3).reshape(b, s, kvh, g, d)
+    return out.transpose(0, 2, 1, 3).reshape(b, s, kvh, g, v.shape[-1])
 
 
 class GQAttention(nn.Module):
@@ -277,6 +281,54 @@ class GQAttention(nn.Module):
             return Linear(sp.hidden_size, sp.dtype, name="o_proj")(out.reshape(b, s, h * d))
 
 
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (the ``deepseek_v2``/``v3`` form), unabsorbed,
+    for the heads held here (``spec.heads_held``: attention tensor-parallel over
+    heads; the output is the held heads' part of ``concat_heads(...) W_o``).
+
+        c_q = RMSNorm(x W_qa);   [q_nope | q_rope] a head = c_q W_qb
+        [c_kv | k_rope] = x W_kva;   c_kv = RMSNorm(c_kv)
+        [k_nope | v] a head = c_kv W_kvb
+        q = [q_nope | rotary(q_rope)];   k = [k_nope | rotary(k_rope)], the one
+        rotary key shared by every head
+        out = concat_heads(softmax(q k^T (nope + rope)^-0.5 m^2, causal) v) W_o
+
+    Both down-projections, the latent norms and the shared key are computed by
+    every chip of a head-parallel group alike; ``W_qb``, ``W_kvb`` and ``W_o``
+    hold the held heads' columns (rows).  Rotate-half pairing: the published
+    interleave is undone by a fixed permutation of ``W_qb``'s and ``W_kva``'s
+    rotary columns."""
+
+    spec: LMSpec
+
+    @nn.compact
+    def __call__(self, x):
+        sp = self.spec
+        rule = sp.rotary_rule("latent_attention")
+        b, s, _ = x.shape
+        h = sp.heads_held[1]
+        nope, rope, dv = sp.qk_nope_head_dim, sp.qk_rope_head_dim, sp.v_head_dim
+        with jax.named_scope("attention"):
+            with jax.named_scope("latent"):
+                c_q = RMSNorm(sp.norm_eps, sp.dtype, name="q_a_layernorm")(
+                    Linear(sp.q_lora_rank, sp.dtype, name="q_a_proj")(x))
+                q = Linear(h * (nope + rope), sp.dtype, name="q_b_proj")(c_q)
+                q_nope, q_rope = jnp.split(q.reshape(b, s, h, nope + rope), [nope], axis=-1)
+                c_kv, k_rope = jnp.split(
+                    Linear(sp.kv_lora_rank + rope, sp.dtype, name="kv_a_proj")(x),
+                    [sp.kv_lora_rank], axis=-1)
+                c_kv = RMSNorm(sp.norm_eps, sp.dtype, name="kv_a_layernorm")(c_kv)
+                kv = Linear(h * (nope + dv), sp.dtype, name="kv_b_proj")(c_kv)
+                k_nope, v = jnp.split(kv.reshape(b, s, h, nope + dv), [nope], axis=-1)
+                k_rope = rotary(k_rope[:, :, None, :], rule)
+                q = jnp.concatenate([q_nope, rotary(q_rope, rule)], axis=-1)
+                k = jnp.concatenate(
+                    [k_nope, jnp.broadcast_to(k_rope, (b, s, h, rope))], axis=-1)
+            scale = (nope + rope) ** -0.5 * sp.softmax_scale_factor
+            out = causal_attention(q[:, :, :, None, :], k, v, scale)
+            return Linear(sp.hidden_size, sp.dtype, name="o_proj")(out.reshape(b, s, h * dv))
+
+
 class ShortConv(nn.Module):
     """Gated short convolution: ``[B, C, u] = split(x W_in)``, a depthwise
     causal convolution of ``B * u`` over ``conv_kernel`` taps, gated by ``C``."""
@@ -301,12 +353,18 @@ class ShortConv(nn.Module):
 
 
 class SwiGLU(nn.Module):
+    """``W_2(silu(x W_1) * x W_3)``: the dense feed-forward layer, or (with a
+    ``width`` and a ``scope_name`` of its own) a shared expert."""
+
     spec: LMSpec
+    width: Optional[int] = None     # None: ``intermediate_size``
+    scope_name: str = "dense_ffn"
 
     @nn.compact
     def __call__(self, x):
         sp = self.spec
-        with jax.named_scope("dense_ffn"):
-            gate = Linear(sp.intermediate_size, sp.dtype, name="w1")(x)
-            up = Linear(sp.intermediate_size, sp.dtype, name="w3")(x)
+        width = self.width or sp.intermediate_size
+        with jax.named_scope(self.scope_name):
+            gate = Linear(width, sp.dtype, name="w1")(x)
+            up = Linear(width, sp.dtype, name="w3")(x)
             return Linear(sp.hidden_size, sp.dtype, name="w2")(jax.nn.silu(gate) * up)

@@ -1,5 +1,5 @@
 """Routed experts (sigmoid or softmax scores), top-k, dropless, for the
-experts held here.
+experts held here, and the shared expert beside them where the spec has one.
 
 The layer is told which experts it holds (``spec.experts_held``).  It scores
 and selects over ALL the router's experts, normalises the selected scores as
@@ -7,6 +7,11 @@ the whole model does, and returns the part of the result its own experts give:
 ``sum over selected AND held experts of w_i * E_i(x)``.  What the absent
 experts would add is left out; no code stands in for them or for the exchange
 that would bring their part here.
+
+A shared expert (``spec.n_shared_experts``) is a SwiGLU of the experts' width
+that every token passes and every chip of an expert-parallel group computes
+alike: ``y = sum over selected AND held of w_i E_i(x) + S(x)``; where the
+shares of a group are added up it counts once.
 
 Dispatch is a sort, not a one-hot tensor: the tokens' assignments are ordered
 by held expert (assignments to absent experts last), the rows are gathered in
@@ -48,7 +53,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from rt1_tpu.models.lm.layers import Leaf
+from rt1_tpu.models.lm.layers import Leaf, SwiGLU
 from rt1_tpu.models.lm.spec import LMSpec
 
 _STACK_INIT = nn.initializers.variance_scaling(1.0, "fan_in", "normal", in_axis=-2, out_axis=-1,
@@ -382,6 +387,13 @@ def held_experts_ffn(x, idx, weights, live, w1, w3, w2, spec: LMSpec, capacity: 
     return out, group_sizes, ~_fits(capacity, group_sizes)
 
 
+def shared_expert(spec: LMSpec, name: str = "shared_expert") -> SwiGLU:
+    """The shared expert: a SwiGLU of the routed experts' width (times
+    ``n_shared_experts``) that every token passes, under ``moe/shared``."""
+    return SwiGLU(spec, spec.moe_intermediate_size * spec.n_shared_experts, "moe/shared",
+                  name=name)
+
+
 class RoutedFFN(nn.Module):
     spec: LMSpec
 
@@ -402,6 +414,14 @@ class RoutedFFN(nn.Module):
         bias = (Leaf("kernel", (sp.num_experts,), nn.initializers.zeros, name="expert_bias")()
                 if sp.use_expert_bias else None)
         w1, w3, w2 = _Experts(sp, name="experts")()
+        if sp.recompute_blocks:
+            # The stacks go into the routed layer in the compute type, so their
+            # gradients come out of it in that type and are widened where Adam
+            # reads them, as every other kernel's are: a step that holds every
+            # gradient until its norm is known (the guard) holds half the bytes.
+            # Only where the block is made again on the way back: elsewhere the
+            # cast copies would be kept from the forward pass to the backward.
+            w1, w3, w2 = (w.astype(sp.dtype) for w in (w1, w3, w2))
         with jax.named_scope("moe/router"):
             idx, weights = route(flat, router_kernel, bias, sp)
         self.sow("intermediates", "selected", idx)
@@ -409,6 +429,9 @@ class RoutedFFN(nn.Module):
             flat, idx, weights, live, w1, w3, w2, sp,
             row_capacity(b * s * sp.experts_per_tok, sp.experts_held[1], sp.num_experts))
         rows = group_sizes.astype(jnp.float32)
-        return out.reshape(b, s, d), {
+        out = out.reshape(b, s, d)
+        if sp.n_shared_experts:
+            out = out + shared_expert(sp)(x)
+        return out, {
             "rows_held": jnp.sum(rows), "rows_max": jnp.max(rows), "rows_mean": jnp.mean(rows),
             "fallback": fell_back.astype(jnp.float32)}

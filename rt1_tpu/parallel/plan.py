@@ -156,6 +156,17 @@ def rt1_sharding_plan() -> List[Rule]:
         (r"mixer/kernel$", P()),
         (r"ffn/w[123]/kernel$", P()),
         (r"(mixer_norm|ffn_norm|final_norm|q_norm|k_norm)/scale$", P()),
+        # Latent attention: on one chip the layer is told its heads
+        # (`model.lm.heads_held`); the latents' projections and norms are
+        # computed by every shard alike.
+        (r"mixer/(q_a_proj|q_b_proj|kv_a_proj|kv_b_proj)/kernel$", P()),
+        (r"mixer/(q_a_layernorm|kv_a_layernorm)/scale$", P()),
+        # The shared expert, a sublayer's stream maps, the prediction module's
+        # merge and norms: replicated.
+        (r"ffn/shared_expert/w[123]/kernel$", P()),
+        (r"(mixer_hc|ffn_hc)/(norm/scale|alpha/scale|phi/kernel|maps_bias/bias)$", P()),
+        (r"mtp/eh_proj/kernel$", P()),
+        (r"mtp/(hnorm|enorm)/scale$", P()),
     ]
 
 

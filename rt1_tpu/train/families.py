@@ -151,6 +151,9 @@ FAMILIES: Dict[str, Family] = {
     "lfm2_moe": _DECODER_LM,
     # configs/mellum.py: sliding and full attention 3:1, softmax router, untied head
     "mellum": _DECODER_LM,
+    # configs/xing4_0.py: latent attention, four residual streams mixed by maps a
+    # token, sigmoid router beside a shared expert, a multi-token-prediction module
+    "xing4_0": _DECODER_LM,
 }
 
 

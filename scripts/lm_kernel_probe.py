@@ -29,6 +29,12 @@ the query's own key and the ``window - 1`` before it): blocks of 256, 512 and
 1,024, fused and unfused backward, and the blockwise lax form.
 
     python scripts/lm_kernel_probe.py [--rows 65536 --held_rows 8192] [--only routed]
+
+Two parts run on request only: ``--only latent`` (a latent-attention layer's
+kernel: keys of 192 as they are and zero-padded to 256, values of 128, fused
+and unfused backward, blocks; ``--batch 1 --seq 8192 --heads 16``) and ``--only
+streams`` (one hyper-connection sublayer both ways, the streams held in
+bfloat16 and in float32; ``--batch 1 --seq 8192 --hidden 3584 --streams 4``).
 """
 
 import argparse
@@ -66,15 +72,123 @@ def main() -> int:
     ap.add_argument("--expert-width", type=int, default=1536)
     ap.add_argument("--experts-held", type=int, default=8)
     ap.add_argument("--top-k", type=int, default=4)
-    ap.add_argument("--only", choices=("attention", "experts", "routed", "layer"), default=None)
+    ap.add_argument("--only", choices=("attention", "experts", "routed", "layer", "latent",
+                                       "streams"), default=None)
+    ap.add_argument("--streams", type=int, default=4, help="residual streams a token")
     args = ap.parse_args()
 
     sys.path.insert(0, ".")
     print(json.dumps({"device": jax.devices()[0].device_kind}), flush=True)
+    if args.only in ("latent", "streams"):      # a configuration's own rows, on request
+        {"latent": latent, "streams": streams}[args.only](args)
+        return 0
     for part in ("attention", "experts", "routed"):
         if args.only in (None, part) or (part, args.only) == ("routed", "layer"):
             {"attention": attention, "experts": experts, "routed": routed}[part](args)
     return 0
+
+
+def latent(args) -> None:
+    """A latent-attention layer's kernel both ways (``--only latent --batch 1
+    --seq 8192 --heads 16``): keys of 192 (128 + the 64-wide rotary part) as
+    they are and zero-padded to 256 (two whole lane tiles), values of 128; fused
+    and unfused backward; blocks.  Layout changes and the scale are in the time,
+    as in the program."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as sk
+    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask as sm
+
+    from rt1_tpu.models.lm import layers
+
+    b, s, h, qk, dv = args.batch, args.seq, args.heads, 192, 128
+    key = jax.random.PRNGKey(0)
+    q = jax.random.normal(key, (b, s, h, 1, qk), jnp.bfloat16)
+    k = jax.random.normal(jax.random.fold_in(key, 1), (b, s, h, qk), jnp.bfloat16)
+    v = jax.random.normal(jax.random.fold_in(key, 2), (b, s, h, dv), jnp.bfloat16)
+    scale = qk ** -0.5 * 2.0047
+
+    def splash(blocks, fused, pad):
+        sizes = sk.BlockSizes(
+            block_q=blocks[0], block_kv=blocks[1], block_kv_compute=blocks[2],
+            block_q_dkv=blocks[0], block_kv_dkv=blocks[1], block_kv_dkv_compute=blocks[1],
+            block_q_dq=None if fused else blocks[0], block_kv_dq=None if fused else blocks[1],
+            use_fused_bwd_kernel=fused)
+
+        def fn(q, k, v):
+            kernel = sk.make_splash_mha(
+                sm.MultiHeadMask([sm.CausalMask((s, s))] * h), block_sizes=sizes,
+                head_shards=1, q_seq_shards=1)
+            qh = (q.astype(jnp.float32) * scale).astype(q.dtype).reshape(b, s, h, qk)
+            if pad:
+                widen = ((0, 0), (0, 0), (0, 0), (0, pad - qk))
+                qh, k = jnp.pad(qh, widen), jnp.pad(k, widen)
+            out = jax.vmap(kernel)(*(x.transpose(0, 2, 1, 3) for x in (qh, k, v)))
+            return out.transpose(0, 2, 1, 3).reshape(b, s, h, 1, dv)
+        return fn
+
+    rows = [({"latent": "program"}, lambda q, k, v: layers.splash_attention(q, k, v, scale))]
+    for pad in (0, 256):
+        for fused in (True, False):
+            for blocks in ((1024, 1024, 512), (1024, 1024, 1024), (512, 512, 512),
+                           (1024, 2048, 512), (2048, 1024, 512)):
+                rows.append(({"latent": "splash", "keys": pad or qk, "values": dv, "fused": fused,
+                              "blocks": blocks}, splash(blocks, fused, pad)))
+    rows.append(({"latent": "program", "again": "the chip's clock at the end of the sweep"},
+                 rows[0][1]))
+    reference = None
+    for row, fn in rows:
+        try:
+            step = both_ways(fn)
+            grads = step(q, k, v)
+            reference = grads if reference is None else reference
+            row["dq_gap"] = float(jnp.max(jnp.abs(
+                grads[0].astype(jnp.float32) - reference[0].astype(jnp.float32))))
+            row["fwd_bwd_ms"] = timed(step, q, k, v, repeats=20)
+            row["device_ms"] = device_ms_by_op(step, q, k, v)
+        except Exception as exc:  # noqa: BLE001 - a probe reports and goes on
+            row["error"] = repr(exc)[:300]
+        print(json.dumps(row), flush=True)
+
+
+def streams(args) -> None:
+    """One hyper-connection sublayer both ways around a sublayer that costs
+    nothing (``--only streams --batch 1 --seq 8192 --hidden 3584 --streams 4``):
+    the maps (norm over the n d-wide streams, the phi product, 20 Sinkhorn
+    rounds) and both mixes as the program writes them, with the streams held in
+    bfloat16 and in float32.  Least bytes: the streams read once and written
+    once each way."""
+    from rt1_tpu.models.lm import model as lm_model
+    from rt1_tpu.models.lm.spec import LMSpec
+    from rt1_tpu.train.configs import xing4_0
+
+    lm = xing4_0.get_config().model.lm
+    lm.hidden_size, lm.hc_mult = args.hidden, args.streams
+    for held in (jnp.bfloat16, jnp.float32):
+        spec = LMSpec.from_config(lm, held)
+        x = jax.random.normal(
+            jax.random.PRNGKey(0), (args.streams, args.batch, args.seq, args.hidden), held)
+        maps = lm_model.HyperConnection(spec)
+        params = jax.jit(maps.init)(jax.random.PRNGKey(1), x)
+        params = jax.tree.map(       # phi as a seed draws it: logits of N(0, 0.25)
+            lambda a: a if a.ndim < 2 else 0.5 * jax.random.normal(
+                jax.random.PRNGKey(2), a.shape) / a.shape[0] ** 0.5, params)
+
+        def sublayer(params, x):
+            h_pre, h_post, h_res, _ = maps.apply(params, x)
+            inside = lm_model.mix_in(x, h_pre)
+            return lm_model.mix_out(x, h_res, h_post, inside)
+
+        step = jax.jit(jax.grad(
+            lambda p, x: jnp.sum(sublayer(p, x).astype(jnp.float32) ** 2), argnums=(0, 1)))
+        row = {"streams": args.streams, "held_in": jnp.dtype(held).name,
+               "tokens": args.batch * args.seq, "hidden": args.hidden}
+        try:
+            row["fwd_bwd_ms"] = timed(step, params, x, repeats=20)
+            moved = 2 * 2 * x.size * x.dtype.itemsize
+            row["least_bytes_ms"] = round(moved / 819e9 * 1e3, 3)
+            row["device_ms"] = device_ms_by_op(step, params, x)
+        except Exception as exc:  # noqa: BLE001 - a probe reports and goes on
+            row["error"] = repr(exc)[:300]
+        print(json.dumps(row), flush=True)
 
 
 def attention_candidates(d: int = 64, window=None):

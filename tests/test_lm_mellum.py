@@ -384,7 +384,7 @@ def test_the_cells_row_buffer():
 def test_every_family_is_a_record():
     from rt1_tpu.train.configs import language_table, lava_tiny, tiny
 
-    assert set(families.FAMILIES) == {"rt1", "lava", "lfm2_moe", "mellum"}
+    assert set(families.FAMILIES) == {"rt1", "lava", "lfm2_moe", "mellum", "xing4_0"}
     # every base config the repo ships names a family that has a record
     named = {module.__name__.rsplit(".", 1)[1]: module.get_config().model.get("family", "rt1")
              for module in (language_table, tiny, lava_tiny, lfm2_moe, mellum)}

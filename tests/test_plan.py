@@ -114,9 +114,9 @@ def test_plan_covers_every_weight_matrix(name, mc_fn):
 @pytest.fixture(scope="module")
 def shipped_param_paths():
     """Every parameter path of the configurations the repo ships: tiny,
-    flagship, efficientnet_small and the lfm2_moe decoder LM."""
+    flagship, efficientnet_small and the lfm2_moe and xing4_0 decoder LMs."""
     from rt1_tpu.parallel import sharding as shardlib
-    from rt1_tpu.train.configs import lfm2_moe
+    from rt1_tpu.train.configs import lfm2_moe, xing4_0
     from rt1_tpu.train.train import build_family
 
     trees = [
@@ -127,12 +127,13 @@ def shipped_param_paths():
             _tiny_model_config(image_tokenizer="efficientnet_small"),
         )
     ]
-    lm_model, lm_init, _ = build_family(lfm2_moe.get_config().model)
     ids = jnp.zeros((1, 16), jnp.int32)
-    trees.append(jax.eval_shape(
-        lambda r: lm_init(lm_model, r, {"tokens": ids}, {"targets": ids}),
-        jax.random.PRNGKey(0),
-    )["params"])
+    for base in (lfm2_moe, xing4_0):
+        lm_model, lm_init, _ = build_family(base.get_config().model)
+        trees.append(jax.eval_shape(
+            lambda r: lm_init(lm_model, r, {"tokens": ids}, {"targets": ids}),
+            jax.random.PRNGKey(0),
+        )["params"])
     return {
         shardlib._path_str(path)
         for tree in trees

@@ -292,3 +292,42 @@ def test_mellum_routed_experts_compile_both_ways_at_the_published_widths(v5e, mo
         shape((tokens,), jnp.bool_)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and " conditional(" in text
+
+
+@pytest.mark.parametrize("padded", [False, True], ids=["keys_192", "keys_256"])
+def test_xing_latent_attention_compiles_both_ways_at_the_published_widths(v5e, monkeypatch,
+                                                                          padded):
+    """The latent layer's kernel of ``xing4.0-29b-a4b`` at the cell's 1 x 8,192
+    positions over the heads held: keys of 192 (128 + the 64-wide shared rotary
+    part, no multiple of the 128 lanes) against values of 128, as they are and
+    with the keys zero-padded to 256; the causal kernels at blocks of 1,024
+    with the fused backward, and an output as wide as the values."""
+    from rt1_tpu.models.lm import layers
+    from rt1_tpu.models.lm.spec import LMSpec
+    from rt1_tpu.train.configs import xing4_0
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")     # as on the chip
+    sp = LMSpec.from_config(xing4_0.get_config().model.lm, jnp.bfloat16)
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    seq, heads, dv = 8192, sp.heads_held[1], sp.v_head_dim
+    qk = 256 if padded else sp.head_dim
+    assert (sp.head_dim, dv) == (192, 128)
+    q = jax.ShapeDtypeStruct((1, seq, heads, 1, qk), jnp.bfloat16, sharding=one_chip)
+    k = jax.ShapeDtypeStruct((1, seq, heads, qk), jnp.bfloat16, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((1, seq, heads, dv), jnp.bfloat16, sharding=one_chip)
+
+    def both_ways(q, k, v):
+        out = layers.causal_attention(q, k, v, 192 ** -0.5 * sp.softmax_scale_factor)
+        assert out.shape == (1, seq, heads, 1, dv)
+        return jax.grad(lambda *a: jnp.sum(layers.causal_attention(
+            *a, 192 ** -0.5 * sp.softmax_scale_factor).astype(jnp.float32)),
+            argnums=(0, 1, 2))(q, k, v)
+
+    compiled = jax.jit(both_ways).lower(q, k, v).compile()
+    text = compiled.as_text()
+    kernels = {name for line in text.splitlines() if "tpu_custom_call" in line
+               for name in re.findall(r"%(splash_mha_\w+?)[.\d]* = ", line)}
+    assert sorted(kernels) == ["splash_mha_dkv_no_residuals", "splash_mha_fwd_residuals"]
+    assert re.search(rf"bf16\[(1,)?{heads},{seq},{qk}\]", text)
+    assert re.search(rf"bf16\[(1,)?{heads},{seq},{dv}\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
