@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from rt1_tpu.models.lm import streams as streams_kernels
 from rt1_tpu.models.lm.layers import (GQAttention, LatentAttention, Leaf, Linear, RMSNorm,
                                       ShortConv, SwiGLU)
 from rt1_tpu.models.lm.moe import RoutedFFN
@@ -95,29 +96,68 @@ class HyperConnection(nn.Module):
 
     ``alpha`` has one gate a map.  The norm's scale is folded into ``phi`` (x~
     phi = r (X (scale . phi)), r the inverse root mean square), so one pass over
-    the streams gives both."""
+    the streams gives both.
+
+    ``enter`` gives the sublayer's input ``H_pre X`` with the maps and
+    ``leave`` writes the streams back: by the Pallas kernels of ``streams.py``
+    where ``streams.fits`` says so (a TPU, a shape that tiles), else by the
+    plain functions below, which are the same arithmetic."""
 
     spec: LMSpec
 
-    @nn.compact
-    def __call__(self, streams):
+    def setup(self):
+        n, d = self.spec.hc_mult, self.spec.hidden_size
+        self.norm = Leaf("scale", (n * d,), nn.initializers.ones)
+        self.phi = Leaf("kernel", (n * d, n * (n + 2)), nn.initializers.normal(0.01))
+        self.alpha = Leaf("scale", (3,), nn.initializers.constant(0.01))
+        self.maps_bias = Leaf("bias", (n * (n + 2),), nn.initializers.zeros)
+
+    def _leaves(self):
+        """phi with the norm's scale folded in (n, d, n (n + 2)), as the
+        streams are held; the gate and the bias of every raw map."""
         sp = self.spec
         n, d = sp.hc_mult, sp.hidden_size
-        scale = Leaf("scale", (n * d,), nn.initializers.ones, name="norm")()
-        phi = Leaf("kernel", (n * d, n * (n + 2)), nn.initializers.normal(0.01), name="phi")()
-        alpha = Leaf("scale", (3,), nn.initializers.constant(0.01), name="alpha")()
-        bias = Leaf("bias", (n * (n + 2),), nn.initializers.zeros, name="maps_bias")()
+        weights = (self.norm()[:, None] * self.phi()).astype(sp.dtype).reshape(n, d, n * (n + 2))
+        return weights, self.alpha()[np.repeat(np.arange(3), [n, n, n * n])], self.maps_bias()
+
+    def _maps(self, raw, gate, bias, rounds=None):
+        sp = self.spec
+        n = sp.hc_mult
+        pre, post, res = jnp.split(
+            raw * gate[:, None, None] + bias[:, None, None], [n, 2 * n], axis=0)
+        res = res.reshape((n, n) + res.shape[1:]) + RES_START * jnp.eye(n)[:, :, None, None]
+        h_res = (rounds or sinkhorn)(res, sp.hc_sinkhorn_iters, sp.hc_eps, sp.hc_clamp)
+        err = jnp.maximum(jnp.max(jnp.abs(jnp.sum(h_res, axis=0) - 1.0)),
+                          jnp.max(jnp.abs(jnp.sum(h_res, axis=1) - 1.0)))
+        return jax.nn.sigmoid(pre), 2.0 * jax.nn.sigmoid(post), h_res, err
+
+    def __call__(self, streams):
         with jax.named_scope("hyper_connection/maps"):
-            weights = (scale[:, None] * phi).astype(sp.dtype).reshape(n, d, n * (n + 2))
-            raw = _normed_projection(streams, weights, sp.norm_eps)
-            gate = alpha[np.repeat(np.arange(3), [n, n, n * n])]
-            pre, post, res = jnp.split(
-                raw * gate[:, None, None] + bias[:, None, None], [n, 2 * n], axis=0)
-            res = res.reshape((n, n) + res.shape[1:]) + RES_START * jnp.eye(n)[:, :, None, None]
-            h_res = sinkhorn(res, sp.hc_sinkhorn_iters, sp.hc_eps, sp.hc_clamp)
-            err = jnp.maximum(jnp.max(jnp.abs(jnp.sum(h_res, axis=0) - 1.0)),
-                              jnp.max(jnp.abs(jnp.sum(h_res, axis=1) - 1.0)))
-            return jax.nn.sigmoid(pre), 2.0 * jax.nn.sigmoid(post), h_res, err
+            weights, gate, bias = self._leaves()
+            return self._maps(_normed_projection(streams, weights, self.spec.norm_eps), gate, bias)
+
+    def enter(self, streams):
+        """``H_pre X`` (b, s, d), the streams as ``leave`` is to take them,
+        ``H_post``, ``H_res`` and the sums' gap."""
+        n, b, s, d = streams.shape
+        if not streams_kernels.fits(b * s, d):
+            h_pre, h_post, h_res, err = self(streams)
+            return mix_in(streams, h_pre), streams, h_post, h_res, err
+        with jax.named_scope("hyper_connection/maps"):
+            weights, gate, bias = self._leaves()
+        mixed, raw, streams = streams_kernels.maps_and_mix_in(
+            streams, weights, gate[:n], bias[:n], self.spec.norm_eps)
+        with jax.named_scope("hyper_connection/maps"):
+            _, h_post, h_res, err = self._maps(raw, gate, bias, streams_kernels.rounds())
+        return mixed, streams, h_post, h_res, err
+
+
+def leave(streams, h_res, h_post, out):
+    """``H_res X + H_post^T F``, by the path ``HyperConnection.enter`` took."""
+    n, b, s, d = streams.shape
+    if streams_kernels.fits(b * s, d):
+        return streams_kernels.mix_out(streams, h_res, h_post, out)
+    return mix_out(streams, h_res, h_post, out)
 
 
 # The three passes over the streams are each under ``jax.checkpoint``: what they
@@ -183,19 +223,19 @@ class Block(nn.Module):
 
     def _mixer_sublayer(self, streams, mixer):
         sp = self.spec
-        h_pre, h_post, h_res, err = HyperConnection(sp, name="mixer_hc")(streams)
-        mixed = mixer(RMSNorm(sp.norm_eps, sp.dtype, name="mixer_norm")(mix_in(streams, h_pre)))
-        return mix_out(streams, h_res, h_post, mixed), err
+        mixed, streams, h_post, h_res, err = HyperConnection(sp, name="mixer_hc").enter(streams)
+        mixed = mixer(RMSNorm(sp.norm_eps, sp.dtype, name="mixer_norm")(mixed))
+        return leave(streams, h_res, h_post, mixed), err
 
     def _ffn_sublayer(self, streams, live):
         sp = self.spec
-        h_pre, h_post, h_res, err = HyperConnection(sp, name="ffn_hc")(streams)
-        normed = RMSNorm(sp.norm_eps, sp.dtype, name="ffn_norm")(mix_in(streams, h_pre))
+        mixed, streams, h_post, h_res, err = HyperConnection(sp, name="ffn_hc").enter(streams)
+        normed = RMSNorm(sp.norm_eps, sp.dtype, name="ffn_norm")(mixed)
         if self.block.ffn == "dense":
             out, rows = SwiGLU(sp, name="ffn")(normed), None
         else:
             out, rows = RoutedFFN(sp, name="ffn")(normed, live)
-        return mix_out(streams, h_res, h_post, out), rows, err
+        return leave(streams, h_res, h_post, out), rows, err
 
 
 def live_positions(targets):
@@ -326,6 +366,9 @@ class DecoderLM(nn.Module):
             out.setdefault("counters", {}).update({
                 "hyper_connection/res_sum_err": jnp.max(jnp.stack(sum_errs)),
                 "hyper_connection/sinkhorn_iters": jnp.float32(sp.hc_sinkhorn_iters),
+                # static: the sublayers whose passes took the kernels (streams.py)
+                "hyper_connection/fused_sublayers": jnp.float32(
+                    len(sum_errs) * 2 * streams_kernels.fits(tokens.size, sp.hidden_size)),
             })
         if return_logits:
             out["logits"] = jnp.einsum("bsd,vd->bsv", x, head,

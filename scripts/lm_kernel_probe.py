@@ -75,6 +75,8 @@ def main() -> int:
     ap.add_argument("--only", choices=("attention", "experts", "routed", "layer", "latent",
                                        "streams"), default=None)
     ap.add_argument("--streams", type=int, default=4, help="residual streams a token")
+    ap.add_argument("--tile", type=int, nargs="+", default=[128],
+                    help="--only streams: the kernels' tokens a tile, a set of rows each")
     args = ap.parse_args()
 
     sys.path.insert(0, ".")
@@ -149,20 +151,48 @@ def latent(args) -> None:
         print(json.dumps(row), flush=True)
 
 
+STREAM_STAGES = ("xla", "fused_mix_out", "fused_all", "fused_all_sinkhorn")
+
+
 def streams(args) -> None:
     """One hyper-connection sublayer both ways around a sublayer that costs
     nothing (``--only streams --batch 1 --seq 8192 --hidden 3584 --streams 4``):
     the maps (norm over the n d-wide streams, the phi product, 20 Sinkhorn
-    rounds) and both mixes as the program writes them, with the streams held in
-    bfloat16 and in float32.  Least bytes: the streams read once and written
-    once each way."""
+    rounds) and both mixes, a row a path and stage: ``xla`` the plain functions
+    (with the streams held in bfloat16 and in float32), ``fused_mix_out`` pass
+    B alone by its kernels, ``fused_all`` both passes with the rounds left to
+    XLA, ``fused_all_sinkhorn`` the rounds in their kernel too (what a TPU
+    runs: models/lm/streams.py), each at every ``--tile``.  Least bytes: the
+    streams read once and written once each way.  Design bytes: what the
+    stage's kernels move by their own count (pass A reads X and writes one
+    stream's width; pass B reads X and F and writes X; back, B reads g, X, F
+    and writes dX, dF; A reads X, dX, d_mixed and writes dX)."""
     from rt1_tpu.models.lm import model as lm_model
+    from rt1_tpu.models.lm import streams as kernels
     from rt1_tpu.models.lm.spec import LMSpec
     from rt1_tpu.train.configs import xing4_0
 
     lm = xing4_0.get_config().model.lm
     lm.hidden_size, lm.hc_mult = args.hidden, args.streams
-    for held in (jnp.bfloat16, jnp.float32):
+
+    def sublayer(maps, stage):
+        def fn(params, x):
+            if stage in ("xla", "fused_mix_out"):
+                h_pre, h_post, h_res, _ = maps.apply(params, x)
+                inside = lm_model.mix_in(x, h_pre)
+            else:
+                inside, x, h_post, h_res, _ = maps.apply(params, x, method="enter")
+            leave = lm_model.mix_out if stage == "xla" else kernels.mix_out
+            return leave(x, h_res, h_post, inside)
+        return fn
+
+    for held, stage, tile in ([(jnp.bfloat16, "xla", None), (jnp.float32, "xla", None)]
+                              + [(jnp.bfloat16, stage, tile) for tile in args.tile
+                                 for stage in STREAM_STAGES[1:]]
+                              + [(jnp.float32, STREAM_STAGES[-1], args.tile[0])]):
+        if tile is not None:
+            kernels.TILE_TOKENS = tile
+        kernels.SINKHORN_IN_KERNEL = stage == "fused_all_sinkhorn"
         spec = LMSpec.from_config(lm, held)
         x = jax.random.normal(
             jax.random.PRNGKey(0), (args.streams, args.batch, args.seq, args.hidden), held)
@@ -171,21 +201,20 @@ def streams(args) -> None:
         params = jax.tree.map(       # phi as a seed draws it: logits of N(0, 0.25)
             lambda a: a if a.ndim < 2 else 0.5 * jax.random.normal(
                 jax.random.PRNGKey(2), a.shape) / a.shape[0] ** 0.5, params)
-
-        def sublayer(params, x):
-            h_pre, h_post, h_res, _ = maps.apply(params, x)
-            inside = lm_model.mix_in(x, h_pre)
-            return lm_model.mix_out(x, h_res, h_post, inside)
-
+        fn = sublayer(maps, stage)
         step = jax.jit(jax.grad(
-            lambda p, x: jnp.sum(sublayer(p, x).astype(jnp.float32) ** 2), argnums=(0, 1)))
-        row = {"streams": args.streams, "held_in": jnp.dtype(held).name,
-               "tokens": args.batch * args.seq, "hidden": args.hidden}
+            lambda p, x: jnp.sum(fn(p, x).astype(jnp.float32) ** 2), argnums=(0, 1)))
+        row = {"path": stage, "tile_tokens": tile, "streams": args.streams,
+               "held_in": jnp.dtype(held).name, "tokens": args.batch * args.seq,
+               "hidden": args.hidden}
         try:
             row["fwd_bwd_ms"] = timed(step, params, x, repeats=20)
-            moved = 2 * 2 * x.size * x.dtype.itemsize
-            row["least_bytes_ms"] = round(moved / 819e9 * 1e3, 3)
-            row["device_ms"] = device_ms_by_op(step, params, x)
+            whole, one = x.size * x.dtype.itemsize, x.size * x.dtype.itemsize // args.streams
+            row["least_bytes_ms"] = round(2 * 2 * whole / 819e9 * 1e3, 3)
+            design = {"xla": None, "fused_mix_out": 5 * whole + 3 * one}.get(
+                stage, 9 * whole + 5 * one)
+            row["design_bytes_ms"] = design and round(design / 819e9 * 1e3, 3)
+            row["device_ms"] = device_ms_by_op(step, params, x, kernels=("streams_",))
         except Exception as exc:  # noqa: BLE001 - a probe reports and goes on
             row["error"] = repr(exc)[:300]
         print(json.dumps(row), flush=True)
@@ -309,7 +338,7 @@ def both_ways(fn):
         lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32)), argnums=(0, 1, 2)))
 
 
-def device_ms_by_op(fn, *args, runs=3):
+def device_ms_by_op(fn, *args, runs=3, kernels=("splash", "flash")):
     """Device time a run of each op of ``fn``'s program, from a profile of
     ``runs`` runs: the Pallas kernels under their own names, all else summed
     as ``around`` (transposes, repeats, the backward's row sums)."""
@@ -330,7 +359,7 @@ def device_ms_by_op(fn, *args, runs=3):
         for _, line, name, _, duration_ns in xplane.rows_from_xplane(xplane.find_xplane(logdir)):
             if line == "XLA Ops":
                 name = name.split(".")[0]
-                kernel = any(word in name for word in ("splash", "flash"))
+                kernel = any(word in name for word in kernels)
                 total[name if kernel else "around"] += duration_ns
         return {name: round(ns / runs / 1e6, 3) for name, ns in sorted(total.items())}
     finally:

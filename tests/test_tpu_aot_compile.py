@@ -331,3 +331,43 @@ def test_xing_latent_attention_compiles_both_ways_at_the_published_widths(v5e, m
     assert re.search(rf"bf16\[(1,)?{heads},{seq},{qk}\]", text)
     assert re.search(rf"bf16\[(1,)?{heads},{seq},{dv}\]", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+@pytest.mark.parametrize("held", ["bfloat16", "float32"])
+def test_xing_streams_kernels_compile_both_ways_at_the_published_widths(v5e, monkeypatch, held):
+    """One hyper-connection sublayer of ``xing4.0-29b-a4b`` both ways at the
+    cell's 4 streams x 8,192 tokens x 3,584: ``HyperConnection.enter`` and
+    ``leave`` take the six kernels of models/lm/streams.py (both passes and the
+    rounds, each with its backward), Mosaic accepts their tiles and VMEM, and
+    no float32 copy of the four streams is among the temporaries."""
+    from rt1_tpu.models.lm import model as lm_model, streams
+    from rt1_tpu.models.lm.spec import LMSpec
+    from rt1_tpu.train.configs import xing4_0
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")     # as on the chip
+    sp = LMSpec.from_config(xing4_0.get_config().model.lm, jnp.dtype(held))
+    n, tokens, d = sp.hc_mult, 8192, sp.hidden_size
+    assert (n, d) == (4, 3584) and streams.fits(tokens, d)
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    module = lm_model.HyperConnection(sp)
+    x = jax.ShapeDtypeStruct((n, 1, tokens, d), sp.dtype, sharding=one_chip)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(module.init, jax.random.PRNGKey(0), x))
+
+    def both_ways(params, x):
+        def loss(params, x):
+            inside, streams_, h_post, h_res, _ = module.apply(params, x, method="enter")
+            return jnp.sum(lm_model.leave(streams_, h_res, h_post, inside).astype(jnp.float32))
+        return jax.value_and_grad(loss, argnums=(0, 1))(params, x)
+
+    compiled = jax.jit(both_ways).lower(params, x).compile()
+    text = compiled.as_text()
+    kernels = {name for line in text.splitlines() if "tpu_custom_call" in line
+               for name in re.findall(r"%(streams_\w+?)[.\d]* = ", line)}
+    assert sorted(kernels) == ["streams_maps", "streams_maps_back", "streams_mix_out",
+                               "streams_mix_out_back", "streams_sinkhorn",
+                               "streams_sinkhorn_back"]
+    # the streams' cotangent from pass B's backward and one stream's width or two
+    whole = n * tokens * d * jnp.dtype(held).itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.6 * whole
