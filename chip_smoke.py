@@ -89,9 +89,11 @@ PALLAS_TOL = {"bfloat16": 0.1, "float32": 2e-3}
 SHARDED_LOSS_RTOL_THROUGH_FIRST_UPDATE = 5e-3
 SHARDED_LOSS_RTOL_LATER = 0.15
 
+# The server child writes no goodput summary: its cache traffic is still
+# counted off its log. The trainer's comes from the start-up log's snapshot
+# in its goodput_summary.json (rt1_tpu/obs/startup.py).
 _HIT = re.compile(r"Persistent compilation cache hit for '([^']+)'")
 _MISS = re.compile(r"PERSISTENT COMPILATION CACHE MISS for '([^']+)'")
-_TOO_FAST = re.compile(r"Not writing persistent cache entry for '([^']+)'")
 _DEVICES = re.compile(r"devices: platform=(\S+) device_kind=(.+?) count=(\d+)")
 _PLACEMENT = re.compile(
     r"state placement: params\+opt_state total_bytes=(\d+) "
@@ -116,8 +118,8 @@ def _load_config(path: str):
 def _child_env(platform: str, chips: int, one_device: bool) -> Dict[str, str]:
     """Environment of a phase child on a machine with `chips` devices;
     `one_device` shows it a single one of them. Cache-hit logging is on
-    for every child: it is how the parent counts persistent-cache hits
-    without a hook in the program."""
+    for every child: it is how the parent counts the server's
+    persistent-cache hits (the trainer's come from its start-up log)."""
     env = dict(os.environ)
     env["JAX_DEBUG_LOG_MODULES"] = "jax._src.compiler"
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
@@ -297,12 +299,10 @@ class Smoke:
             raise PhaseFailed(f"{phase}: non-finite loss {bad}")
         with open(os.path.join(workdir, "goodput_summary.json")) as f:
             goodput = json.load(f)
-        hits, misses = _HIT.findall(log), _MISS.findall(log)
-        step_programs = [n for n in hits + misses if "train_step" in n]
-        # jax writes no cache entry for a compile under its time floor
-        # (1 s, rt1_tpu/compilation_cache.py) — only the tiny CPU
-        # rehearsal compiles the step that fast.
-        unwritten = [n for n in _TOO_FAST.findall(log) if "train_step" in n]
+        # What the child's own start-up log counted: every backend compile
+        # or fetch, and the train step's by its role.
+        totals = goodput["startup"]["totals"]
+        step = goodput["startup"]["roles"].get("train_step")
         placement = _PLACEMENT.search(log)
         peak = _PEAK.search(log)
         return {
@@ -315,13 +315,17 @@ class Smoke:
             "ckpt_restore_seconds": round(
                 goodput["buckets_s"]["ckpt_restore"], 2
             ),
-            "cache_hits": len(hits),
-            "cache_misses": len(misses),
+            "cache_hits": totals["cache_hits"],
+            "cache_misses": totals["compiles"] - totals["cache_hits"],
             "train_step_cache": (
-                "hit" if any(n in hits for n in step_programs)
-                else "miss" if step_programs else "not compiled"
+                "not compiled" if step is None or not step["compiles"]
+                else "hit" if step["cache_hits"] else "miss"
             ),
-            "train_step_in_cache_after": bool(step_programs) and not unwritten,
+            # jax writes no cache entry for a compile under its time floor
+            # (1 s, rt1_tpu/compilation_cache.py) — only the tiny CPU
+            # rehearsal compiles the step that fast.
+            "train_step_in_cache_after": step is not None
+            and step["cache_hits"] + step["cache_writes"] > 0,
             "peak_bytes_in_use": int(peak.group(1)) if peak else None,
             "memory_stats": json.loads(peak.group(2)) if peak else None,
             "state_total_bytes": (

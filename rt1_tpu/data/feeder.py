@@ -61,6 +61,7 @@ import numpy as np
 
 from rt1_tpu.data.pack import UNKNOWN_TASK, PackedEpisodeCache
 from rt1_tpu.obs.health import TASK_ID_KEY
+from rt1_tpu.obs import startup
 from rt1_tpu.obs import trace as obs_trace
 from rt1_tpu.resilience import faults
 
@@ -143,6 +144,7 @@ class SampleAheadFeeder:
     `WindowedEpisodeDataset`'s loaders, with uint8 images.
     """
 
+    @startup.phased("open_feed")
     def __init__(
         self,
         cache: PackedEpisodeCache,

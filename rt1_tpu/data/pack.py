@@ -58,6 +58,7 @@ import numpy as np
 
 from rt1_tpu.data import episodes as ep_lib
 from rt1_tpu.data.pipeline import _crop_box, crop_resize_frames
+from rt1_tpu.obs import startup
 from rt1_tpu.resilience import faults
 
 MANIFEST_NAME = "pack_manifest.json"
@@ -570,6 +571,7 @@ class PackedEpisodeCache:
     (seed, epoch, corpus-at-epoch-start).
     """
 
+    @startup.phased("open_feed")
     def __init__(self, pack_dir: str, window: int = 6, clip_tokenizer=None):
         self.pack_dir = pack_dir
         self.manifest = load_manifest(pack_dir)

@@ -26,6 +26,7 @@ from typing import Callable, Dict, Iterator, List, Optional, Sequence, Tuple
 import numpy as np
 
 from rt1_tpu.data import episodes as ep_lib
+from rt1_tpu.obs import startup
 from rt1_tpu.obs import trace as obs_trace
 
 
@@ -425,7 +426,13 @@ def device_feeder(iterator, batch_sharding, depth: int = 1) -> Iterator:
     its block of the global batch and `put_global` assembles the global
     `jax.Array` via `jax.make_array_from_process_local_data`; single-process
     keeps the plain async `device_put`. `depth=2` double-buffers (see
-    `prefetch_to_device`)."""
-    return prefetch_to_device(
+    `prefetch_to_device`). The first batch (the host feed's first pulls and
+    the first copies to the device) is the start-up log's `first_batch`."""
+    batches = prefetch_to_device(
         map(to_obs_actions, iterator), batch_sharding, depth=depth
     )
+    with startup.phase("first_batch"):
+        first = next(batches, None)
+    if first is not None:
+        yield first
+        yield from batches

@@ -24,6 +24,7 @@ from typing import Any, Dict, Iterator, List
 
 import numpy as np
 
+from rt1_tpu.obs import startup
 from rt1_tpu.obs import trace as obs_trace
 
 IGNORE = -1     # models/lm/spec.py's; not imported, so that this module needs no jax
@@ -37,6 +38,7 @@ def document_lengths(corpus_seed: int, documents: int, median: float, sigma: flo
 
 
 class PackedTokenFeed:
+    @startup.phased("open_feed")
     def __init__(self, *, batch_size: int, seq_len: int, vocab: int, seed: int,
                  corpus_seed: int = 20240801, documents: int = 4096,
                  doc_len_median: float = 1024, doc_len_sigma: float = 1.0,

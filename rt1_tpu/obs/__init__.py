@@ -1,6 +1,6 @@
 """rt1_tpu.obs — unified observability across train, data, and serve.
 
-One subsystem, nine pieces, all optional and all cheap when off:
+One subsystem, ten pieces, all optional and all cheap when off:
 
 * :mod:`rt1_tpu.obs.trace`      — host-side Chrome-trace span recorder
   (Perfetto-loadable); train loop, feeder workers, and serve batcher emit
@@ -17,6 +17,9 @@ One subsystem, nine pieces, all optional and all cheap when off:
   the jitted step, fetched only at log steps.
 * :mod:`rt1_tpu.obs.goodput`    — `GoodputLedger`: run-level wall-time
   partition (init/compile/step/stall/ckpt/rollback/preempt) + live MFU.
+* :mod:`rt1_tpu.obs.startup`    — the start-up log: set-up phases
+  (`rt1/setup/*`) and every trace, lowering and compile by function name
+  from `jax.monitoring`; feeds the ledger's `compile` bucket, always on.
 * :mod:`rt1_tpu.obs.flops`      — XLA cost-analysis FLOPs + MFU math,
   shared by `bench.py --mode mfu` and the goodput ledger.
 * :mod:`rt1_tpu.obs.slo`        — serving SLO ledger: request outcome
@@ -46,6 +49,7 @@ from rt1_tpu.obs import (
     quantiles,
     recorder,
     slo,
+    startup,
     steps,
     trace,
 )
@@ -73,6 +77,7 @@ __all__ = [
     "quantiles",
     "recorder",
     "slo",
+    "startup",
     "steps",
     "trace",
 ]

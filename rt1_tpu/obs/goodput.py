@@ -13,11 +13,16 @@ Buckets:
 
 * ``init``            — process start to the first loop step: model build,
   dataset open, state init, sharding (checkpoint restore time is carved
-  out into ``ckpt_restore`` even when it happens inside init).
-* ``compile``         — the first executed step's whole wall time (XLA
-  compilation dominates it; subsequent steps hit the executable cache).
+  out into ``ckpt_restore`` even when it happens inside init, and what
+  was traced or compiled there into ``compile``).
+* ``compile``         — the seconds the start-up log measured
+  (:mod:`rt1_tpu.obs.startup`): tracing, lowering, backend compiles and
+  fetches from the persistent cache, the whole run's. They are carved out
+  of the phase or the step they fell into (a step's record carries its
+  ``compile_ms``), so a recompile in mid-run leaves ``step``.
 * ``step``            — productive step time: everything in a non-replay
-  step except its input-stall share. This is the GOODPUT bucket.
+  step except its input-stall share and what it compiled. This is the
+  GOODPUT bucket.
 * ``data_stall``      — the ``wait_data + h2d`` share of productive steps
   (from the StepTimeline records the loop already produces).
 * ``ckpt_save`` / ``ckpt_restore`` — checkpoint I/O, reported by the
@@ -55,6 +60,8 @@ import threading
 import time
 from typing import Any, Callable, Dict, Mapping, Optional
 
+from rt1_tpu.obs import startup
+
 #: Reporting order; ``unattributed`` is always computed, never accrued.
 BUCKETS = (
     "init",
@@ -77,9 +84,17 @@ SUMMARY_BASENAME = "goodput_summary.json"
 class GoodputLedger:
     """Accrues run wall time into `BUCKETS`; fractions sum to 100%."""
 
-    def __init__(self, clock: Callable[[], float] = time.perf_counter):
+    def __init__(
+        self,
+        clock: Callable[[], float] = time.perf_counter,
+        compile_seconds: Callable[[], float] = startup.compile_seconds,
+    ):
         self._clock = clock
         self._t0 = clock()
+        # The process's measured compile seconds so far; the bucket is what
+        # they grew by since this ledger began.
+        self._compile_seconds = compile_seconds
+        self._compile0 = compile_seconds()
         self._lock = threading.Lock()
         self._buckets: Dict[str, float] = {b: 0.0 for b in BUCKETS[:-1]}
         self._steps_productive = 0
@@ -95,6 +110,7 @@ class GoodputLedger:
         self._phase_name: Optional[str] = None
         self._phase_t0 = 0.0
         self._phase_stolen = 0.0
+        self._phase_compile0 = 0.0
 
     # ------------------------------------------------------------- phases
 
@@ -109,13 +125,20 @@ class GoodputLedger:
             self._phase_name = name
             self._phase_t0 = self._clock()
             self._phase_stolen = 0.0
+            self._phase_compile0 = self._compile_seconds()
+
+    def _phase_seconds(self) -> float:
+        """The open phase's own time so far: less the I/O reported inside
+        it and what was traced or compiled inside it. The lock is held."""
+        compiled = self._compile_seconds() - self._phase_compile0
+        dt = self._clock() - self._phase_t0 - self._phase_stolen - compiled
+        return max(dt, 0.0)
 
     def close_phase(self) -> None:
         with self._lock:
             if self._phase_name is None:
                 raise RuntimeError("no open phase")
-            dt = self._clock() - self._phase_t0 - self._phase_stolen
-            self._buckets[self._phase_name] += max(dt, 0.0)
+            self._buckets[self._phase_name] += self._phase_seconds()
             self._phase_name = None
 
     @contextlib.contextmanager
@@ -147,22 +170,21 @@ class GoodputLedger:
     def note_step(self, record: Mapping[str, Any], replay: bool = False) -> None:
         """Consume one StepTimeline record (ms buckets, see obs/steps.py).
 
-        The first record of the run goes wholesale to ``compile``; replayed
-        steps (post-rollback re-runs) go wholesale to ``rollback_replay``;
+        What the step traced or compiled (``compile_ms``) is taken out
+        first: it is in ``compile``. Of the rest, replayed steps
+        (post-rollback re-runs) go wholesale to ``rollback_replay``;
         everything else splits into ``data_stall`` (wait_data + h2d) and
         ``step`` (the productive remainder).
         """
         total = float(record.get("total_ms", 0.0)) / 1e3
+        total = max(total - float(record.get("compile_ms", 0.0)) / 1e3, 0.0)
         stall = (
             float(record.get("wait_data_ms", 0.0))
             + float(record.get("h2d_ms", 0.0))
         ) / 1e3
         stall = min(max(stall, 0.0), max(total, 0.0))
         with self._lock:
-            first = self._steps_productive == 0 and self._steps_replayed == 0
-            if first and self._buckets["compile"] == 0.0:
-                self._buckets["compile"] += total
-            elif replay:
+            if replay:
                 self._buckets["rollback_replay"] += total
                 self._steps_replayed += 1
             else:
@@ -197,9 +219,9 @@ class GoodputLedger:
         """Buckets incl. live partial of an open phase (scrape-safe)."""
         with self._lock:
             out = dict(self._buckets)
+            out["compile"] = max(self._compile_seconds() - self._compile0, 0.0)
             if self._phase_name is not None:
-                live = self._clock() - self._phase_t0 - self._phase_stolen
-                out[self._phase_name] += max(live, 0.0)
+                out[self._phase_name] += self._phase_seconds()
             return out
 
     def wall_s(self) -> float:
@@ -270,9 +292,11 @@ class GoodputLedger:
             out[f"{prefix}mfu_pct"] = s["mfu_pct"]
         return out
 
-    def write_summary(self, path: str) -> str:
-        """Write the JSON summary (the run_report/post-mortem artifact)."""
-        summary = self.summary()
+    def write_summary(self, path: str, **extra: Any) -> str:
+        """Write the JSON summary (the run_report/post-mortem artifact);
+        `extra` keys ride beside the ledger's (the trainer hands in
+        `startup=`, the start-up log's snapshot)."""
+        summary = dict(self.summary(), **extra)
         parent = os.path.dirname(os.path.abspath(path))
         os.makedirs(parent, exist_ok=True)
         with open(path, "w") as f:

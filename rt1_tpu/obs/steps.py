@@ -51,7 +51,7 @@ import threading
 import time
 from typing import Any, Dict, Iterator, Optional
 
-from rt1_tpu.obs import trace
+from rt1_tpu.obs import startup, trace
 
 BUCKETS = ("wait_data", "h2d", "device_step", "host")
 
@@ -77,6 +77,7 @@ class StepTimeline:
         self._cur: Optional[Dict[str, float]] = None
         self._cur_step = -1
         self._t0 = 0.0
+        self._compile0 = 0.0
         self._step_span = None
 
     # ------------------------------------------------------------ recording
@@ -105,6 +106,10 @@ class StepTimeline:
         self._cur = dict(self._orphan)
         self._orphan = {}
         self._cur_step = step
+        # a trace, lowering, compile or fetch inside the step is named with
+        # the step (obs/startup.py) and its seconds go into the record
+        startup.set_step(step)
+        self._compile0 = startup.compile_seconds()
         self._t0 = time.perf_counter()
         self._step_span = trace.span("step", step=step)
         self._step_span.__enter__()
@@ -144,6 +149,8 @@ class StepTimeline:
                 jax.block_until_ready(sync_on)
             self._add("device_step", time.perf_counter() - t0)
         total = time.perf_counter() - self._t0
+        compile_s = startup.compile_seconds() - self._compile0
+        startup.set_step(None)
         cur, self._cur = self._cur, None
         if self._step_span is not None:
             self._step_span.__exit__(None, None, None)
@@ -157,6 +164,7 @@ class StepTimeline:
             "step": self._cur_step,
             "total_ms": total * 1e3,
             "stall_pct": (input_s / total * 100.0) if total > 0 else 0.0,
+            "compile_ms": compile_s * 1e3,
         }
         for b in BUCKETS:
             record[f"{b}_ms"] = buckets[b] * 1e3

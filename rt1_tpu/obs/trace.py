@@ -198,20 +198,6 @@ class TraceRecorder:
         the waiter stamps both ends and emits the span after the fact."""
         self._complete(name, ts_us, max(dur_us, 0.0), args or None)
 
-    def instant(self, name: str, **args) -> None:
-        """Point-in-time marker (thread-scoped)."""
-        event = {
-            "ph": "i",
-            "s": "t",
-            "name": name,
-            "pid": self._pid,
-            "tid": self._tid(),
-            "ts": _now_us(),
-        }
-        if args:
-            event["args"] = args
-        self._append(event)
-
     def counter(self, name: str, value: float, **series) -> None:
         """Counter track (queue depths, gauge time-series)."""
         self._append(
@@ -325,12 +311,6 @@ def span(name: str, **args):
         return _NULL_SPAN if t is None else t.span(name, **args)
     note = profiler.TraceAnnotation(PROFILE_PREFIX + name, **args)
     return note if t is None else _Both(note, t.span(name, **args))
-
-
-def instant(name: str, **args) -> None:
-    t = _tracer
-    if t is not None:
-        t.instant(name, **args)
 
 
 def complete(name: str, ts_us: float, dur_us: float, **args) -> None:

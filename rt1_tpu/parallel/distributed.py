@@ -229,8 +229,14 @@ def describe_devices() -> Dict[str, Any]:
     """
     import jax
 
+    from rt1_tpu.obs import startup
+
+    # The start-up log's listeners go in before anything is traced, on the
+    # trainer's path and the benchmark's alike (obs/startup.py).
+    startup.install()
     try:
-        devices = jax.devices()
+        with startup.phase("backend_init"):
+            devices = jax.devices()
     except RuntimeError as exc:
         raise RuntimeError(
             f"cannot initialize the accelerator backend: {exc}\n"

@@ -57,6 +57,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from rt1_tpu.obs import startup
 from rt1_tpu.obs import trace as obs_trace
 
 EPS = np.finfo(np.float32).eps
@@ -354,10 +355,12 @@ class PolicyEngine:
                 return b
         return self.buckets[-1]  # unreachable: buckets top at max_sessions
 
+    @startup.phased("compile_buckets")
     def _build_step(self, obs_shapes: Dict[str, Tuple[int, ...]]):
         """Lower + compile the batched step for EVERY bucket at fixed
         per-item obs shapes — compile_count lands at len(buckets) and
-        never moves again."""
+        never moves again. A set-up phase of the start-up log: one
+        function compiled once a bucket is set-up's work, not a recompile."""
         import jax
         import jax.numpy as jnp
 

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from rt1_tpu.obs import startup
 from rt1_tpu.specs import language_table_action_space, sample_space
 from rt1_tpu.train.families import family_of
 from rt1_tpu.trainer import (
@@ -125,6 +126,7 @@ def build_model(model_config, mesh=None):
     )
 
 
+@startup.phased("build_model")
 def build_family(model_config, mesh=None):
     """(model, init_fn, loss_fn) for ``config.model.family``, from the
     family's record (rt1_tpu/train/families.py).
@@ -656,7 +658,10 @@ def train_and_evaluate(config, workdir: str):
     # the CURRENT plan's target shardings, so a checkpoint saved under a
     # different mesh/plan (a bigger slice, dense vs fsdp) resumes directly
     # in this run's layout instead of relying on a layout coincidence.
-    state, initial_step = ckpt.restore_or_initialize(state, plan=sharding_plan)
+    with startup.phase("restore"):
+        state, initial_step = ckpt.restore_or_initialize(
+            state, plan=sharding_plan
+        )
 
     fns = make_train_step_fns(
         model, mesh, state, accum_steps=config.accum_steps, loss_fn=loss_fn,
@@ -792,6 +797,9 @@ def train_and_evaluate(config, workdir: str):
             # latest_scalars from the last log step).
             if ledger is not None:
                 scalars.update(ledger.scalars())
+            # rt1_train_compile_*: what the start-up log has measured so
+            # far; a recompile in mid-run shows on the next scrape.
+            scalars.update(startup.scalars())
             body = obs.prometheus.render_scalar_gauges(scalars)
             # rt1_flywheel_*: live corpus-growth gauges — a scrape during
             # an epoch shows the shard pickup the moment the feeder takes
@@ -893,7 +901,9 @@ def train_and_evaluate(config, workdir: str):
         from absl import logging
 
         try:
-            path = ledger.write_summary(obs_opts.goodput_summary_path)
+            path = ledger.write_summary(
+                obs_opts.goodput_summary_path, startup=startup.snapshot()
+            )
             s = ledger.summary()
             logging.info(
                 "obs: goodput summary at %s (goodput %.1f%%, badput %.1f%%"
@@ -908,6 +918,11 @@ def train_and_evaluate(config, workdir: str):
     # Steps at or before this mark are post-rollback re-runs — badput the
     # ledger books as rollback_replay, not productive step time.
     replay_until = initial_step
+    # The first call of the step traces, lowers and compiles it (or fetches
+    # it from the persistent cache): the start-up log's last phase, and the
+    # block is logged once it has closed.
+    no_phase = contextlib.nullcontext()
+    first_call = startup.phase("first_step", step=initial_step)
     cleanup = contextlib.ExitStack()
     cleanup.callback(_obs_teardown)
     cleanup.callback(_close_host_iter)
@@ -931,7 +946,7 @@ def train_and_evaluate(config, workdir: str):
             with step_trace("train", step):
                 with timeline.phase("h2d", exclusive_of="wait_data"):
                     batch = next(dev_iter)
-                with timeline.phase("device_step"):
+                with timeline.phase("device_step"), first_call:
                     step_rng = jax.random.fold_in(rng, step)
                     if fns.guarded:
                         state, guard_skips, metrics = fns.train_step(
@@ -944,6 +959,9 @@ def train_and_evaluate(config, workdir: str):
             step_record = timeline.end_step(sync_on=metrics.get("loss"))
             if ledger is not None:
                 ledger.note_step(step_record, replay=step < replay_until)
+            if first_call is not no_phase:
+                first_call = no_phase
+                logging.info("%s", "\n".join(startup.block(startup.snapshot())))
 
             log_now = (step + 1) % config.log_every_steps == 0
             verdict = resilience.GuardVerdict.OK
@@ -972,6 +990,7 @@ def train_and_evaluate(config, workdir: str):
                 scalars.update(timeline.scalars())
                 if ledger is not None:
                     scalars.update(ledger.scalars())
+                scalars.update(startup.scalars())
                 if feeder_stats is not None:
                     scalars.update(
                         {

@@ -17,6 +17,8 @@ from typing import Optional, Sequence
 
 import optax
 
+from rt1_tpu.obs import startup
+
 
 def multistep_lr(
     base_lr: float,
@@ -29,6 +31,7 @@ def multistep_lr(
     return optax.piecewise_constant_schedule(base_lr, boundaries)
 
 
+@startup.phased("make_optimizer")
 def make_optimizer(
     learning_rate: float = 5e-4,
     milestones: Sequence[int] = (50, 75, 90),

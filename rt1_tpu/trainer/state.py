@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from rt1_tpu.obs import startup
+
 
 @flax.struct.dataclass
 class TrainState:
@@ -49,6 +51,7 @@ class TrainState:
         return (new_state, updates) if return_updates else new_state
 
 
+@startup.phased("init_state")
 def create_train_state(
     model: Any,
     rng: jax.Array,

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from rt1_tpu.obs import startup
 from rt1_tpu.parallel import plan as planlib
 from rt1_tpu.parallel import sharding as shardlib
 from rt1_tpu.trainer.state import TrainState
@@ -59,6 +60,7 @@ class TrainStepFns:
     # unpacks the fetched vector against these at log steps.
     health_names: Tuple[str, ...] = ()
 
+    @startup.phased("shard_state")
     def shard_state(self, state: TrainState) -> TrainState:
         """Place the state per the plan. Multi-process meshes cannot
         `device_put` host values onto non-addressable devices; there the
@@ -124,6 +126,7 @@ def _loss_fn(model, params, batch_stats, batch: Batch, rng: jax.Array, train: bo
     return out["loss"], (out, new_bs)
 
 
+@startup.phased("make_step_fns")
 def make_train_step_fns(
     model: Any,
     mesh: Mesh,
@@ -418,6 +421,10 @@ def make_train_step_fns(
         metrics = dict(metrics, guard_skips_cum=skips)
         return new_state, skips, metrics
 
+    # The start-up log reads the step's trace, lowering and compile by role.
+    startup.mark_role(
+        "train_step", (train_step_guarded if guard_nonfinite else train_step).__name__)
+    startup.mark_role("eval_step", eval_step.__name__)
     with mesh:
         if guard_nonfinite:
             train_jit = jax.jit(

@@ -40,7 +40,7 @@ if _REPO not in sys.path:  # runnable as `python scripts/run_report.py`
 # Goodput bucket reporting order + one-line meanings for the table.
 _BUCKET_NOTES = {
     "init": "model/dataset/state setup",
-    "compile": "first step (XLA compilation)",
+    "compile": "tracing, lowering, compiles and cache fetches (measured)",
     "step": "productive train steps (GOODPUT)",
     "data_stall": "input pipeline wait inside steps",
     "ckpt_save": "checkpoint saves (retries included)",
@@ -315,6 +315,13 @@ def render_goodput(goodput: Optional[Dict[str, Any]]) -> List[str]:
         extras.append("run was PREEMPTED (saved and exited 0)")
     if extras:
         lines.append("Events: " + "; ".join(extras) + ".")
+    if goodput.get("startup"):
+        # The start-up log's snapshot (obs/startup.py): set-up phases and
+        # every trace, lowering and compile by function name.
+        from rt1_tpu.obs import startup
+
+        lines.append("")
+        lines.extend(startup.block(goodput["startup"]))
     return lines
 
 

@@ -3,8 +3,10 @@
 The contract the tests pin: every second of wall time lands in exactly one
 bucket, the fractions sum to exactly 1.0 no matter what sequence of
 phases/steps/IO/rollbacks/preemptions occurred, checkpoint I/O inside an
-open phase is carved out (not double-counted), and replayed steps are
-badput — plus the MFU gauge arithmetic and the summary JSON round-trip.
+open phase is carved out (not double-counted), what the start-up log measured
+as tracing and compiling is `compile` and is carved out of the phase or step
+it fell into, and replayed steps are badput — plus the MFU gauge arithmetic
+and the summary JSON round-trip.
 """
 
 import json
@@ -25,11 +27,22 @@ class FakeClock:
         return self.t
 
 
-def _step_record(total_ms, wait_ms=0.0, h2d_ms=0.0):
+class FakeCompiled:
+    """The start-up log's cumulative compile seconds, by hand."""
+
+    def __init__(self):
+        self.seconds = 0.0
+
+    def __call__(self):
+        return self.seconds
+
+
+def _step_record(total_ms, wait_ms=0.0, h2d_ms=0.0, compile_ms=0.0):
     return {
         "total_ms": total_ms,
         "wait_data_ms": wait_ms,
         "h2d_ms": h2d_ms,
+        "compile_ms": compile_ms,
     }
 
 
@@ -39,14 +52,16 @@ def clock():
 
 
 def test_full_run_partition_sums_to_exactly_one(clock):
-    led = GoodputLedger(clock=clock)
+    compiled = FakeCompiled()
+    led = GoodputLedger(clock=clock, compile_seconds=compiled)
 
     with led.phase("init"):
         clock.advance(10.0)
         led.note_io("ckpt_restore", 4.0)  # restore during init: carved out
-    # First step = compile.
+    # The first step traces, lowers and compiles for all of its 30 s.
     clock.advance(30.0)
-    led.note_step(_step_record(30_000.0))
+    compiled.seconds += 30.0
+    led.note_step(_step_record(30_000.0, compile_ms=30_000.0))
     # Three productive steps, 20% input-stalled each.
     for _ in range(3):
         clock.advance(1.0)
@@ -80,7 +95,7 @@ def test_full_run_partition_sums_to_exactly_one(clock):
     # schedule) -> denominator max() keeps fractions exact.
     assert sum(s["fractions"].values()) == pytest.approx(1.0, abs=1e-12)
     assert set(s["buckets_s"]) == set(BUCKETS)
-    assert s["steps_productive"] == 3
+    assert s["steps_productive"] == 4  # the first step is one, at 0 s of its own
     assert s["steps_replayed"] == 2
     assert s["rollbacks"] == 1
     assert s["preempted"] is True
@@ -93,7 +108,7 @@ def test_full_run_partition_sums_to_exactly_one(clock):
 def test_unattributed_absorbs_uninstrumented_time(clock):
     led = GoodputLedger(clock=clock)
     clock.advance(5.0)
-    led.note_step(_step_record(1000.0))  # compile
+    led.note_step(_step_record(1000.0))
     clock.advance(7.0)  # nobody claims this
     s = led.summary()
     assert s["buckets_s"]["unattributed"] == pytest.approx(11.0)
@@ -103,7 +118,6 @@ def test_unattributed_absorbs_uninstrumented_time(clock):
 
 def test_stall_clamped_to_step_total(clock):
     led = GoodputLedger(clock=clock)
-    led.note_step(_step_record(100.0))  # compile
     # Degenerate record (clock jitter): stall claims more than the total.
     led.note_step(_step_record(100.0, wait_ms=80.0, h2d_ms=40.0))
     b = led.summary()["buckets_s"]
@@ -145,7 +159,6 @@ def test_unknown_io_kind_folds_into_ckpt_save(clock):
 def test_mfu_gauge_arithmetic(clock):
     led = GoodputLedger(clock=clock)
     assert led.mfu_pct() is None  # disarmed
-    led.note_step(_step_record(100.0))  # compile
     led.set_flops_per_step(1e12, peak_flops=200e12, n_chips=2)
     assert led.mfu_pct() is None  # no productive steps yet
     for _ in range(4):
@@ -176,7 +189,6 @@ def test_unknown_device_kind_has_no_peak_and_no_mfu(clock, caplog):
     led.set_flops_per_step(
         1e12, peak_flops=flops.peak_flops("Imaginary TPU v99"), n_chips=1
     )
-    led.note_step(_step_record(100.0))  # compile
     clock.advance(0.1)
     led.note_step(_step_record(100.0))
     assert led.mfu_pct() is None
@@ -207,3 +219,33 @@ def test_scalars_render_as_rt1_train_goodput_gauges(clock):
     assert "# TYPE rt1_train_goodput_compile_s gauge" in text
     assert "rt1_train_goodput_goodput_pct" in text
     assert "rt1_train_goodput_badput_pct" in text
+
+
+def test_compile_inside_a_phase_and_between_steps(clock):
+    """What was traced inside `init` leaves `init`; what was compiled
+    between steps (an eval step's first call) comes out of `unattributed`."""
+    compiled = FakeCompiled()
+    compiled.seconds = 5.0      # the process compiled before this ledger began
+    led = GoodputLedger(clock=clock, compile_seconds=compiled)
+    led.open_phase("init")
+    clock.advance(8.0)
+    compiled.seconds += 3.0
+    assert led.summary()["buckets_s"]["init"] == pytest.approx(5.0)  # live
+    led.close_phase()
+    clock.advance(1.0)
+    led.note_step(_step_record(1000.0))
+    clock.advance(2.0)
+    compiled.seconds += 2.0     # between steps
+    b = led.summary()["buckets_s"]
+    assert b["init"] == pytest.approx(5.0)
+    assert b["compile"] == pytest.approx(5.0)
+    assert b["step"] == pytest.approx(1.0)
+    assert b["unattributed"] == pytest.approx(0.0)
+
+
+def test_write_summary_carries_extra_keys(tmp_path, clock):
+    led = GoodputLedger(clock=clock, compile_seconds=FakeCompiled())
+    path = led.write_summary(str(tmp_path / "g.json"), startup={"totals": {"traces": 3}})
+    loaded = read_summary(path)
+    assert loaded["startup"] == {"totals": {"traces": 3}}
+    assert set(loaded["buckets_s"]) == set(BUCKETS)

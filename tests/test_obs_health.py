@@ -472,7 +472,20 @@ def test_train_loop_emits_health_goodput_and_report(tmp_path, monkeypatch):
     goodput = run_report.load_goodput(workdir)
     assert goodput is not None
     assert sum(goodput["fractions"].values()) == pytest.approx(1.0, abs=0.01)
-    assert goodput["steps_productive"] == 3  # step 0 went to compile
+    assert goodput["steps_productive"] == 4  # step 0 too, less what it compiled
+    # The start-up log rides in the summary, and the ledger's compile bucket
+    # is what the log measured over this run.
+    from rt1_tpu.obs import startup
+
+    snap = goodput["startup"]
+    assert {"backend_init", "build_model", "make_optimizer", "init_state", "restore",
+            "make_step_fns", "shard_state", "first_batch", "first_step"} <= set(snap["phase_s"])
+    step_role = snap["roles"]["train_step"]
+    assert step_role["functions"] == ["train_step_guarded"]
+    assert step_role["inner_traces"] > 0 and step_role["compiles"] >= 1
+    assert 0 < goodput["buckets_s"]["compile"] <= startup.compile_seconds()
+    assert goodput["buckets_s"]["compile"] >= (
+        step_role["trace_s"] + step_role["lower_s"] + step_role["backend_s"]) * 0.99
     assert "mfu_pct" in goodput and goodput["flops_per_step"] > 0
 
     tb = run_report.load_tb_scalars(workdir)
@@ -485,6 +498,8 @@ def test_train_loop_emits_health_goodput_and_report(tmp_path, monkeypatch):
     goodput_tags = [t for t in tb if t.startswith("goodput/")]
     assert "goodput/goodput_pct" in goodput_tags
     assert "goodput/mfu_pct" in goodput_tags
+    assert {"compile/seconds_total", "compile/compiles_total",
+            "compile/recompiles_total"} <= set(tb)
 
     report = run_report.render_report(
         workdir, goodput, run_report.load_flight(workdir), tb

@@ -24,6 +24,15 @@ sys.meta_path.insert(0, Blocker())
 
 import rt1_tpu.obs as obs
 
+# The start-up log (PR 36) comes with the package: stdlib at import, jax
+# looked up and never imported, and a phase works where jax is not loaded.
+assert "jax" not in sys.modules and "tensorflow" not in sys.modules
+with obs.startup.phase("probe"):
+    pass
+assert obs.startup.install() is False
+assert obs.startup.snapshot()["phase_s"]["probe"]["count"] == 1
+assert obs.startup.compile_seconds() == 0.0
+
 # The pieces a serve-only deployment touches must all be live.
 tracer = obs.trace.enable()
 with obs.trace.span("probe"):

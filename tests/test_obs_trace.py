@@ -36,8 +36,8 @@ def test_disabled_tracer_is_a_shared_noop(monkeypatch):
     with s:
         pass
     monkeypatch.undo()
-    # Instant/counter/dump are no-ops, not errors.
-    trace.instant("marker")
+    # Counter/complete/dump are no-ops, not errors.
+    trace.complete("marker", trace.now_us(), 1.0)
     trace.counter("depth", 3)
     assert trace.dump() is None
 
@@ -86,20 +86,16 @@ def test_spans_from_two_threads_serialize_to_valid_chrome_trace(tmp_path):
     assert counters and counters[0]["args"] == {"value": 2}
 
 
-def test_span_args_and_instant_events(tmp_path):
+def test_span_args_and_stamped_events(tmp_path):
     rec = trace.enable()
     with trace.span("phase", step=7):
-        trace.instant("inside", detail="x")
-    events = rec.to_dict()["traceEvents"]
-    by_ph = {e["ph"]: e for e in events}
-    assert by_ph["X"]["args"] == {"step": 7}
-    assert by_ph["i"]["name"] == "inside"
-    # Instant falls inside the span on the same thread's clock.
-    assert (
-        by_ph["X"]["ts"]
-        <= by_ph["i"]["ts"]
-        <= by_ph["X"]["ts"] + by_ph["X"]["dur"]
-    )
+        trace.complete("inside", trace.now_us(), 0.0, detail="x")
+    inside, phase = [e for e in rec.to_dict()["traceEvents"] if e["ph"] == "X"]
+    assert phase["args"] == {"step": 7}
+    assert inside["name"] == "inside" and inside["args"] == {"detail": "x"}
+    # A span stamped after the fact falls inside the live one on the same
+    # thread's clock.
+    assert phase["ts"] <= inside["ts"] <= phase["ts"] + phase["dur"]
 
 
 def test_ring_bounds_memory_and_reports_drops():

@@ -17,6 +17,7 @@ sys.path.insert(
 )
 import run_report  # noqa: E402
 
+from rt1_tpu.obs import startup  # noqa: E402
 from rt1_tpu.obs.goodput import GoodputLedger  # noqa: E402
 from rt1_tpu.obs.recorder import FlightRecorder  # noqa: E402
 
@@ -31,12 +32,21 @@ def _canned_workdir(tmp_path):
     def fake_clock():
         return clock["t"]
 
-    led = GoodputLedger(clock=fake_clock)
+    # The start-up log of that run: the first step traced and compiled
+    # for all of its 20 s.
+    log = startup.StartupLog(clock=fake_clock)
+    led = GoodputLedger(clock=fake_clock, compile_seconds=log.compile_seconds)
     with led.phase("init"):
-        clock["t"] += 8.0
+        with log.phase("build_model"):
+            clock["t"] += 8.0
         led.note_io("ckpt_restore", 2.0)
     clock["t"] += 20.0
-    led.note_step({"total_ms": 20_000.0, "wait_data_ms": 0.0, "h2d_ms": 0.0})
+    log.mark_role("train_step", "train_step_guarded")
+    log._on_start(startup.TRACE, 0.0, fun_name="train_step_guarded")
+    log._on_span(startup.TRACE, 0.0, 5.0, fun_name="train_step_guarded")
+    log._on_start(startup.BACKEND, 5.0, fun_name="jit(train_step_guarded)")
+    log._on_span(startup.BACKEND, 5.0, 20.0, fun_name="jit(train_step_guarded)")
+    led.note_step({"total_ms": 20_000.0, "compile_ms": 20_000.0})
     for _ in range(10):
         clock["t"] += 1.0
         led.note_step(
@@ -52,7 +62,7 @@ def _canned_workdir(tmp_path):
     with led.phase("preempt_drain"):
         clock["t"] += 2.0
     led.set_flops_per_step(1.5e9, peak_flops=197e12, n_chips=1)
-    led.write_summary(str(wd / "goodput_summary.json"))
+    led.write_summary(str(wd / "goodput_summary.json"), startup=log.snapshot())
 
     rec = FlightRecorder(capacity=8, path=str(wd / "flight_record.jsonl"))
     for step in range(30, 42):
@@ -98,6 +108,12 @@ def test_report_golden_sections(tmp_path):
     assert "MFU" in report and "1.5e+09 FLOPs/step" in report
     assert "1 rollback(s), 4 step(s) replayed" in report
     assert "PREEMPTED" in report
+    # The start-up log's block rides in the summary and is rendered with it.
+    row = next(ln for ln in lines if ln.startswith("compile"))
+    assert row.startswith("compile              20.00   42.6%")
+    assert "  setup/build_model: 8.000 s, self 8.000 s" in lines
+    assert any(ln.startswith("  train_step (train_step_guarded): trace 5.000 s")
+               and "compile 15.000 s" in ln for ln in lines)
 
     # Flight tail: capacity 8 with 13 records -> 8 retained, tail of 4.
     assert "Dump reason: preempt — 8 of 13 recorded steps retained." in report
