@@ -22,11 +22,23 @@ Where the spec has a multi-token-prediction module (``mtp_layers`` 1, the
 of its own, the trunk's embedding and head again; it predicts ``t_{i+2}`` and
 the loss is ``L_next + mtp_loss_weight x L_mtp``, each a mean over its own
 counted targets.
+
+The head and the loss run a block of ``LOSS_BLOCK`` tokens at a time, so the
+(tokens, vocabulary) float32 logits exist a block at a time only.
+``next_token_loss`` is a ``jax.custom_vjp``: differentiated, its one loop makes
+a block's logits once and from them the loss and both gradients (``d_x``, and
+``d_head`` summed over the blocks in float32), and those two gradients are all
+it keeps for the way back, where they are multiplied by the loss's cotangent: no
+logits, no second pass over the head.  Its primal, ``next_token_loss_plain``, is
+the same arithmetic as a plain function: what runs where nothing is
+differentiated, and the oracle the rule's gradients are held to
+(tests/test_lm_loss.py).
 """
 
 from __future__ import annotations
 
 import functools
+import logging
 from typing import Any, Dict
 
 import flax.linen as nn
@@ -40,28 +52,96 @@ from rt1_tpu.models.lm.layers import (GQAttention, LatentAttention, Leaf, Linear
 from rt1_tpu.models.lm.moe import RoutedFFN
 from rt1_tpu.models.lm.spec import IGNORE, BlockSpec, LMSpec
 
-LOSS_BLOCK = 2048   # tokens of one block of the output head and the loss
+# tokens of one block of the output head and the loss (scripts/lm_kernel_probe.py --only head)
+LOSS_BLOCK = 1024
+
+_LOG = logging.getLogger(__name__)
 
 
-def next_token_loss(x, head, targets):
-    """Mean cross-entropy of ``x @ head.T`` (float32 logits) over the targets
-    that count, a block of tokens at a time, each block under
-    ``jax.checkpoint``: a block's (tokens, vocabulary) logits are made, reduced
-    and made again on the way back, never kept for the whole batch."""
+def _loss_blocks(x, targets):
+    """``x`` and the targets as (blocks, tokens a block, ...): blocks of
+    ``LOSS_BLOCK`` tokens where that divides the tokens, else one block."""
     flat, flat_targets = x.reshape(-1, x.shape[-1]), targets.reshape(-1)
     block = LOSS_BLOCK if flat.shape[0] % LOSS_BLOCK == 0 else flat.shape[0]
+    return flat.reshape(-1, block, flat.shape[-1]), flat_targets.reshape(-1, block)
 
-    @jax.checkpoint
+
+def _logits(xb, head):
+    return jnp.einsum("td,vd->tv", xb, head, preferred_element_type=jnp.float32)
+
+
+def next_token_loss_plain(x, head, targets):
+    """Mean cross-entropy of ``x @ head.T`` (float32 logits) over the targets
+    that count, a block of tokens at a time: the plain function.  What runs
+    where nothing is differentiated, and the oracle of ``next_token_loss``'s
+    gradients (``jax.grad`` of this is what they have to equal)."""
+    xs, ts = _loss_blocks(x, targets)
+
     def one(args):
         xb, tb = args
-        logits = jnp.einsum("td,vd->tv", xb, head, preferred_element_type=jnp.float32)
+        logits = _logits(xb, head)
         picked = jnp.take_along_axis(logits, jnp.maximum(tb, 0)[:, None], axis=-1)[:, 0]
         ce = jax.nn.logsumexp(logits, axis=-1) - picked
         return jnp.sum(jnp.where(tb != IGNORE, ce, 0.0))
 
-    total = jnp.sum(jax.lax.map(one, (flat.reshape(-1, block, flat.shape[-1]),
-                                      flat_targets.reshape(-1, block))))
-    return total / jnp.maximum(jnp.sum(flat_targets != IGNORE), 1)
+    return jnp.sum(jax.lax.map(one, (xs, ts))) / jnp.maximum(jnp.sum(ts != IGNORE), 1)
+
+
+@jax.custom_vjp
+def next_token_loss(x, head, targets):
+    """``next_token_loss_plain``, with its gradient made where its logits are
+    made: differentiated, the one loop over the blocks makes a block's logits
+    once and from them the loss and both gradients, and nothing of a block is
+    kept or made again for the way back."""
+    return next_token_loss_plain(x, head, targets)
+
+
+@functools.lru_cache(maxsize=None)
+def _announce_loss(block: int, blocks: int, accumulator: str) -> None:
+    """Once a shape: how the head's gradient is made, as a log line."""
+    _LOG.info("lm loss, gradient in the forward loop: %s",
+              dict(block_tokens=block, blocks_a_pass=blocks, accumulator=accumulator))
+
+
+def _loss_and_gradients(x, head, targets, accumulator=jnp.float32):
+    """The forward rule: the loss and, as the only residuals, its gradients with
+    respect to ``x`` and ``head`` for a cotangent of 1 (the gradient is linear
+    in it): a block's ``d_logits = (softmax - onehot) * counted / count`` in
+    float32, rounded to the operands' type for the two products as the default
+    precision rounds them; ``d_head`` summed over the blocks in ``accumulator``
+    (float32; the probe times bfloat16 beside it) and rounded once."""
+    xs, ts = _loss_blocks(x, targets)
+    count = jnp.maximum(jnp.sum(ts != IGNORE), 1).astype(jnp.float32)
+    _announce_loss(xs.shape[1], xs.shape[0], jnp.dtype(accumulator).name)
+
+    def one(d_head, args):
+        xb, tb = args
+        logits = _logits(xb, head)
+        top = jnp.max(logits, axis=-1)
+        exps = jnp.exp(logits - top[:, None])       # the block's one exponential
+        sums = jnp.sum(exps, axis=-1)
+        hit = jnp.arange(logits.shape[-1])[None, :] == tb[:, None]  # never at IGNORE
+        counted = tb != IGNORE
+        weight = counted / count
+        ce = top + jnp.log(sums) - jnp.sum(jnp.where(hit, logits, 0.0), axis=-1)
+        d_logits = (exps * (weight / sums)[:, None] - jnp.where(hit, weight[:, None], 0.0)
+                    ).astype(xb.dtype)
+        d_xb = jnp.einsum("tv,vd->td", d_logits, head, preferred_element_type=jnp.float32)
+        d_head = d_head + jnp.einsum("tv,td->vd", d_logits, xb,
+                                     preferred_element_type=jnp.float32).astype(accumulator)
+        return d_head, (jnp.sum(jnp.where(counted, ce, 0.0)), d_xb.astype(xb.dtype))
+
+    d_head, (ce, d_x) = jax.lax.scan(one, jnp.zeros(head.shape, accumulator), (xs, ts))
+    return jnp.sum(ce) / count, (d_x.reshape(x.shape), d_head.astype(head.dtype))
+
+
+def _scaled_gradients(gradients, g):
+    """The backward rule: the kept gradients times the loss's cotangent."""
+    d_x, d_head = gradients
+    return (g * d_x).astype(d_x.dtype), (g * d_head).astype(d_head.dtype), None
+
+
+next_token_loss.defvjp(_loss_and_gradients, _scaled_gradients)
 
 
 # What ``H_res``'s diagonal starts at before the exponential: the bias leaf holds
@@ -359,8 +439,12 @@ class DecoderLM(nn.Module):
                 "attention/window_layers": jnp.float32(mixers.count("sliding_attention")),
                 "attention/full_layers": jnp.float32(mixers.count("full_attention")),
             })
+        # static: the passes through the head, each one loop that makes the
+        # loss and, where it is differentiated, its gradients (next_token_loss)
+        out.setdefault("counters", {})["lm_loss/grad_in_forward_passes"] = jnp.float32(
+            1 + bool(sp.mtp_layers))
         if sp.mtp_layers:
-            out.setdefault("counters", {})["mtp/loss"] = mtp_loss
+            out["counters"]["mtp/loss"] = mtp_loss
         if sum_errs:
             # the largest gap of a row or column sum of any sublayer's H_res from 1
             out.setdefault("counters", {}).update({

@@ -30,14 +30,19 @@ the query's own key and the ``window - 1`` before it): blocks of 256, 512 and
 
     python scripts/lm_kernel_probe.py [--rows 65536 --held_rows 8192] [--only routed]
 
-Two parts run on request only: ``--only latent`` (a latent-attention layer's
-kernel: keys of 192 as they are and zero-padded to 256, values of 128, fused
-and unfused backward, blocks; ``--batch 1 --seq 8192 --heads 16``) and ``--only
-streams`` (one hyper-connection sublayer both ways, the streams held in
-bfloat16 and in float32; ``--batch 1 --seq 8192 --hidden 3584 --streams 4``).
+Three parts run on request only: ``--only head`` (the output head and the
+loss both ways at the three token cells' shapes: the loop of blocks under
+``jax.checkpoint`` that PR 36 had against the gradient made in the forward
+loop, with a float32 and a bfloat16 accumulator, at every ``--block``),
+``--only latent`` (a latent-attention layer's kernel: keys of 192 as they are
+and zero-padded to 256, values of 128, fused and unfused backward, blocks;
+``--batch 1 --seq 8192 --heads 16``) and ``--only streams`` (one
+hyper-connection sublayer both ways, the streams held in bfloat16 and in
+float32; ``--batch 1 --seq 8192 --hidden 3584 --streams 4``).
 """
 
 import argparse
+import functools
 import json
 import sys
 import time
@@ -73,7 +78,9 @@ def main() -> int:
     ap.add_argument("--experts-held", type=int, default=8)
     ap.add_argument("--top-k", type=int, default=4)
     ap.add_argument("--only", choices=("attention", "experts", "routed", "layer", "latent",
-                                       "streams"), default=None)
+                                       "streams", "head"), default=None)
+    ap.add_argument("--block", type=int, nargs="+", default=[1024, 2048, 4096],
+                    help="--only head: the loss's tokens a block, a set of rows each")
     ap.add_argument("--streams", type=int, default=4, help="residual streams a token")
     ap.add_argument("--tile", type=int, nargs="+", default=[128],
                     help="--only streams: the kernels' tokens a tile, a set of rows each")
@@ -81,8 +88,8 @@ def main() -> int:
 
     sys.path.insert(0, ".")
     print(json.dumps({"device": jax.devices()[0].device_kind}), flush=True)
-    if args.only in ("latent", "streams"):      # a configuration's own rows, on request
-        {"latent": latent, "streams": streams}[args.only](args)
+    if args.only in ("latent", "streams", "head"):      # a part's own rows, on request
+        {"latent": latent, "streams": streams, "head": head}[args.only](args)
         return 0
     for part in ("attention", "experts", "routed"):
         if args.only in (None, part) or (part, args.only) == ("routed", "layer"):
@@ -331,6 +338,77 @@ def attention_inputs(seq: int, d: int = 64, b: int = 2, kvh: int = 8, g: int = 4
     k = jax.random.normal(jax.random.fold_in(key, 1), (b, seq, kvh, d), jnp.bfloat16)
     v = jax.random.normal(jax.random.fold_in(key, 2), (b, seq, kvh, d), jnp.bfloat16)
     return q, k, v
+
+
+# tokens, hidden, vocabulary rows held: lfm2's, mellum's and xing's cells
+HEAD_SHAPES = ((16384, 2048, 8192), (16384, 2304, 24576), (8192, 3584, 16384))
+
+
+def checkpointed_loss(x, head, targets, block):
+    """``next_token_loss`` as it was through PR 36 (the probe's baseline): each
+    block under ``jax.checkpoint``, so its logits and log-sum-exp are made in the
+    forward loop and again in the transposed one."""
+    from rt1_tpu.models.lm.spec import IGNORE
+
+    @jax.checkpoint
+    def one(args):
+        xb, tb = args
+        logits = jnp.einsum("td,vd->tv", xb, head, preferred_element_type=jnp.float32)
+        picked = jnp.take_along_axis(logits, jnp.maximum(tb, 0)[:, None], axis=-1)[:, 0]
+        ce = jax.nn.logsumexp(logits, axis=-1) - picked
+        return jnp.sum(jnp.where(tb != IGNORE, ce, 0.0))
+
+    total = jnp.sum(lax.map(one, (x.reshape(-1, block, x.shape[-1]),
+                                  targets.reshape(-1, block))))
+    return total / jnp.maximum(jnp.sum(targets != IGNORE), 1)
+
+
+def head(args) -> None:
+    """The output head and the loss both ways (``--only head``), a row a shape,
+    path and block: ``checkpointed`` (four products a block in two loops) and
+    ``grad_in_forward`` (``model.next_token_loss``: three products in one loop)
+    with ``d_head`` summed over the blocks in float32 (what ships) and in
+    bfloat16; the loss's cotangent 1 (the trunk's pass: the scaling folds away)
+    and, for what ships, 0.3 (a prediction module's: a pass over ``d_x`` and
+    ``d_head``).  ``roof_ms``: three products at the MXU's peak.  ``temp_bytes``:
+    the compiled program's temporaries."""
+    from rt1_tpu.models.lm import model as lm_model
+    from rt1_tpu.models.lm.spec import IGNORE
+
+    def with_accumulator(accumulator):
+        fn = jax.custom_vjp(lm_model.next_token_loss_plain)
+        fn.defvjp(functools.partial(lm_model._loss_and_gradients, accumulator=accumulator),
+                  lm_model._scaled_gradients)
+        return fn
+
+    for tokens, d, rows in HEAD_SHAPES:
+        key = jax.random.PRNGKey(0)
+        x = jax.random.normal(key, (1, tokens, d), jnp.bfloat16)
+        table = (0.02 * jax.random.normal(jax.random.fold_in(key, 1), (rows, d))
+                 ).astype(jnp.bfloat16)
+        targets = jax.random.randint(jax.random.fold_in(key, 2), (1, tokens), 0, rows)
+        targets = jnp.where(jnp.arange(tokens)[None] < 0.85 * tokens, targets, IGNORE)
+        for block in args.block:
+            lm_model.LOSS_BLOCK = block
+            for path, accumulator, weight, loss in (
+                    ("checkpointed", None, 1.0,
+                     functools.partial(checkpointed_loss, block=block)),
+                    ("grad_in_forward", "float32", 1.0, lm_model.next_token_loss),
+                    ("grad_in_forward", "float32", 0.3, lm_model.next_token_loss),
+                    ("grad_in_forward", "bfloat16", 1.0, with_accumulator(jnp.bfloat16))):
+                row = {"path": path, "accumulator": accumulator, "cotangent": weight,
+                       "tokens": tokens, "hidden": d, "rows": rows, "block": block,
+                       "roof_ms": round(3 * 2 * tokens * d * rows / 197e12 * 1e3, 3)}
+                try:
+                    step = jax.jit(jax.value_and_grad(
+                        lambda x, table, loss=loss, weight=weight:
+                        weight * loss(x, table, targets), argnums=(0, 1)))
+                    row["temp_bytes"] = step.lower(
+                        x, table).compile().memory_analysis().temp_size_in_bytes
+                    row["fwd_bwd_ms"] = round(timed(step, x, table, repeats=20), 3)
+                except Exception as exc:  # noqa: BLE001 - a probe reports and goes on
+                    row["error"] = repr(exc)[:300]
+                print(json.dumps(row), flush=True)
 
 
 def both_ways(fn):

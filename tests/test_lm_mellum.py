@@ -140,7 +140,8 @@ def test_program_against_the_reference(world, dtype):
 
 
 def test_a_decoder_of_full_layers_alone_keeps_its_counters():
-    """``lfm2_moe``'s step has the outputs it had (its program is its parent's)."""
+    """``lfm2_moe``'s step has the outputs it had and the head's counter, which
+    every decoder of the family has since PR 37; none of a sliding layer's."""
     config = lfm2_moe.get_config()
     for k, v in dict(hidden_size=64, num_attention_heads=4, num_key_value_heads=2, head_dim=16,
                      intermediate_size=96, moe_intermediate_size=32, num_experts=16,
@@ -151,6 +152,7 @@ def test_a_decoder_of_full_layers_alone_keeps_its_counters():
     out = jax.eval_shape(
         lambda r: model.apply(init_fn(model, r, *batch), *batch), jax.random.PRNGKey(0))
     assert sorted(out["counters"]) == [
+        "lm_loss/grad_in_forward_passes",
         "moe/assignments_held", "moe/fallback_layers", "moe/load_max_over_mean"]
     assert model.spec.tie_word_embeddings and model.spec.scoring_func == "sigmoid"
     assert model.spec.rotary == (("full_attention", RotaryRule("default", 1000000.0)),)
