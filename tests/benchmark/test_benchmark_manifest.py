@@ -4,6 +4,7 @@ harness's promise that a cell is data only."""
 import json
 import os
 import re
+import shutil
 
 import pytest
 
@@ -107,6 +108,51 @@ def test_a_new_cell_is_one_workloads_entry(tmp_path, config, mix):
         "setup_s", "train_samples_per_s"}
     assert len(run.cell_metrics(m, "per_layer", name)) == len(
         [x for x in m["per_layer"] if "workloads" not in x])
+
+
+def test_a_new_configuration_lands_as_additions(tmp_path):
+    """A configuration (a renamed copy of a file that is there), its cell and a
+    per-layer entry of its own, appended: nothing that was there is edited, the
+    cell resolves, it reports every entry without a list and its own, and every
+    other cell reports what it reported."""
+    from benchmarks import program, run
+
+    config, mix = "added-config", "train-tokens-8k"
+    cell, metric = f"{config}.{mix}", "added_ms.train"
+    root = temp_checkout(tmp_path)
+    configs = os.path.join(root, "benchmarks", "configs")
+    shutil.copy(os.path.join(configs, "lfm2-24b-a2b.json"), os.path.join(configs, config + ".json"))
+    metrics = os.path.join(root, "benchmarks", "metrics")
+    shutil.copy(os.path.join(metrics, "moe_ms.train.py"), os.path.join(metrics, metric + ".py"))
+    path = os.path.join(root, "BENCHMARK.json")
+    before, m = run.load_manifest(root), run.load_manifest(root)
+    m["configs"].append({"name": config, "source": "added by a test", "reduced": [],
+                         "file": f"benchmarks/configs/{config}.json", "why": "added by a test"})
+    m["workloads"].append({"name": cell, "config": config, "traffic": mix, "chips": 1,
+                           "why": "added by a test"})
+    m["per_layer"].append({"name": metric, "unit": "ms", "better": "lower",
+                           "source": "device_trace", "layer": "Model step",
+                           "moves": "train_samples_per_s", "workloads": [cell]})
+    with open(path, "w") as f:
+        json.dump(m, f)
+
+    m = run.load_manifest(root)
+    entry = run.find_cell(m, cell)
+    cf = program.load_config_file(os.path.join(root, run.config_path(m, entry["config"])))
+    assert program.program_config(cf).model.family == "lfm2_moe"
+    assert os.path.exists(os.path.join(root, "benchmarks", "traffic", mix + ".json"))
+    unlisted = {x["name"] for x in m["per_layer"] if "workloads" not in x}
+    assert {"train_mfu_pct", "setup_build_s", "setup_cache_misses"} <= unlisted
+    mine = run.cell_metrics(m, "per_layer", cell)
+    assert {x["name"] for x in mine} == unlisted | {metric}
+    for x in mine:
+        assert callable(run.metric_reader(root, x["name"]).read)
+    assert {x["name"] for x in run.cell_metrics(m, "end_to_end", cell)} >= {
+        "setup_s", "train_samples_per_s"}
+    for other in before["workloads"]:
+        for group in ("per_layer", "end_to_end"):
+            assert run.cell_metrics(m, group, other["name"]) == run.cell_metrics(
+                before, group, other["name"]), other["name"]
 
 
 def test_peaks_know_the_chip_and_nothing_else():

@@ -1,25 +1,19 @@
-"""The seven ``setup_*`` readers (PR 36): each reads the program's own
-start-up log (rt1_tpu/obs/startup.py) through benchmarks/setup_log.py.  From a
-hand-made snapshot each returns its number; from an empty one, and from a
-program that has no such module (the parent commit's), None; and with
-nothing handed in they read the process's own log, after a rehearsal run of a
-cell as after a run on the chip.
+"""The seven ``setup_*`` readers: each reads the program's own start-up log
+(rt1_tpu/obs/startup.py) through benchmarks/setup_log.py.  From a hand-made
+snapshot each returns its number; from an empty one, and from a program that
+has no such module (an older commit's), None; and with nothing handed in they
+read the process's own log, after a rehearsal run of a cell as after a run on
+the chip.
 
-Their seven ``per_layer`` entries are not in BENCHMARK.json yet:
-test_benchmark_xing.py holds xing's six entries to the last six places of
-``per_layer``, and a PR may not edit a file the benchmark has.  They wait in
-data/setup_per_layer.json, held here to the manifest's rules and appended to
-a temporary checkout's manifest, for the ``benchmark`` PR that frees the
-place."""
+Their seven ``per_layer`` entries in BENCHMARK.json carry no ``workloads``
+list: every cell reports ``setup_s``, so every cell, and every cell added
+later, reports all seven.  They are found by name, never by place."""
 
 import builtins
-import json
-import os
-import re
 
 import pytest
 
-from bench_testlib import DATA, REPO, manifest, pretend_chip, run_cell, temp_checkout
+from bench_testlib import REPO, manifest, pretend_chip, run_cell, temp_checkout
 from benchmarks import run, setup_log
 
 CELLS = [w["name"] for w in manifest()["workloads"]]
@@ -101,23 +95,22 @@ def test_reader_returns_none_from_a_program_without_the_log(name, monkeypatch):
     assert run.metric_reader(REPO, name).read({"trace": {}}) is None
 
 
-def entries():
-    with open(os.path.join(DATA, "setup_per_layer.json")) as f:
-        return json.load(f)
-
-
-def test_after_a_rehearsal_run_the_readers_read_the_programs_own_set_up(tmp_path, monkeypatch):
+@pytest.mark.parametrize("cell", ["small.packed", "small.pool"])
+def test_after_a_rehearsal_run_the_readers_read_the_programs_own_set_up(
+        tmp_path, monkeypatch, cell):
     """The whole path on the CPU: the driver builds the program through
     ``program.build`` (the phases), ``make_train_step_fns`` marks the step's
-    role, the first steps trace, lower and compile it, and the readers, run
-    after the driver returns, find all of it in the process's log."""
+    role, the first steps trace, lower and compile it, and the readers the
+    cell's entries name, run after the driver returns, find all of it in the
+    process's log."""
     from rt1_tpu.obs import startup
     from rt1_tpu.parallel import describe_devices
 
     describe_devices()          # as benchmarks/devices.py::describe does on the chip
     pretend_chip(monkeypatch)
     before = startup.snapshot()
-    rc, line, _ = run_cell(temp_checkout(tmp_path), "small.packed")
+    root = temp_checkout(tmp_path)
+    rc, line, _ = run_cell(root, cell)
     assert rc == 0 and line["correct"] is True, line
     after = startup.snapshot()
     for phase in setup_log.BUILD_PHASES:
@@ -125,8 +118,11 @@ def test_after_a_rehearsal_run_the_readers_read_the_programs_own_set_up(tmp_path
     role = after["roles"]["train_step"]
     assert set(role["functions"]) <= {"train_step", "train_step_guarded"}
     assert after["totals"]["traces"] > before["totals"]["traces"]
+    names = [x["name"] for x in run.cell_metrics(run.load_manifest(root), "per_layer", cell)
+             if x["moves"] == "setup_s"]
+    assert sorted(names) == sorted(READERS)
     reading = {"trace": {}}
-    values = {name: run.metric_reader(REPO, name).read(reading) for name in READERS}
+    values = {name: run.metric_reader(root, name).read(reading) for name in names}
     assert all(v is not None for v in values.values()), values
     assert values["setup_step_inner_traces"] > 0 and values["setup_build_s"] > 0
     assert values["setup_step_trace_s"] > 0 and values["setup_step_lower_s"] > 0
@@ -135,37 +131,28 @@ def test_after_a_rehearsal_run_the_readers_read_the_programs_own_set_up(tmp_path
     assert reading["startup"]["totals"] == after["totals"]      # one snapshot a run, kept
 
 
-@pytest.mark.parametrize("entry", entries(), ids=lambda m: m["name"])
-def test_a_waiting_entry_keeps_the_manifests_rules(entry):
-    """tests/benchmark/test_benchmark_manifest.py::test_metric_entry's, for
-    an entry that is not in the manifest yet."""
-    m = manifest()
-    assert re.match(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$", entry["name"])
-    assert entry["name"] not in {x["name"] for x in m["end_to_end"] + m["per_layer"]}
-    assert set(entry) == {"name", "unit", "better", "source", "layer", "moves", "workloads"}
+@pytest.mark.parametrize("name", sorted(READERS))
+def test_a_manifest_entry_keeps_the_rules(name):
+    """Each entry, found by name: no list of cells, under ``setup_s`` in the
+    entry point's layer, its source by its unit."""
+    entry = next(x for x in manifest()["per_layer"] if x["name"] == name)
+    assert set(entry) == {"name", "unit", "better", "source", "layer", "moves"}
     assert entry["unit"] in ("s", "count") and entry["better"] == "lower"
     assert entry["source"] == ("program_counter" if entry["unit"] == "count" else "program_span")
     assert entry["moves"] == "setup_s" and entry["layer"] == "Entry point"
-    assert entry["workloads"] == CELLS      # every cell reports setup_s
 
 
-def test_appended_to_a_manifest_the_entries_resolve_in_every_cell(tmp_path):
-    root = temp_checkout(tmp_path)
-    path = os.path.join(root, "BENCHMARK.json")
-    with open(path) as f:
-        m = json.load(f)
-    m["per_layer"].extend(entries())
-    with open(path, "w") as f:
-        json.dump(m, f)
+def test_every_cell_reports_the_seven_and_each_reader_resolves(tmp_path):
+    """The manifest's cells, and the cells a temporary checkout adds (the
+    rehearsal cells and one more of a configuration that is there)."""
+    extra = {"name": "lfm2-24b-a2b.train-tokens-16k", "config": "lfm2-24b-a2b",
+             "traffic": "train-tokens-16k", "chips": 1, "why": "added by a test"}
+    root = temp_checkout(tmp_path, extra_workloads=[extra])
     m = run.load_manifest(root)
-    assert [x["name"] for x in m["per_layer"][-7:]] == [
-        "setup_backend_init_s", "setup_build_s", "setup_step_trace_s",
-        "setup_step_inner_traces", "setup_step_lower_s", "setup_step_compile_s",
-        "setup_cache_misses"]
-    assert {x["name"] for x in entries()} == set(READERS)
-    for cell in CELLS:
+    cells = [w["name"] for w in m["workloads"]]
+    assert set(CELLS) | {"small.packed", "small.pool", extra["name"]} <= set(cells)
+    for cell in cells:
         mine = [x["name"] for x in run.cell_metrics(m, "per_layer", cell) if x["moves"] == "setup_s"]
-        assert len(mine) == 7
+        assert sorted(mine) == sorted(READERS), cell
         for name in mine:
             assert callable(run.metric_reader(root, name).read)
-    assert not any(x["moves"] == "setup_s" for x in run.cell_metrics(m, "per_layer", "small.packed"))

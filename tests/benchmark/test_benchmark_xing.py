@@ -31,6 +31,14 @@ TALLIES = {"latent_projection_ms.train": "latent_projections",
 ROOFLINES = ("latent_attention_roofline", "hyper_connection_roofline")
 NEW_READERS = tuple(TALLIES) + ROOFLINES
 CONTROLS = ("int8", "plain_residual", "no_yarn_scale", "no_mtp")
+GENERIC = ("feeder_wait_ms.train", "h2d_ms.train", "device_step_ms.train", "train_mfu_pct",
+           "train_step_roofline", "device_idle_pct.train")
+# the per-layer entries that stood before this configuration came
+EARLIER = GENERIC + (
+    "moe_ms.train", "mixer_ms.train", "moe_experts_roofline", "attention_roofline",
+    "moe_assignments_held_share.train", "attention_ms.train", "attention_masked_roofline",
+    "lm_loss_ms.train", "attention_window_ms.train", "attention_full_ms.train",
+    "moe_fallback_share.train")
 
 
 def xing_checkout(tmp_path):
@@ -161,23 +169,25 @@ def test_a_new_entry_lists_the_cell_alone(name):
     assert entry["source"] == "device_trace"
     assert entry["unit"] == ("%" if name in ROOFLINES else "ms")
     assert entry["layer"] == ("Kernels" if name in ROOFLINES else "Model step")
-    # the six generic metrics report in the new cell as in the others
-    generic = [m["name"] for m in manifest()["per_layer"] if "workloads" not in m]
-    assert len(generic) == 6
-    assert {m["name"] for m in run.cell_metrics(manifest(), "per_layer", CELL)} == set(
-        generic) | set(NEW_READERS)
+    # every entry without a list reports in the new cell as in the others,
+    # the generic six among them, however many are appended later
+    unlisted = {m["name"] for m in manifest()["per_layer"] if "workloads" not in m}
+    assert set(GENERIC) <= unlisted
+    assert {m["name"] for m in run.cell_metrics(manifest(), "per_layer", CELL)} == (
+        unlisted | set(NEW_READERS))
 
 
 def test_the_manifest_gained_entries_and_lost_none():
+    """Found by name, wherever later entries put them."""
     m = manifest()
-    assert [c["name"] for c in m["configs"]][-1] == CONFIG
-    assert [w["name"] for w in m["workloads"]][-1] == CELL
-    assert [x["name"] for x in m["per_layer"]][-6:] == [
-        "latent_projection_ms.train", "latent_attention_roofline", "hyper_connection_ms.train",
-        "hyper_connection_roofline", "mtp_ms.train", "shared_expert_ms.train"]
-    # the other cells' lists are as they were
-    for entry in m["per_layer"][:-6]:
-        assert CELL not in entry.get("workloads", [])
+    assert CONFIG in {c["name"] for c in m["configs"]}
+    assert CELL in {w["name"] for w in m["workloads"]}
+    by_name = {x["name"]: x for x in m["per_layer"]}
+    for name in NEW_READERS:
+        assert by_name[name]["workloads"] == [CELL], name
+    # the entries that were there are there still, and their lists leave the cell out
+    for name in EARLIER:
+        assert CELL not in by_name[name].get("workloads", []), name
 
 
 XING_SCOPES = {
